@@ -11,17 +11,28 @@ Each maps one reference script (diff_train.py, diff_inference.py,
 diff_retrieval.py, embedding_search/*, sd_mitigation.py) onto the library
 APIs; config parsing is the shared dotted-key system (core.config.parse_cli).
 
-Set DCR_TPU_PLATFORM=cpu to force a platform after jax import — needed in
-environments that pre-import jax with a pinned platform (env vars are then too
-late; jax.config still works as long as no backend has initialized).
+Every ``main`` calls :func:`setup_compile_cache` before it builds anything.
+The platform is JAX's own choice (the TPU where there is one); tests and
+rehearsals pin the CPU with ``JAX_PLATFORMS=cpu``.
 """
 
 import os
+from pathlib import Path
 
 
-def setup_platform() -> None:
-    platform = os.environ.get("DCR_TPU_PLATFORM")
-    if platform:
-        import jax
+def setup_compile_cache() -> str:
+    """The ONE place that decides where JAX's persistent compilation cache
+    lives; returns the directory in use.
 
-        jax.config.update("jax_platforms", platform)
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and no directory
+    is set in code. Unset: ``<checkout>/.jax_cache`` (git-ignored), resolved
+    from this package's own path — the path is part of the cache key, so it
+    must never move between runs."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+
+    cache_dir = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir

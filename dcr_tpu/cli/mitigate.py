@@ -38,9 +38,9 @@ KNOWN_REPLICATION_PROMPTS = (
 
 
 def main(argv=None) -> None:
-    from dcr_tpu.cli import setup_platform
+    from dcr_tpu.cli import setup_compile_cache
 
-    setup_platform()
+    setup_compile_cache()
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
     cfg = parse_cli(SampleConfig, argv)

@@ -109,9 +109,9 @@ def precompute(cfg: TrainConfig) -> dict:
 
 
 def main(argv=None) -> None:
-    from dcr_tpu.cli import setup_platform
+    from dcr_tpu.cli import setup_compile_cache
 
-    setup_platform()
+    setup_compile_cache()
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s", force=True)
     cfg = parse_cli(TrainConfig, argv)

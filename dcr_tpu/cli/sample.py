@@ -36,9 +36,9 @@ def infer_modelstyle(model_path: str) -> str:
 
 
 def main(argv=None) -> None:
-    from dcr_tpu.cli import setup_platform
+    from dcr_tpu.cli import setup_compile_cache
 
-    setup_platform()
+    setup_compile_cache()
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
     argv = list(sys.argv[1:] if argv is None else argv)

@@ -48,9 +48,9 @@ log = logging.getLogger("dcr_tpu")
 
 
 def main(argv=None) -> None:
-    from dcr_tpu.cli import setup_platform
+    from dcr_tpu.cli import setup_compile_cache
 
-    setup_platform()
+    setup_compile_cache()
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s", force=True)
     cfg = parse_cli(ServeConfig, argv)

@@ -10,9 +10,9 @@ from dcr_tpu.diffusion.trainer import Trainer
 
 
 def main(argv=None) -> None:
-    from dcr_tpu.cli import setup_platform
+    from dcr_tpu.cli import setup_compile_cache
 
-    setup_platform()
+    setup_compile_cache()
     # force=True: orbax/absl imports grab the root logger first, which would
     # silently drop every INFO line (including the resume/recovery messages
     # the fault-tolerance contract requires to be visible)

@@ -129,7 +129,9 @@ def init_train_state(cfg: TrainConfig, models: DiffusionModels, *,
         unet_params=unet_params,
         text_params=text_params,
         vae_params=vae_params,
-        opt_state=tx.init(
+        # jitted: un-jitted, optax fills every moment leaf with a dispatch of
+        # its own, and on a TPU each distinct shape of those is a compile
+        opt_state=jax.jit(tx.init)(
             trainable_of(
                 TrainState(jnp.zeros((), jnp.int32), unet_params, text_params,
                            vae_params, None),

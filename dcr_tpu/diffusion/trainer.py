@@ -27,7 +27,8 @@ from dcr_tpu.core import dist
 from dcr_tpu.core.compile_surface import compile_surface
 from dcr_tpu.core import resilience as R
 from dcr_tpu.core import tracing
-from dcr_tpu.core.checkpoint import CheckpointManager, export_hf_layout
+from dcr_tpu.core.checkpoint import (CheckpointManager, export_hf_layout,
+                                     host_copy)
 from dcr_tpu.core.config import TrainConfig, run_name, save_config, to_dict, validate_train_config
 from dcr_tpu.core.metrics import MetricWriter
 from dcr_tpu.core import rng as rngmod
@@ -79,7 +80,7 @@ def build_modules(cfg: TrainConfig, mesh=None) -> "T.DiffusionModels":
 
     Module objects are static pytree-less config holders; the only arrays here
     are the (tiny) noise-schedule tables. Pairs with abstract_train_state for
-    zero-memory cost-analysis lowering (bench.py FLOPs accounting)."""
+    zero-memory lowering (FLOPs accounting, compiles for a described chip)."""
     from dcr_tpu.models.clip_text import CLIPTextModel
     from dcr_tpu.models.unet2d import UNet2DCondition
     from dcr_tpu.models.vae import AutoencoderKL
@@ -420,11 +421,19 @@ class Trainer:
         unet_to_export = (self.state.ema_params if self.state.ema_params is not None
                           else self.state.unet_params)
         if dist.is_primary():
+            model_config = to_dict(self.cfg.model)
+            # one component at a time, and no host copy left cached on the
+            # state: the exporter holds a component about three times over
+            # while it writes both layouts (10 GB for SD-2.1's UNet), and all
+            # three at once beside the cached copies ran a 40 GiB v5e host
+            # out of memory (PR 24)
+            for name, tree in (("unet", unet_to_export),
+                               ("vae", self.state.vae_params),
+                               ("text_encoder", self.state.text_params)):
+                export_hf_layout(out, model_config=model_config,
+                                 **{name: host_copy(tree)})
             export_hf_layout(
                 out,
-                unet=jax.device_get(unet_to_export),
-                vae=jax.device_get(self.state.vae_params),
-                text_encoder=jax.device_get(self.state.text_params),
                 scheduler_config={
                     "num_train_timesteps": self.cfg.model.num_train_timesteps,
                     "beta_schedule": self.cfg.model.beta_schedule,
@@ -432,7 +441,7 @@ class Trainer:
                     "beta_end": self.cfg.model.beta_end,
                     "prediction_type": self.cfg.model.prediction_type,
                 },
-                model_config=to_dict(self.cfg.model),
+                model_config=model_config,
             )
         # bounded: a peer that died mid-export must become a BarrierTimeout,
         # not an eternal hang. barrier_timeout_s defaults to 0 (= wait
@@ -445,11 +454,26 @@ class Trainer:
 
     def _step_flops(self, sharded_batch) -> float:
         """Per-device FLOPs of the compiled train step (0 if unavailable);
-        feeds the MFU telemetry (SURVEY.md §5.1 — absent in the reference)."""
+        feeds the MFU telemetry (SURVEY.md §5.1 — absent in the reference).
+
+        Called BEFORE the first step, it compiles the step once and the loop
+        then runs that executable: calling the jit function and lowering it
+        again for the cost analysis traced and lowered the step twice (19 s
+        of tracing at SD-2.1 widths on a v5e host, PR 24, and a second full
+        compile wherever no persistent cache is set)."""
         from dcr_tpu.utils.profiling import flops_of_jitted
 
-        return flops_of_jitted(self.step_fn, self.state, sharded_batch,
-                               self.train_key)
+        if self._step_call is not self.step_fn:
+            # warm start already put an AOT executable in the loop
+            return flops_of_jitted(self.step_fn, self.state, sharded_batch,
+                                   self.train_key)
+        from dcr_tpu.core import warmcache
+
+        compiled = self.step_fn.lower(self.state, sharded_batch,
+                                      self.train_key).compile()
+        self._step_call = warmcache.guarded(compiled, self.step_fn,
+                                            "train/step")
+        return memwatch.flops_of_compiled(compiled)
 
     def _denoise_flops(self, enc) -> float:
         """Pipelined-mode MFU numerator: FLOPs of the denoiser-only hot step
@@ -830,6 +854,8 @@ class Trainer:
                             if producer is None:
                                 sharded = pmesh.shard_batch(self.mesh,
                                                             dict(batch))
+                                if flops_per_step is None:
+                                    flops_per_step = self._step_flops(sharded)
                                 self.state, metrics = self._step_call(
                                     self.state, sharded, self.train_key)
                             else:
@@ -864,8 +890,6 @@ class Trainer:
                         C.simulate_hang(f"injected hang at step {step}")
                     at_sync = step % accum == 0
                     sync = step // accum
-                    if flops_per_step is None and producer is None:
-                        flops_per_step = self._step_flops(sharded)
                     decision: Optional[C.Decision] = None
                     if (at_sync and sync % cfg.log_every == 0) or step == max_micro:
                         metrics = jax.device_get(metrics)
@@ -944,7 +968,9 @@ class Trainer:
                             metrics["tflops_per_sec"] = per_chip / 1e12
                             metrics["tflops_per_sec_total"] = (
                                 per_chip * jax.device_count() / 1e12)
-                            metrics["mfu"] = per_chip / 1e12 / chip_peak_tflops()
+                            peak = chip_peak_tflops()
+                            if peak:    # None on the CPU: no mfu there
+                                metrics["mfu"] = per_chip / 1e12 / peak
                         # recovery counters: no retry/rollback is ever silent —
                         # each also logged a structured [fault] line when it fired
                         metrics["faults/bad_samples"] = self.loader.bad_samples
@@ -1025,12 +1051,16 @@ class Trainer:
                 if producer is not None:
                     producer.stop()
         self.watchdog.stop()  # export/teardown below has no step heartbeat
+        # export BEFORE the final save: orbax's device-to-host transfer
+        # leaves a host copy cached on every leaf of the live state (12 GB at
+        # SD-2.1 widths, until the state dies), and the exporter's own copies
+        # on top of that ran a 40 GiB v5e host out of memory (PR 24)
+        self.export_checkpoint()
         self.save(force=True)
         self.ckpt.wait()
         if jax.process_count() > 1:
             log.info("state fingerprint at step %d: %s", step,
                      state_fingerprint(self.state))
-        self.export_checkpoint()
         self.writer.close()
         self._uninstall_preemption_handler()
         return last_metrics

@@ -86,5 +86,6 @@ def init_clip_text(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32,
                    model: "CLIPTextModel | None" = None):
     model = model if model is not None else CLIPTextModel(cfg, dtype=dtype)
     ids = jnp.zeros((1, cfg.text_max_length), jnp.int32)
-    params = model.init(key, ids)["params"]
+    # jitted for the reason init_unet gives
+    params = jax.jit(model.init)(key, ids)["params"]
     return model, params

@@ -131,5 +131,7 @@ class ResNet50Classifier(nn.Module):
 
 def init_sscd(key: jax.Array, embed_dim: int = 512, image_size: int = 224):
     model = SSCDModel(embed_dim=embed_dim)
-    params = model.init(key, jnp.zeros((1, image_size, image_size, 3)))["params"]
+    # jitted for the reason init_unet gives
+    params = jax.jit(model.init)(
+        key, jnp.zeros((1, image_size, image_size, 3)))["params"]
     return model, params

@@ -256,7 +256,12 @@ def dpmpp_2m_step(sched: NoiseSchedule, model_out: jax.Array, x_t: jax.Array,
     h_last = lam_t - state.prev_lambda
     r = h_last / jnp.where(h == 0, 1e-20, h)
     inv2r = _bcast(1.0 / (2.0 * jnp.maximum(r, 1e-20)), nd)
-    use_second = jnp.logical_and(state.step_index > 0,
+    # `>= 1`, not `> 0`: inside lax.scan, XLA:TPU (libtpu 0.0.34, jax 0.9.0)
+    # evaluates `step_index > 0` as True for the scan-carried counter at 0,
+    # which sends step 0 through the multistep term with inv2r = 5e19 and
+    # ends every 50-step sample in NaN; `>= 1` compiles right (both seen on
+    # a v5e, PR 24; chip_smoke.py's sample phase is the guard)
+    use_second = jnp.logical_and(state.step_index >= 1,
                                  jnp.logical_not(force_first_order))
     d = jnp.where(use_second, (1.0 + inv2r) * x0 - inv2r * state.prev_x0, x0)
 

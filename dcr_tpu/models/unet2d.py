@@ -123,7 +123,10 @@ def init_unet(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32, mesh=None,
     sample = jnp.zeros((1, cfg.sample_size, cfg.sample_size, cfg.in_channels))
     t = jnp.zeros((1,), jnp.int32)
     ctx = jnp.zeros((1, cfg.text_max_length, cfg.cross_attention_dim))
-    params = model.init(key, sample, t, ctx)["params"]
+    # jitted: flax's init runs the forward pass to find the shapes, and
+    # un-jitted every distinct op of it is a compile of its own on a TPU
+    # (minutes at SD-2.1 widths); under jit the forward is dead code
+    params = jax.jit(model.init)(key, sample, t, ctx)["params"]
     return model, params
 
 

@@ -125,5 +125,6 @@ def init_vae(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32,
     model = model if model is not None else AutoencoderKL(cfg, dtype=dtype)
     px = vae_scale_factor(cfg) * cfg.sample_size
     x = jnp.zeros((1, px, px, 3))
-    params = model.init(key, x, jax.random.key(0))["params"]
+    # jitted for the reason init_unet gives
+    params = jax.jit(model.init)(key, x, jax.random.key(0))["params"]
     return model, params

@@ -165,13 +165,16 @@ def make_risk_scorer(top_k: int):
     as an ARGUMENT — device-resident between calls, never baked into the
     executable — which keeps the program reusable across index reloads of
     the same shape and fingerprintable for the compile manifest.
+    ``precision=HIGHEST`` as in ``search/topk``, which scores the
+    store-backed index: both backends answer with float32 dot products.
     """
     import jax
     import jax.numpy as jnp
 
     def score(index_feats, q):
         q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
-        sims = q @ index_feats.T
+        sims = jnp.matmul(q, index_feats.T,
+                          precision=jax.lax.Precision.HIGHEST)
         return jax.lax.top_k(sims, top_k)
 
     return jax.jit(score)

@@ -1,8 +1,8 @@
 """dcr-hbm: memory observability — static HBM accounting, live device-memory
 telemetry, and OOM forensics.
 
-The stack measured only the FLOPs half of the efficiency ledger (bench.py /
-utils/profiling.py cost analysis); the memory half — the axis the serve
+The stack measured only the FLOPs half of the efficiency ledger
+(utils/profiling.py cost analysis); the memory half — the axis the serve
 scale-out and bigger-effective-batch arcs are actually bound by — was
 invisible: ``compiled.memory_analysis()`` was never called, no
 ``device.memory_stats()`` gauge existed, and an OOM was an opaque crash with
@@ -13,7 +13,7 @@ module is the one home for all three:
   ``memory_analysis()`` of a compiled program to a plain byte dict
   (argument/output/temp/generated-code/alias + total), and
   :func:`flops_of_compiled` is the ONE ``cost_analysis()`` extraction
-  (bench.py and utils/profiling.py previously each hand-rolled their own).
+  (utils/profiling.py shares it).
   ``core/warmcache.aot_compile`` and ``tools/check/surfaces.py`` capture a
   block per compiled surface: the warm path feeds the live-surface registry
   below (and a ``memwatch/surface_memory`` trace event), the check path
@@ -93,8 +93,8 @@ _MEMORY_FIELDS = (
 def flops_of_analysis(analysis: Any) -> float:
     """FLOPs out of a ``cost_analysis()`` result, whatever its shape: older
     jax returns a per-device list of dicts, newer a single dict; either may
-    be None or lack the key. The ONE implementation behind bench.py's two
-    extractions and utils/profiling.flops_of_jitted (StepTimer MFU)."""
+    be None or lack the key. The ONE implementation, behind
+    utils/profiling.flops_of_jitted (StepTimer MFU)."""
     if isinstance(analysis, (list, tuple)):
         analysis = analysis[0] if analysis else None
     if analysis is None:

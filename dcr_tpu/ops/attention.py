@@ -18,10 +18,9 @@ import jax.numpy as jnp
 
 @functools.lru_cache(maxsize=1)
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # a backend that fails to come up raises here: it must not silently
+    # become XLA attention on some other device
+    return jax.devices()[0].platform == "tpu"
 
 
 def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

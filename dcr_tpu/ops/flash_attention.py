@@ -12,13 +12,17 @@ self-attention at 512px+ (S=4096 latent tokens). Classic FlashAttention
 - backward: recompute-based fused kernels that never materialize the S×S
   matrix. dQ: grid over q blocks, key fori-loop inside. dK/dV: 3-D grid
   (bh, k block, q block) accumulating into f32 VMEM scratch across the
-  sequential q dimension — full-sequence tensors never sit in VMEM, so the
-  kernel scales to S=16k+ within the ~16 MB/core budget. delta (= rowsum
-  do∘o) is recomputed per block in-kernel instead of being passed as a
-  full-sequence operand.
+  sequential q dimension. delta (= rowsum do∘o) is recomputed per block
+  in-kernel instead of being passed as a full-sequence operand.
+- the forward and dQ kernels keep one head's whole K and V resident in VMEM
+  (the key loop runs inside the kernel), which bounds the key length the
+  kernel can be built for within the ~16 MB/core budget: supported() refuses
+  what does not fit (RESIDENT_KV_MAX_BYTES), and those shapes take XLA
+  attention.
 
-Block sizes are tunable per call; defaults come from a measured-on-v5e policy
-(_resolve_blocks; sweep in tools/sweep_flash.py, table in BASELINE.md).
+Block sizes are tunable per call; the defaults (_resolve_blocks) came from a
+sweep with tools/sweep_flash.py, measured 2026-07-29 on a backend since
+retired; to be re-measured by the benchmark.
 
 Layout contract: [B, S, H, D] at the dispatcher, reshaped to [B*H, S, D] here.
 interpret=True runs the same kernels through the Pallas interpreter (CPU tests).
@@ -31,14 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces are unavailable when only CPU jaxlib is present
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 # legacy defaults (round-1); _resolve_blocks picks per-shape tuned values
 BLOCK_Q = 256
@@ -49,16 +46,26 @@ LANES = 128      # TPU lane count: min last-dim tile for f32 outputs
 # Dispatch threshold: below this key length XLA's fused attention wins on a
 # v5e (the S×S weight tensor still fits HBM comfortably and XLA's single
 # fused kernel beats the Pallas pipeline's overheads); at/above it the flash
-# kernel wins on memory and is competitive on time. Measured 2026-07-29 —
-# BASELINE.md "Pallas kernel table".
+# kernel wins on memory and is competitive on time (measured 2026-07-29 on a
+# backend since retired; to be re-measured by the benchmark).
 FLASH_MIN_SEQ = 2048
+
+# The forward and dQ kernels hold K and V of one head whole, each
+# double-buffered by the pipeline: 4 * sk * d * itemsize bytes of the ~16 MB
+# of VMEM a v5e core gives a kernel, most of which the [block_q, block_k] f32
+# logits of the loop body take. 4.5 MiB is the largest resident K/V that
+# compiles for v5e, forward and backward (S=9216, D=64, bf16: SD-2.1 at
+# 768 px); tests/test_chip_compile.py walks every accepted shape through the
+# chip's compiler.
+RESIDENT_KV_MAX_BYTES = 4 * 9216 * 64 * 2
 
 
 def _resolve_blocks(sq: int, sk: int, block_q: int | None,
                     block_k: int | None) -> tuple[int, int]:
     """Pick (block_q, block_k): explicit args win, else the tuned default
-    clamped so blocks divide the sequence lengths. (1024, 1024) won the v5e
-    sweep at every large shape (tools/sweep_flash.py; BASELINE.md table)."""
+    clamped so blocks divide the sequence lengths. (1024, 1024) won the
+    tools/sweep_flash.py sweep at every large shape (measured 2026-07-29 on
+    a backend since retired; to be re-measured by the benchmark)."""
     bq = block_q or min(1024, sq)
     bk = block_k or min(1024, sk)
     while sq % bq:
@@ -70,8 +77,9 @@ def _resolve_blocks(sq: int, sk: int, block_q: int | None,
 
 def supported(q: jax.Array, k: jax.Array, v: jax.Array) -> bool:
     """Kernel-capable shapes: 128 divides both sequence lengths, D fits the MXU
-    lane layout. Anything else falls back to XLA attention (correct, still
-    fused). Capability only — the dispatch *policy* is should_use()."""
+    lane layout, and one head's resident K/V fits VMEM
+    (RESIDENT_KV_MAX_BYTES). Anything else takes XLA attention (correct,
+    still fused). Capability only — the dispatch *policy* is should_use()."""
     if q.ndim != 4:
         return False
     _, sq, _, d = q.shape
@@ -81,6 +89,7 @@ def supported(q: jax.Array, k: jax.Array, v: jax.Array) -> bool:
         and sk % 128 == 0
         and d in (64, 128, 256)
         and q.dtype in (jnp.float32, jnp.bfloat16)
+        and 4 * sk * d * q.dtype.itemsize <= RESIDENT_KV_MAX_BYTES
     )
 
 
@@ -93,13 +102,13 @@ def should_use(q: jax.Array, k: jax.Array, v: jax.Array) -> bool:
 
 
 def _mem(interpret: bool) -> dict:
-    return {} if (interpret or _VMEM is None) else {"memory_space": _VMEM}
+    return {} if interpret else {"memory_space": pltpu.VMEM}
 
 
 def _compiler_params(interpret: bool, semantics: tuple[str, ...]):
     """Tell Mosaic which grid dims are embarrassingly parallel; sequential
     (accumulating) dims must be 'arbitrary'."""
-    if interpret or pltpu is None:
+    if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=semantics)}
@@ -271,10 +280,6 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, *, interpret: bool,
         **_compiler_params(interpret, ("parallel", "parallel")),
     )(q3, k3, v3, o3, do3, lse)
 
-    if pltpu is None:  # pragma: no cover - pallas-tpu metadata always imports
-        raise NotImplementedError(
-            "flash-attention backward needs jax.experimental.pallas.tpu for "
-            "its VMEM scratch accumulators; use the XLA attention fallback")
     scratch = [pltpu.VMEM((bk, d), jnp.float32),
                pltpu.VMEM((bk, d), jnp.float32)]
     dk, dv = pl.pallas_call(

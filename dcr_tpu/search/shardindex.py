@@ -26,8 +26,10 @@ restart answers its first query with ZERO XLA compiles.
 
 Exactness: with ``normalize_queries=False`` and a store built without
 ingest normalization, every score is the same float32 dot product the
-brute force computes (the contraction axis is never split), so store-backed
-results are bit-equal to ``search_folders`` on the same dump — pinned by
+brute force computes (the contraction axis is never split, and the matmul
+asks for ``precision=HIGHEST``), so store-backed results equal
+``search_folders`` on the same dump: keys exactly, scores to a few ulps (XLA
+picks a reduction order per batch and shard shape) — pinned by
 tests/test_store.py.
 """
 
@@ -66,7 +68,9 @@ def make_topk(top_k: int, normalize_queries: bool = False):
     pad rows to ``-inf`` before the on-device ``lax.top_k`` merge.
     ``normalize_queries`` bakes the copy-risk cosine convention into the
     program (the store-backed risk index); the search path leaves it off so
-    scores stay bit-equal to the brute force."""
+    scores stay equal to the brute force. The matmul asks for
+    ``precision=HIGHEST``: scores are promised as float32 dot products, and
+    a TPU's default precision multiplies in bf16 passes (2e-3 off)."""
     import jax
     import jax.numpy as jnp
 
@@ -74,7 +78,7 @@ def make_topk(top_k: int, normalize_queries: bool = False):
         if normalize_queries:
             q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True),
                                 1e-12)
-        sims = q @ feats.T
+        sims = jnp.matmul(q, feats.T, precision=jax.lax.Precision.HIGHEST)
         sims = jnp.where(valid[None, :], sims, -jnp.inf)
         return jax.lax.top_k(sims, top_k)
 

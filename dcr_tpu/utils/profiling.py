@@ -17,23 +17,31 @@ from typing import Optional
 import jax
 import numpy as np
 
-# dense peak TFLOP/s (bf16) per chip by TPU generation; used for MFU.
+# Dense bf16 peak TFLOP/s of one chip, keyed by the device_kind JAX reports
+# (lower-cased); the MFU denominator. Source of each peak beside it.
 PEAK_TFLOPS = {
-    "v4": 275.0,
-    "v5 lite": 197.0,   # v5e
-    "v5e": 197.0,
-    "v5p": 459.0,
-    "v6 lite": 918.0,   # trillium
-    "cpu": 1.0,
+    "tpu v4": 275.0,        # Google Cloud docs, "TPU v4": 275 TFLOP/s bf16
+    "tpu v5 lite": 197.0,   # Google Cloud docs, "TPU v5e": 197 TFLOP/s bf16
+    "tpu v5e": 197.0,       # same chip, the kind newer runtimes report
+    "tpu v5p": 459.0,       # Google Cloud docs, "TPU v5p": 459 TFLOP/s bf16
+    "tpu v6 lite": 918.0,   # Google Cloud docs, "TPU v6e": 918 TFLOP/s bf16
 }
 
 
-def chip_peak_tflops() -> float:
-    kind = jax.devices()[0].device_kind.lower()
-    for name, peak in PEAK_TFLOPS.items():
-        if name in kind:
-            return peak
-    return PEAK_TFLOPS["cpu"]
+def chip_peak_tflops() -> Optional[float]:
+    """Peak of the chip this process runs on; None on the CPU, which has no
+    peak on record, so CPU runs report no MFU at all. Any other device_kind
+    missing from the table is an error, never a default: an MFU against a
+    made-up peak is not a measurement."""
+    device = jax.devices()[0]
+    if device.platform == "cpu":
+        return None
+    kind = device.device_kind.lower()
+    if kind not in PEAK_TFLOPS:
+        raise ValueError(
+            f"no peak TFLOP/s on record for device_kind {kind!r}; add it to "
+            f"PEAK_TFLOPS with its source (known: {sorted(PEAK_TFLOPS)})")
+    return PEAK_TFLOPS[kind]
 
 
 @contextlib.contextmanager
@@ -162,8 +170,8 @@ def flops_of_jitted(jitted_fn, *args, **kwargs) -> float:
     """Per-device FLOPs of an already-jitted function from XLA's cost analysis
     (post-GSPMD-partitioning, so this is the per-chip share). 0 if
     unavailable. Extraction (list-vs-dict analysis shapes) lives in
-    obs/memwatch.flops_of_compiled — the ONE implementation bench.py and the
-    StepTimer MFU numbers share."""
+    obs/memwatch.flops_of_compiled — the ONE implementation the trainer's
+    and the StepTimer's MFU numbers share."""
     from dcr_tpu.obs.memwatch import flops_of_compiled
 
     try:
@@ -213,7 +221,9 @@ class StepTimer:
             achieved = self.flops_per_step * steps / dt / 1e12
             out["tflops_per_sec"] = achieved
             out["tflops_per_sec_total"] = achieved * jax.device_count()
-            out["mfu"] = achieved / chip_peak_tflops()
+            peak = chip_peak_tflops()
+            if peak:
+                out["mfu"] = achieved / peak
         if reset:
             self._t0 = time.perf_counter()
             self._steps = self._items = 0

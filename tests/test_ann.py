@@ -244,8 +244,9 @@ def test_mesh_sharded_ann_equals_single_device(tmp_path, rng_np,
     eight = open_ann_engine(store, mesh=mesh8, top_k=4, nprobe=4,
                             query_batch=8)
     s8, k8 = eight.query(q)
-    # 8-way row sharding never splits the contraction axis: bit-equal
-    np.testing.assert_array_equal(s1, s8)
+    # 8-way row sharding never splits the contraction axis, but this XLA:CPU
+    # picks a reduction order per shard shape: within 4 ulps, keys equal
+    np.testing.assert_array_max_ulp(s1, s8, maxulp=4)
     assert (k1 == k8).all()
     assert eight.segment_rows % 8 == 0
 

@@ -436,12 +436,9 @@ def _run_pod(cfg, cfg_path: Path, *, dcr_faults: str = "",
 
     save_config(cfg, cfg_path)
     env = worker_base_env(local_devices=1, inherit=True)
-    cache = os.environ.get("DCR_TEST_CACHE_DIR") or str(
-        REPO / "tests" / ".jax_cache_cpu")
     env.update(
-        DCR_TPU_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         JAX_THREEFRY_PARTITIONABLE="1",
-        JAX_COMPILATION_CACHE_DIR=cache,
         JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="1.0",
         JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0",
     )

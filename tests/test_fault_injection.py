@@ -434,19 +434,16 @@ def _run_cli(cfg, cfg_path, *, dcr_faults: str = "", timeout: int = 540):
 
     save_config(cfg, cfg_path)
     repo = Path(__file__).parent.parent
-    cache = os.environ.get("DCR_TEST_CACHE_DIR") or str(
-        repo / "tests" / ".jax_cache_cpu")
     env = dict(os.environ)
     env.pop("DCR_FAULTS", None)
     if dcr_faults:
         env["DCR_FAULTS"] = dcr_faults
     env.update(
-        DCR_TPU_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         PYTHONPATH=str(repo) + os.pathsep + env.get("PYTHONPATH", ""),
         # match the conftest jax config so trajectories are bit-identical to
         # in-process runs and the persistent compile cache is shared
         JAX_THREEFRY_PARTITIONABLE="1",
-        JAX_COMPILATION_CACHE_DIR=cache,
         JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="1.0",
         JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0",
     )

@@ -736,6 +736,8 @@ def test_serve_http_e2e_risk_and_warm_restart_zero_compiles(tmp_path,
     for k in list(env):
         if k.startswith("JAX_COMPILATION") or k.startswith("JAX_PERSISTENT"):
             env.pop(k)
+    # ... and the CLIs' setup_compile_cache() would otherwise turn it back on
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
     # train image + threshold from an offline probe of the same stack
     stack = _tiny_stack()

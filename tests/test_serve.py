@@ -415,14 +415,11 @@ def _serve_env():
     from pathlib import Path
 
     repo = Path(__file__).parent.parent
-    cache = os.environ.get("DCR_TEST_CACHE_DIR") or str(
-        repo / "tests" / ".jax_cache_cpu")
     env = dict(os.environ)
     env.update(
-        DCR_TPU_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         PYTHONPATH=str(repo) + os.pathsep + env.get("PYTHONPATH", ""),
         JAX_THREEFRY_PARTITIONABLE="1",
-        JAX_COMPILATION_CACHE_DIR=cache,
         JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="1.0",
         JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0",
     )

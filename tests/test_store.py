@@ -333,6 +333,12 @@ def test_search_folders_quarantines_unreadable_keeps_invalid(
 # 3. exact-equality pins
 # ---------------------------------------------------------------------------
 
+def _assert_scores_equal(want, got):
+    # same float32 dots, but this XLA:CPU picks a reduction order per batch
+    # and shard shape: equal within 4 ulps, not bit for bit
+    np.testing.assert_array_max_ulp(want, got, maxulp=4)
+
+
 def _equality_fixture(tmp_path, rng_np, dim=16, sizes=(10, 7, 13), n_gen=5):
     folders = _dump_folders(tmp_path, rng_np, list(sizes), dim=dim)
     gen = rng_np.standard_normal((n_gen, dim)).astype(np.float32)
@@ -346,7 +352,7 @@ def test_store_backed_equals_brute_force_single_device(
     folders, store, gen, gen_keys = _equality_fixture(tmp_path, rng_np)
     brute = S.search_folders(gen, gen_keys, folders, top_k=3, num_chunks=2)
     res = S.search_store(gen, gen_keys, store, top_k=3, query_batch=4)
-    np.testing.assert_array_equal(brute["scores"], res["scores"])
+    _assert_scores_equal(brute["scores"], res["scores"])
     assert (brute["keys"] == res["keys"]).all()
     assert list(res["gen_images"]) == gen_keys
 
@@ -363,7 +369,7 @@ def test_store_backed_equals_brute_force_single_device(
     S.run_search(cfg_store)
     with np.load(tmp_path / "brute.npz") as zb, \
             np.load(tmp_path / "store.npz") as zs:
-        np.testing.assert_array_equal(zb["scores"], zs["scores"])
+        _assert_scores_equal(zb["scores"], zs["scores"])
         assert (zb["keys"] == zs["keys"]).all()
         assert (zb["gen_images"] == zs["gen_images"]).all()
 
@@ -379,8 +385,8 @@ def test_mesh_sharded_equals_single_device(tmp_path, rng_np, cpu_devices):
     mesh8 = pmesh.make_mesh(MeshConfig(data=8))
     engine = open_engine(store, mesh=mesh8, top_k=4, query_batch=3)
     scores, keys = engine.query(gen)
-    # 8-way row sharding: same dots, same merge — bit-equal, key-equal
-    np.testing.assert_array_equal(brute["scores"], scores)
+    # 8-way row sharding: same dots, same merge — key-equal
+    _assert_scores_equal(brute["scores"], scores)
     assert (brute["keys"] == keys).all()
     # segment padded to the row-shard multiple
     assert engine.segment_rows % 8 == 0
@@ -425,7 +431,7 @@ def test_store_smaller_than_topk_pads_like_brute(tmp_path, rng_np,
     gen = rng_np.standard_normal((2, 8)).astype(np.float32)
     brute = S.search_folders(gen, ["g0", "g1"], folders, top_k=5)
     res = S.search_store(gen, ["g0", "g1"], store, top_k=5, query_batch=2)
-    np.testing.assert_array_equal(brute["scores"], res["scores"])
+    _assert_scores_equal(brute["scores"], res["scores"])
     assert (brute["keys"] == res["keys"]).all()
     assert np.isneginf(res["scores"][:, 2:]).all()
     assert (res["keys"][:, 2:] == "").all()
@@ -693,6 +699,8 @@ def test_query_warm_restart_zero_compiles(tmp_path, rng_np, cpu_devices):
     for k in list(env):
         if k.startswith("JAX_COMPILATION") or k.startswith("JAX_PERSISTENT"):
             env.pop(k)
+    # ... and the CLIs' setup_compile_cache() would otherwise turn it back on
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     warm = tmp_path / "warm"
 
     def run_query(logdir, out):

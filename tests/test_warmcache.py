@@ -551,6 +551,8 @@ def test_serve_warm_restart_readiness_and_zero_compiles(tmp_path, cpu_devices):
     for k in list(env):
         if k.startswith("JAX_COMPILATION") or k.startswith("JAX_PERSISTENT"):
             env.pop(k)
+    # ... and the CLIs' setup_compile_cache() would otherwise turn it back on
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     warm_dir = tmp_path / "warm"
 
     def start(logdir):

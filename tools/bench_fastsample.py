@@ -189,10 +189,11 @@ def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
     smoke = "--smoke" in argv
 
-    cache_dir = Path(__file__).resolve().parent.parent / ".jax_cache"
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    from dcr_tpu.cli import setup_compile_cache
+
+    setup_compile_cache()
     import numpy as np
 
     from dcr_tpu.core.config import FastSampleConfig

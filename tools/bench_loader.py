@@ -1,10 +1,11 @@
 """Host input-pipeline benchmark: images/sec through the data loader's decode
 +transform path, PIL-only vs the native libjpeg scaled-decode fast path.
 
-The TPU bench (bench.py) uses synthetic batches, so the host pipeline's
-contribution never shows up there; this tool measures it directly on CPU —
-no TPU needed. The number that matters for training is images/sec/core vs
-the chip's demand (~92 img/s/chip at 256px, BASELINE.md): a v5e host has
+A step benchmark on synthetic batches never shows the host pipeline's
+contribution; this tool measures it directly on CPU — no TPU needed. The
+number that matters for training is images/sec/core vs the chip's demand
+(~92 img/s/chip at 256px, measured 2026-07-29 on a backend since retired; to
+be re-measured by the benchmark): a v5e host has
 dozens of cores feeding each chip, so per-core decode throughput × cores
 must exceed chip demand with headroom.
 

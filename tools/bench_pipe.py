@@ -139,9 +139,9 @@ def _leg_result(steps: int, dt: float, flops: float) -> dict:
     from dcr_tpu.obs.memwatch import peak_bytes
     from dcr_tpu.utils.profiling import chip_peak_tflops
 
-    peak = chip_peak_tflops() * 1e12
+    peak = chip_peak_tflops()       # None on the CPU: no mfu there
     per_step = dt / steps
-    mfu = (flops / per_step) / peak if flops and peak > 0 else None
+    mfu = (flops / per_step) / (peak * 1e12) if flops and peak else None
     return {"steps_per_sec": round(steps / dt, 3),
             "step_ms": round(per_step * 1e3, 2),
             "gflops_per_step": round(flops / 1e9, 2) if flops else None,

@@ -21,8 +21,8 @@ Artifact registry:
   CHAOS's zero-drop + bit-identical pins) become pass/fail rows;
 - info-only artifacts (RISK overhead, SERVE/SERVE_FAST speedups) render
   as gate-less rows so the table is the one place to read progress;
-- raw run logs (BENCH_r*.json, BENCH_PROGRESS_*, BENCH_SAMPLE.jsonl) are
-  explicitly skipped, not unknown.
+- raw run logs (BENCH_PROGRESS_*, BENCH_SAMPLE.jsonl) are explicitly
+  skipped, not unknown.
 """
 
 from __future__ import annotations
@@ -34,7 +34,7 @@ import sys
 from pathlib import Path
 
 #: raw run logs and probe dumps — present at the root, not gate artifacts
-SKIP_RE = re.compile(r"^BENCH_(r\d+|PROGRESS_.*)\.json$")
+SKIP_RE = re.compile(r"^BENCH_PROGRESS_.*\.json$")
 
 
 class SchemaError(ValueError):

@@ -134,10 +134,11 @@ def main() -> None:
     steps = int(os.environ.get("BENCH_SERVE_STEPS", "4"))
     res = int(os.environ.get("BENCH_SERVE_RES", "16"))
 
-    cache_dir = Path(__file__).resolve().parent.parent / ".jax_cache"
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    from dcr_tpu.cli import setup_compile_cache
+
+    setup_compile_cache()
     print(f"bench_serve: {n_requests} requests, max_batch={max_batch}, "
           f"steps={steps}, res={res}, devices={len(jax.devices())}", flush=True)
 
@@ -527,6 +528,8 @@ def chaos_main() -> None:
     for k in list(os.environ):
         if k.startswith("JAX_COMPILATION") or k.startswith("JAX_PERSISTENT"):
             os.environ.pop(k)
+    # ... and the workers' setup_compile_cache() would otherwise turn it on
+    os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
     print(f"bench_serve --chaos: {n_requests} requests, {workers} workers, "
           f"kill every {kill_every_s}s, steps={steps}, res={res}", flush=True)
@@ -673,10 +676,11 @@ def risk_main() -> None:
     image_size = int(os.environ.get("BENCH_RISK_IMAGE_SIZE", "32"))
     index_n = int(os.environ.get("BENCH_RISK_INDEX_N", "4096"))
 
-    cache_dir = Path(__file__).resolve().parent.parent / ".jax_cache"
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    from dcr_tpu.cli import setup_compile_cache
+
+    setup_compile_cache()
     print(f"bench_serve --risk: {n_requests} requests, max_batch={max_batch},"
           f" steps={steps}, res={res}, index_n={index_n}, "
           f"image_size={image_size}", flush=True)
@@ -772,10 +776,11 @@ def fast_main() -> None:
     steps = int(os.environ.get("BENCH_FAST_SERVE_STEPS", "32"))
     res = int(os.environ.get("BENCH_SERVE_RES", "16"))
 
-    cache_dir = Path(__file__).resolve().parent.parent / ".jax_cache"
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    from dcr_tpu.cli import setup_compile_cache
+
+    setup_compile_cache()
     print(f"bench_serve --fast: {n_requests} requests, max_batch={max_batch},"
           f" steps={steps}, res={res}", flush=True)
 
