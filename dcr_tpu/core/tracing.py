@@ -8,8 +8,16 @@ numbers from:
 
 - **Span tracer** — ``with span("train/step", step=n): ...`` records one
   structured span per region: ids/parents propagated via :mod:`contextvars`
-  (so nesting is automatic within a thread), monotonic-clock durations,
-  wall-clock timestamps, rank/thread tags. Spans append to a per-process
+  (so nesting is automatic within a thread), rank/thread tags. A span's
+  start and duration are both read on ``time.perf_counter()``, the clock
+  the benchmark's window is taken on (the record's ``ts`` is a wall stamp
+  beside it, for ``trace.jsonl`` alone), and :func:`span` enters a
+  ``jax.profiler.TraceAnnotation("dcr/<name>")`` for its block, so a
+  profiler capture shows the program's spans on the host plane of the same
+  ``.xplane.pb`` as the device ops. Every span also lands in a per-name
+  in-memory record (count, total seconds, a bounded timeline of
+  ``(start, seconds)`` pairs: :func:`timeline`, :func:`span_totals`), which
+  is what the benchmark's per-layer metrics read. Spans append to a per-process
   ``trace.jsonl`` under the run directory once :func:`configure` has run;
   ``tools/trace_report.py`` turns the files into a stage-time breakdown and
   a Chrome-trace/Perfetto export. Spans may additionally carry a
@@ -34,13 +42,16 @@ numbers from:
   the final seconds of activity plus a registry snapshot, the timeline the
   post-mortems of core/coordination.py previously lacked.
 
-Performance notes: a span is one dict build + deque append + (when a trace
-file is configured) one buffered ``write`` — no locks are held across user
-code. Set ``DCR_TRACE=0`` to keep the ring buffer but skip the file on
-runs where even that is too much. Nothing here touches XLA: on-device
-dispatch is asynchronous, so a span around a jitted call measures dispatch
-(plus any host sync inside the region), which is exactly the host-side
-timeline the trainer's log-boundary ``device_get`` closes.
+Performance notes: a span is one dict build + two deque appends + (when a
+trace file is configured) one buffered ``write`` — no locks are held across
+user code, and PERF.md gives the microseconds. Set ``DCR_TRACE=0`` to keep
+the ring buffer but skip the file on runs where even that is too much. With
+no profiler session the annotation is a flag test; ``jax.profiler`` is
+imported at the first span, never at import, and no backend is brought up.
+On-device dispatch is asynchronous, so a span around a jitted call measures
+dispatch (plus any host sync inside the region): a span that is to time the
+device blocks on the result inside its block (``search/device_wait``,
+``xfer/device_wait``).
 """
 
 from __future__ import annotations
@@ -55,9 +66,8 @@ import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from pathlib import Path
-from typing import Any, Iterator, Mapping, Optional
+from typing import Any, Mapping, Optional
 
 import numpy as np
 
@@ -100,6 +110,79 @@ class _TraceState:
 
 
 _state = _TraceState()
+
+#: timeline entries kept per span name; older ones fall off the front
+TIMELINE_SPANS = 32768
+
+
+class _SpanRecord:
+    """What the process remembers of one span name. The lock is the name's
+    own, so spans of different names never wait for each other, and never
+    for ``_state.lock`` (which a slow trace file can hold)."""
+
+    __slots__ = ("lock", "count", "seconds", "timeline")
+
+    def __init__(self) -> None:
+        self.lock = threading.Lock()
+        self.count = 0
+        self.seconds = 0.0
+        self.timeline: deque = deque(maxlen=TIMELINE_SPANS)
+
+    def add(self, start: float, seconds: float) -> None:
+        with self.lock:
+            self.count += 1
+            self.seconds += seconds
+            self.timeline.append((start, seconds))
+
+
+_span_records: dict[str, _SpanRecord] = {}
+
+
+def _span_record(name: str) -> _SpanRecord:
+    rec = _span_records.get(name)
+    # setdefault is atomic: two threads' first spans of a name share a record
+    return rec if rec is not None else _span_records.setdefault(
+        name, _SpanRecord())
+
+
+def timeline(name: str) -> list[tuple[float, float]]:
+    """``(start, seconds)`` on ``time.perf_counter()`` of the last
+    ``TIMELINE_SPANS`` finished spans called ``name``, in the order they
+    ended. Spans of :func:`span` and :func:`begin_span`; a
+    :func:`complete_span` was measured elsewhere and has no start on this
+    clock."""
+    rec = _span_records.get(name)
+    if rec is None:
+        return []
+    with rec.lock:
+        return list(rec.timeline)
+
+
+def span_totals() -> dict[str, dict]:
+    """{name: {"count", "seconds"}} over the whole life of the process (the
+    timeline forgets; these do not). Rides every flight-recorder dump."""
+    out = {}
+    for name, rec in sorted(list(_span_records.items())):   # list(): atomic
+        with rec.lock:
+            out[name] = {"count": rec.count, "seconds": rec.seconds}
+    return out
+
+
+_trace_annotation: Any = None
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation("dcr/<name>")``: an event on the host
+    plane of a profiler capture, a flag test when no session is open.
+    Imported at the first span (importing jax starts no backend)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation("dcr/" + name)
+
+
 _current_span: contextvars.ContextVar[Optional[int]] = contextvars.ContextVar(
     "dcr_current_span", default=None)
 # the distributed trace id (a 16-hex-char token) the current span belongs to.
@@ -219,15 +302,16 @@ class SpanHandle:
         self.parent = parent
         self.trace = trace
         self.attrs = attrs
-        self._t0_wall = time.time()
-        self._t0 = time.monotonic()
+        self._t0_wall = time.time()         # the record's `ts`, nothing else
+        self._t0 = time.perf_counter()
         self._done = False
 
     def end(self, **extra: Any) -> None:
         if self._done:          # idempotent: future callbacks can race .end()
             return
         self._done = True
-        dur = time.monotonic() - self._t0
+        dur = time.perf_counter() - self._t0
+        _span_record(self.name).add(self._t0, dur)
         rec = {"ph": _PH_SPAN, "name": self.name, "id": self.id,
                "parent": self.parent, "ts": round(self._t0_wall * 1e6),
                "dur": round(dur * 1e6), "pid": _rank(),
@@ -248,25 +332,44 @@ def begin_span(name: str, *, parent: Optional[int] = None,
                       attrs)
 
 
-@contextmanager
+class _BlockSpan(SpanHandle):
+    """What :func:`span` returns: a :class:`SpanHandle` that, for its
+    ``with`` block, is the current span (contextvars) and a ``dcr/<name>``
+    annotation in a profiler capture. A class and not a generator: a span
+    sits on hot paths (seven of them in one 17 ms search call)."""
+
+    __slots__ = ("_token", "_trace_token", "_annotation")
+
+    def __enter__(self) -> SpanHandle:
+        self._token = _current_span.set(self.id)
+        self._trace_token = _current_trace.set(self.trace)
+        self._annotation = _annotation(self.name)
+        self._annotation.__enter__()
+        self._t0_wall = time.time()     # the block's start, not the call's
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._annotation.__exit__(exc_type, exc, tb)
+        _current_trace.reset(self._trace_token)
+        _current_span.reset(self._token)
+        if exc is None:
+            self.end()
+        else:
+            self.end(error=repr(exc))
+
+
 def span(name: str, *, parent: Optional[int] = None,
-         trace: Optional[str] = None, **attrs: Any) -> Iterator[SpanHandle]:
-    """Record the block as one span. Parent (and distributed-trace id)
+         trace: Optional[str] = None, **attrs: Any) -> _BlockSpan:
+    """Record a ``with`` block as one span. Parent (and distributed-trace id)
     default to the enclosing span in this context (contextvars), so nesting
     is automatic; an exception in the block is recorded as an ``error`` attr
-    and re-raised unchanged."""
-    h = begin_span(name, parent=parent, trace=trace, **attrs)
-    token = _current_span.set(h.id)
-    trace_token = _current_trace.set(h.trace)
-    try:
-        yield h
-    except BaseException as e:
-        h.end(error=repr(e))
-        raise
-    finally:
-        _current_trace.reset(trace_token)
-        _current_span.reset(token)
-        h.end()
+    and re-raised unchanged. The block is also a ``dcr/<name>`` annotation
+    in a profiler capture (a :func:`begin_span`, whose end is not lexical,
+    is not)."""
+    return _BlockSpan(
+        name, parent if parent is not None else _current_span.get(),
+        trace if trace is not None else _current_trace.get(), attrs)
 
 
 def event(name: str, *, parent: Optional[int] = None,
@@ -620,6 +723,7 @@ def dump_flight_recorder(reason: str, *,
         "os_pid": os.getpid(),
         "memory": memory,
         "records": flight_records(),
+        "span_totals": span_totals(),
         "registry": _registry.snapshot(),
         **(extra or {}),
     }
@@ -683,4 +787,5 @@ def reset_for_tests() -> None:
         _state.max_bytes = 0
         _state.bytes_written = 0
         _state.ring.clear()
+    _span_records.clear()
     _registry.reset()

@@ -192,17 +192,36 @@ class DataLoader:
 
         threads = [threading.Thread(target=worker, args=(w,), daemon=True)
                    for w in range(self.num_workers)]
-        for t in threads:
-            t.start()
         pending: dict[int, Batch] = {}
+
+        def take(step: int) -> Batch:
+            while step not in pending:
+                got_step, batch, err = out_q.get()
+                if err is not None:
+                    raise err
+                pending[got_step] = batch
+            return pending.pop(step)
+
+        # consumer-side spans, children of the caller's train/data_wait:
+        # data/fill is the refill bubble every epoch opens with (workers'
+        # start to the first batch in hand), data/wait each later wait on
+        # the queue. Neither stays open across a yield: the generator hands
+        # control back with the context variable as it found it.
+        tracing.registry().gauge("data/workers").set(self.num_workers)
         try:
-            for step in range(start_step, steps):
-                while step not in pending:
-                    got_step, batch, err = out_q.get()
-                    if err is not None:
-                        raise err
-                    pending[got_step] = batch
-                yield pending.pop(step)
+            if start_step < steps:
+                with tracing.span("data/fill", epoch=epoch, step=start_step):
+                    for t in threads:
+                        t.start()
+                    batch = take(start_step)
+                yield batch
+            for step in range(start_step + 1, steps):
+                if step in pending:     # came out of order, already in hand
+                    batch = pending.pop(step)
+                else:
+                    with tracing.span("data/wait", step=step, epoch=epoch):
+                        batch = take(step)
+                yield batch
         finally:
             stop.set()
             # drain until every worker has exited (safe_put re-checks stop, so

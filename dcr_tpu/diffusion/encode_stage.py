@@ -236,11 +236,12 @@ def make_denoise_step(cfg: TrainConfig, models: DiffusionModels,
         keys = {name: rngmod.step_key(rngmod.stream_key(root_key, name), step)
                 for name in DENOISER_STREAMS}
 
-        noise = jax.random.normal(keys["noise"], latents.shape)
-        timesteps = jax.random.randint(keys["timesteps"], (bsz,), 0,
-                                       sched.num_train_timesteps)
-        noisy_latents = S.add_noise(sched, latents, noise, timesteps)
-        target = S.training_target(sched, latents, noise, timesteps)
+        with jax.named_scope("noising"):
+            noise = jax.random.normal(keys["noise"], latents.shape)
+            timesteps = jax.random.randint(keys["timesteps"], (bsz,), 0,
+                                           sched.num_train_timesteps)
+            noisy_latents = S.add_noise(sched, latents, noise, timesteps)
+            target = S.training_target(sched, latents, noise, timesteps)
 
         def loss_fn(trainable):
             if cfg.train_text_encoder:
@@ -263,13 +264,17 @@ def make_denoise_step(cfg: TrainConfig, models: DiffusionModels,
             pred = unet_apply(policy.cast_to_compute(trainable["unet"]),
                               policy.cast_to_compute(noisy_latents), timesteps,
                               policy.cast_to_compute(ctx))
-            return jnp.mean((pred.astype(jnp.float32) - target) ** 2)
+            with jax.named_scope("loss"):
+                return jnp.mean((pred.astype(jnp.float32) - target) ** 2)
 
         trainable = hot_trainable(hot)
         loss, grads = jax.value_and_grad(loss_fn)(trainable)
-        grad_norm = optax.global_norm(grads)
+        # the same scopes as the fused step (train.py); `tx` scopes its own
+        with jax.named_scope("grad_clip"):
+            grad_norm = optax.global_norm(grads)
         updates, new_opt_state = tx.update(grads, hot.opt_state, trainable)
-        new_trainable = optax.apply_updates(trainable, updates)
+        with jax.named_scope("optimizer"):
+            new_trainable = optax.apply_updates(trainable, updates)
 
         new_unet = new_trainable["unet"]
         new_ema = hot.ema_params
@@ -281,9 +286,10 @@ def make_denoise_step(cfg: TrainConfig, models: DiffusionModels,
                 applied = new_opt_state.mini_step == 0
             else:
                 applied = jnp.asarray(True)
-            new_ema = jax.tree.map(
-                lambda e, p: jnp.where(applied, d * e + (1.0 - d) * p, e),
-                hot.ema_params, new_unet)
+            with jax.named_scope("ema"):
+                new_ema = jax.tree.map(
+                    lambda e, p: jnp.where(applied, d * e + (1.0 - d) * p, e),
+                    hot.ema_params, new_unet)
         new_hot = HotState(
             step=step + 1,
             unet_params=new_unet,

@@ -101,6 +101,19 @@ def make_lr_schedule(cfg: OptimConfig) -> optax.Schedule:
     raise ValueError(f"unknown lr_scheduler {cfg.lr_scheduler!r}")
 
 
+def _scoped(name: str, inner: optax.GradientTransformation
+            ) -> optax.GradientTransformation:
+    """``inner`` with its update under ``jax.named_scope(name)``: the device
+    trace can then tell AdamW's elementwise traffic from GroupNorm's (neither
+    is a Flax module, whose calls Flax names itself). Metadata only: state,
+    arithmetic and the lowered text are ``inner``'s."""
+    def update(updates, state, params=None):
+        with jax.named_scope(name):
+            return inner.update(updates, state, params)
+
+    return optax.GradientTransformation(inner.init, update)
+
+
 def make_optimizer(cfg: OptimConfig) -> optax.GradientTransformation:
     """AdamW with global-norm clipping and optional scan-free grad accumulation
     (reference: AdamW diff_train.py:424-446, clip 657-663, accumulate 618;
@@ -114,7 +127,9 @@ def make_optimizer(cfg: OptimConfig) -> optax.GradientTransformation:
         b1=cfg.adam_beta1, b2=cfg.adam_beta2,
         eps=cfg.adam_epsilon, weight_decay=cfg.adam_weight_decay,
     )
-    tx = optax.chain(optax.clip_by_global_norm(cfg.max_grad_norm), adam)
+    tx = optax.chain(
+        _scoped("grad_clip", optax.clip_by_global_norm(cfg.max_grad_norm)),
+        _scoped("optimizer", adam))
     if cfg.gradient_accumulation_steps > 1:
         tx = optax.MultiSteps(tx, cfg.gradient_accumulation_steps)
     return tx
@@ -202,11 +217,12 @@ def make_train_step(cfg: TrainConfig, models: DiffusionModels,
         latents = dist.sample(keys["vae_sample"]) * models.vae.config.vae_scaling_factor
         latents = latents.astype(jnp.float32)
 
-        noise = jax.random.normal(keys["noise"], latents.shape)
-        timesteps = jax.random.randint(keys["timesteps"], (bsz,), 0,
-                                       sched.num_train_timesteps)
-        noisy_latents = S.add_noise(sched, latents, noise, timesteps)
-        target = S.training_target(sched, latents, noise, timesteps)
+        with jax.named_scope("noising"):
+            noise = jax.random.normal(keys["noise"], latents.shape)
+            timesteps = jax.random.randint(keys["timesteps"], (bsz,), 0,
+                                           sched.num_train_timesteps)
+            noisy_latents = S.add_noise(sched, latents, noise, timesteps)
+            target = S.training_target(sched, latents, noise, timesteps)
 
         def text_encode(text_params):
             out = models.text_encoder.apply(
@@ -233,13 +249,17 @@ def make_train_step(cfg: TrainConfig, models: DiffusionModels,
             pred = unet_apply(policy.cast_to_compute(trainable["unet"]),
                               policy.cast_to_compute(noisy_latents), timesteps,
                               policy.cast_to_compute(ctx))
-            return jnp.mean((pred.astype(jnp.float32) - target) ** 2)
+            with jax.named_scope("loss"):
+                return jnp.mean((pred.astype(jnp.float32) - target) ** 2)
 
         trainable = trainable_of(state, cfg.train_text_encoder)
         loss, grads = jax.value_and_grad(loss_fn)(trainable)
-        grad_norm = optax.global_norm(grads)
+        with jax.named_scope("grad_clip"):      # the norm the clip takes too
+            grad_norm = optax.global_norm(grads)
+        # `tx` scopes its own parts (make_optimizer): grad_clip, optimizer
         updates, new_opt_state = tx.update(grads, state.opt_state, trainable)
-        new_trainable = optax.apply_updates(trainable, updates)
+        with jax.named_scope("optimizer"):
+            new_trainable = optax.apply_updates(trainable, updates)
 
         new_unet = new_trainable["unet"]
         new_ema = state.ema_params
@@ -251,9 +271,10 @@ def make_train_step(cfg: TrainConfig, models: DiffusionModels,
                 applied = new_opt_state.mini_step == 0
             else:
                 applied = jnp.asarray(True)
-            new_ema = jax.tree.map(
-                lambda e, p: jnp.where(applied, d * e + (1.0 - d) * p, e),
-                state.ema_params, new_unet)
+            with jax.named_scope("ema"):
+                new_ema = jax.tree.map(
+                    lambda e, p: jnp.where(applied, d * e + (1.0 - d) * p, e),
+                    state.ema_params, new_unet)
         new_state = TrainState(
             step=step + 1,
             unet_params=new_unet,

@@ -961,8 +961,8 @@ class Trainer:
 
                             # flops_per_step is the per-chip share (post-partition
                             # cost analysis): per-chip achieved / per-chip peak =
-                            # MFU. One naming convention with StepTimer.report:
-                            # bare tflops_per_sec is PER-DEVICE, _total is the job.
+                            # MFU. Bare tflops_per_sec is PER-DEVICE, _total is
+                            # the job.
                             steps_done = imgs_last / global_bs
                             per_chip = flops_per_step * steps_done / max(dt, 1e-9)
                             metrics["tflops_per_sec"] = per_chip / 1e12

@@ -94,7 +94,7 @@ def flops_of_analysis(analysis: Any) -> float:
     """FLOPs out of a ``cost_analysis()`` result, whatever its shape: older
     jax returns a per-device list of dicts, newer a single dict; either may
     be None or lack the key. The ONE implementation, behind
-    utils/profiling.flops_of_jitted (StepTimer MFU)."""
+    utils/profiling.flops_of_jitted (the trainer's MFU)."""
     if isinstance(analysis, (list, tuple)):
         analysis = analysis[0] if analysis else None
     if analysis is None:

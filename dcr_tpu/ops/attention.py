@@ -43,5 +43,7 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    mask: Optional[jax.Array]) -> jax.Array:
     # jax.nn.dot_product_attention takes the same BSHD layout and scaling and
-    # lets XLA pick its fused implementation.
-    return jax.nn.dot_product_attention(q, k, v, mask=mask)
+    # lets XLA pick its fused implementation. The scope tells this path from
+    # the Pallas kernels (named flash_*) in a device trace.
+    with jax.named_scope("attention_xla"):
+        return jax.nn.dot_product_attention(q, k, v, mask=mask)

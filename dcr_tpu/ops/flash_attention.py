@@ -178,6 +178,7 @@ def _flash_fwd(q3: jax.Array, k3: jax.Array, v3: jax.Array, *,
             jax.ShapeDtypeStruct((bh, sq, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
         **_compiler_params(interpret, ("parallel", "parallel")),
     )(q3, k3, v3)
     return out, lse
@@ -277,6 +278,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, *, interpret: bool,
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0), **mem),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
         **_compiler_params(interpret, ("parallel", "parallel")),
     )(q3, k3, v3, o3, do3, lse)
 
@@ -303,6 +305,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, *, interpret: bool,
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_bwd_dkv",
         **_compiler_params(interpret, ("parallel", "parallel", "arbitrary")),
     )(q3, k3, v3, o3, do3, lse)
     return dq, dk, dv

@@ -21,6 +21,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dcr_tpu.core import tracing
 from dcr_tpu.core.config import MeshConfig
 
 DATA_AXIS = "data"
@@ -105,18 +106,26 @@ def fsdp_sharding_for_params(mesh: Mesh, params, min_size: int = 2 ** 16):
 def to_host(x) -> np.ndarray:
     """Fetch a (possibly globally-sharded) device array to host numpy on every
     process. Single-process: plain device_get. Multi-host: the array's shards
-    are not all addressable locally, so all-gather across processes first."""
-    if jax.process_count() == 1:
-        return np.asarray(jax.device_get(x))
-    from jax.experimental import multihost_utils
+    are not all addressable locally, so all-gather across processes first.
 
-    from dcr_tpu.core import dist
+    Two spans split what a fetch costs: ``xfer/device_wait`` is the wait for
+    the program that makes ``x`` (device time, nothing to win on the host),
+    ``xfer/d2h`` the copy alone (the gather too, across hosts), during which
+    a caller that fetches batch by batch has nothing in flight."""
+    with tracing.span("xfer/device_wait"):
+        jax.block_until_ready(x)
+    with tracing.span("xfer/d2h"):
+        if jax.process_count() == 1:
+            return np.asarray(jax.device_get(x))
+        from jax.experimental import multihost_utils
 
-    # bounded: a host that died mid-eval turns this into a BarrierTimeout
-    # with a name, instead of every surviving rank hanging in the gather
-    return np.asarray(dist.run_with_timeout(
-        lambda: multihost_utils.process_allgather(x, tiled=True),
-        dist.default_allgather_timeout_s(), name="to_host"))
+        from dcr_tpu.core import dist
+
+        # bounded: a host that died mid-eval turns this into a BarrierTimeout
+        # with a name, instead of every surviving rank hanging in the gather
+        return np.asarray(dist.run_with_timeout(
+            lambda: multihost_utils.process_allgather(x, tiled=True),
+            dist.default_allgather_timeout_s(), name="to_host"))
 
 
 @contextmanager

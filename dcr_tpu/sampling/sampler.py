@@ -88,15 +88,19 @@ def scheduler_step(sampler: str, sched, pred: jax.Array, x: jax.Array,
     the single dispatch both the bulk pipeline (:func:`make_sampler`) and the
     serving worker (dcr_tpu/serve/worker.py) call, so a scheduler-parity fix
     lands in every generation path at once. Returns ``(x_new, dpm_state)``;
-    ``noise_key`` is required only for the ancestral ``ddpm`` sampler."""
-    if sampler == "ddim":
-        return S.ddim_step(sched, pred, x, t, prev_t), dpm_state
-    if sampler == "dpm++":
-        return S.dpmpp_2m_step(sched, pred, x, t, prev_t, dpm_state,
-                               force_first_order=force_first_order)
-    if sampler == "ddpm":
-        assert noise_key is not None, "ddpm needs a per-step noise key"
-        return S.ddpm_step(sched, pred, x, t, prev_t, noise_key), dpm_state
+    ``noise_key`` is required only for the ancestral ``ddpm`` sampler. The
+    update runs under ``jax.named_scope("scheduler_step")`` (it is no Flax
+    module, so nothing else names it in a device trace)."""
+    with jax.named_scope("scheduler_step"):
+        if sampler == "ddim":
+            return S.ddim_step(sched, pred, x, t, prev_t), dpm_state
+        if sampler == "dpm++":
+            return S.dpmpp_2m_step(sched, pred, x, t, prev_t, dpm_state,
+                                   force_first_order=force_first_order)
+        if sampler == "ddpm":
+            assert noise_key is not None, "ddpm needs a per-step noise key"
+            return (S.ddpm_step(sched, pred, x, t, prev_t, noise_key),
+                    dpm_state)
     raise ValueError(f"unknown sampler {sampler!r}")
 
 
@@ -164,8 +168,9 @@ def make_sampler(cfg: SampleConfig, models: DiffusionModels, mesh):
                 tb = jnp.full((2 * bsz,), t, jnp.int32)
                 pred = models.unet.apply({"params": params["unet"]},
                                          jnp.concatenate([x, x], axis=0), tb, ctx)
-                pred_uncond, pred_cond = jnp.split(pred, 2, axis=0)
-                return pred_uncond + guidance * (pred_cond - pred_uncond)
+                with jax.named_scope("cfg"):
+                    pred_uncond, pred_cond = jnp.split(pred, 2, axis=0)
+                    return pred_uncond + guidance * (pred_cond - pred_uncond)
 
             if use_fast:
                 pred, bank = fastsample.predict_or_reuse(

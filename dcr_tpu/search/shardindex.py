@@ -281,11 +281,17 @@ class ShardedTopK:
         reg = tracing.registry()
         reg.counter("search/query_total").inc()
         reg.counter("search/query_rows_total").inc(n)
-        chunks = self._chunked_queries(q)
-        segments = (self._dev_segments if self.resident
-                    else map(self._put_segment, self._segments))
-        for si, seg in enumerate(segments):
-            self._scan_segment(si, seg, chunks, out_scores, out_keys)
+        # the root of one call; its children (search/put, search/topk with
+        # dispatch / device_wait / fetch inside it, search/merge) say what
+        # the call was made of
+        with tracing.span("search/query", rows=n,
+                          chunks=-(-n // self.query_batch),
+                          segments=self.num_segments):
+            chunks = self._chunked_queries(q)
+            segments = (self._dev_segments if self.resident
+                        else map(self._put_segment, self._segments))
+            for si, seg in enumerate(segments):
+                self._scan_segment(si, seg, chunks, out_scores, out_keys)
         return out_scores, out_keys
 
     def _chunked_queries(self, q: np.ndarray) -> list[tuple[int, int, object]]:
@@ -295,15 +301,16 @@ class ShardedTopK:
         import jax
 
         chunks: list[tuple[int, int, object]] = []
-        for start in range(0, q.shape[0], self.query_batch):
-            chunk = q[start:start + self.query_batch]
-            m = chunk.shape[0]
-            if m < self.query_batch:
-                chunk = np.concatenate(
-                    [chunk, np.repeat(chunk[-1:], self.query_batch - m,
-                                      axis=0)])
-            chunks.append((start, m,
-                           jax.device_put(chunk, self._q_sharding)))
+        with tracing.span("search/put"):
+            for start in range(0, q.shape[0], self.query_batch):
+                chunk = q[start:start + self.query_batch]
+                m = chunk.shape[0]
+                if m < self.query_batch:
+                    chunk = np.concatenate(
+                        [chunk, np.repeat(chunk[-1:], self.query_batch - m,
+                                          axis=0)])
+                chunks.append((start, m,
+                               jax.device_put(chunk, self._q_sharding)))
         return chunks
 
     def _scan_segment(self, si: int, seg, chunks, out_scores: np.ndarray,
@@ -316,15 +323,24 @@ class ShardedTopK:
             with tracing.span("search/topk", segment=si,
                               rows=int(n_rows), batch=m,
                               index_size=self.reader.total):
-                scores, idx = self._fn(feats, valid, chunk_dev)
-                scores = np.asarray(scores)[:m]
-                idx = np.asarray(idx)[:m]
+                with tracing.span("search/dispatch"):
+                    scores, idx = self._fn(feats, valid, chunk_dev)
+                # one host call that returns when the fetch would have: the
+                # device's share of the call, apart from the copies below
+                # (both results are one program's: one is ready, both are;
+                # waiting on the pair costs 0.05 ms a call more on a v5e)
+                with tracing.span("search/device_wait"):
+                    scores.block_until_ready()
+                with tracing.span("search/fetch"):
+                    scores = np.asarray(scores)[:m]
+                    idx = np.asarray(idx)[:m]
             reg.counter("search/segments_scanned_total").inc()
-            # pad hits (score -inf) keep key "" — invisible post-merge
-            seg_keys = np.where(np.isneginf(scores), "", keys[idx])
-            sl = slice(start, start + m)
-            out_scores[sl], out_keys[sl] = merge_topk(
-                out_scores[sl], out_keys[sl], scores, seg_keys)
+            with tracing.span("search/merge"):
+                # pad hits (score -inf) keep key "" — invisible post-merge
+                seg_keys = np.where(np.isneginf(scores), "", keys[idx])
+                sl = slice(start, start + m)
+                out_scores[sl], out_keys[sl] = merge_topk(
+                    out_scores[sl], out_keys[sl], scores, seg_keys)
 
     def query_rows(self, q: np.ndarray, feats: np.ndarray,
                    keys: Sequence[str]) -> tuple[np.ndarray, np.ndarray]:

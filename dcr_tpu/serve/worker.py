@@ -156,8 +156,9 @@ def make_batch_sampler(bucket: GenBucket, models, root_seed: int,
                 pred = models.unet.apply({"params": params["unet"]},
                                          jnp.concatenate([x, x], axis=0), tb,
                                          ctx)
-                pred_uncond, pred_cond = jnp.split(pred, 2, axis=0)
-                return pred_uncond + guidance * (pred_cond - pred_uncond)
+                with jax.named_scope("cfg"):
+                    pred_uncond, pred_cond = jnp.split(pred, 2, axis=0)
+                    return pred_uncond + guidance * (pred_cond - pred_uncond)
 
             if use_fast:
                 # elementwise over the batch, plan uniform per bucket: row

@@ -1,21 +1,21 @@
 """Profiling + MFU telemetry.
 
 The reference has no tracing at all (SURVEY.md §5.1 — its closest artifact is
-MetricLogger's iter/data timing). On TPU this is cheap and first-class:
-jax.profiler trace capture around any code region, a step timer, and
-model-FLOPs-utilization accounting against the chip's peak.
+MetricLogger's iter/data timing). Here: the chips' published peaks, the
+per-device FLOPs of a jitted step from XLA's cost analysis (the trainer's
+``mfu``), and an armer that captures the next K hot regions with
+``jax.profiler`` (``DCR_PROFILE_AT_STEP``, ``POST /debug/profile``); the
+capture shows core/tracing's spans as ``dcr/<name>`` host events beside the
+device ops.
 """
 
 from __future__ import annotations
 
 import contextlib
 import threading
-import time
-from dataclasses import dataclass, field
 from typing import Optional
 
 import jax
-import numpy as np
 
 # Dense bf16 peak TFLOP/s of one chip, keyed by the device_kind JAX reports
 # (lower-cased); the MFU denominator. Source of each peak beside it.
@@ -44,21 +44,11 @@ def chip_peak_tflops() -> Optional[float]:
     return PEAK_TFLOPS[kind]
 
 
-@contextlib.contextmanager
-def trace(logdir: str):
-    """jax.profiler trace capture around a region; view with tensorboard."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
 class _ProfileArmer:
     """On-demand device profiling: arm once, capture the next K hot regions.
 
-    The imperative sibling of :func:`trace` for long-lived processes where
-    nobody can wrap the hot loop in a ``with`` block after the fact: a serve
+    For long-lived processes where nobody can wrap the hot loop in a
+    ``with`` block after the fact: a serve
     worker arms via ``POST /debug/profile``, the trainer via
     ``DCR_PROFILE_AT_STEP`` — both then pass every hot region (device step /
     train step) through :meth:`capture`, which starts the jax.profiler trace
@@ -170,61 +160,10 @@ def flops_of_jitted(jitted_fn, *args, **kwargs) -> float:
     """Per-device FLOPs of an already-jitted function from XLA's cost analysis
     (post-GSPMD-partitioning, so this is the per-chip share). 0 if
     unavailable. Extraction (list-vs-dict analysis shapes) lives in
-    obs/memwatch.flops_of_compiled — the ONE implementation the trainer's
-    and the StepTimer's MFU numbers share."""
+    obs/memwatch.flops_of_compiled, the one implementation."""
     from dcr_tpu.obs.memwatch import flops_of_compiled
 
     try:
         return flops_of_compiled(jitted_fn.lower(*args, **kwargs).compile())
     except Exception:
         return 0.0
-
-
-def compiled_flops(fn, *args, **kwargs) -> Optional[float]:
-    """FLOPs estimate of a function from XLA's cost analysis."""
-    return flops_of_jitted(jax.jit(fn), *args, **kwargs) or None
-
-
-@dataclass
-class StepTimer:
-    """Steady-state step timing + images/sec + MFU.
-
-    ``flops_per_step`` is the PER-DEVICE FLOP share (what
-    :func:`flops_of_jitted` returns: post-GSPMD-partitioning cost analysis),
-    so MFU is per-device achieved over per-device peak — dividing by
-    ``device_count`` again, as an earlier revision did, under-reported MFU by
-    exactly that factor. ``tflops_per_sec`` stays the per-device rate the
-    flops input implies; ``tflops_per_sec_total`` scales it to the whole job.
-    """
-
-    flops_per_step: Optional[float] = None
-    _t0: float = field(default_factory=time.perf_counter)
-    _steps: int = 0
-    _items: int = 0
-
-    def tick(self, items: int = 0) -> None:
-        self._steps += 1
-        self._items += items
-
-    def report(self, reset: bool = True) -> dict:
-        dt = time.perf_counter() - self._t0
-        steps = max(self._steps, 1)
-        out = {
-            "step_time_ms": 1e3 * dt / steps,
-            "steps_per_sec": steps / dt if dt > 0 else float("inf"),
-        }
-        if self._items:
-            out["items_per_sec"] = self._items / dt
-        if self.flops_per_step:
-            # per-device achieved TFLOP/s vs per-device peak: both sides of
-            # the MFU ratio are per-chip, so device_count cancels
-            achieved = self.flops_per_step * steps / dt / 1e12
-            out["tflops_per_sec"] = achieved
-            out["tflops_per_sec_total"] = achieved * jax.device_count()
-            peak = chip_peak_tflops()
-            if peak:
-                out["mfu"] = achieved / peak
-        if reset:
-            self._t0 = time.perf_counter()
-            self._steps = self._items = 0
-        return out

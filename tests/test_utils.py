@@ -4,7 +4,7 @@ import pytest
 pytestmark = pytest.mark.fast
 
 from dcr_tpu.eval import retrieval_metrics as RM
-from dcr_tpu.utils import profiling, provenance
+from dcr_tpu.utils import provenance
 
 
 def test_retrieval_metrics_perfect_ranking():
@@ -27,52 +27,6 @@ def test_average_precision_edge_cases():
     assert np.isnan(RM.average_precision([False, False], 0))
     assert RM.average_precision([False, False], 2) == 0.0
     assert RM.average_precision([True, True], 2) == 1.0
-
-
-def test_step_timer_and_mfu():
-    t = profiling.StepTimer(flops_per_step=1e9)
-    for _ in range(3):
-        t.tick(items=4)
-    rep = t.report()
-    assert rep["steps_per_sec"] > 0
-    assert rep["items_per_sec"] > 0
-    assert rep["tflops_per_sec"] >= 0
-    assert "mfu" not in rep     # the CPU has no peak on record
-
-
-def test_step_timer_mfu_formula_is_per_device(monkeypatch):
-    """Pin the MFU formula: flops_per_step is the PER-DEVICE share
-    (flops_of_jitted is post-GSPMD cost analysis), so
-    mfu = (flops_per_step * steps / dt) / (peak * 1e12) with NO device_count
-    in the denominator — a run achieving exactly per-chip peak reports
-    mfu == 1.0 whatever the device count (the old formula divided by
-    device_count and under-reported by that factor). The CPU has no peak on
-    record (and reports no mfu), so the test stands in a v5e's."""
-    import jax
-
-    n_dev = jax.device_count()
-    assert n_dev > 1  # conftest forces 8 virtual devices; the regression
-    #                   is only observable with more than one
-    peak_tflops = profiling.PEAK_TFLOPS["tpu v5 lite"]
-    monkeypatch.setattr(profiling, "chip_peak_tflops", lambda: peak_tflops)
-    t = profiling.StepTimer(flops_per_step=peak_tflops * 1e12)  # peak/step/chip
-    t._t0 -= 1.0                      # pretend exactly 1s elapsed
-    monkeypatch.setattr(profiling.time, "perf_counter", lambda: t._t0 + 1.0)
-    t.tick(items=1)
-    rep = t.report()
-    assert rep["mfu"] == pytest.approx(1.0, rel=1e-6)
-    assert rep["tflops_per_sec"] == pytest.approx(peak_tflops, rel=1e-6)
-    assert rep["tflops_per_sec_total"] == pytest.approx(peak_tflops * n_dev,
-                                                        rel=1e-6)
-
-
-def test_compiled_flops_returns_positive():
-    import jax.numpy as jnp
-
-    flops = profiling.compiled_flops(lambda a, b: a @ b,
-                                     jnp.zeros((64, 64)), jnp.zeros((64, 64)))
-    if flops is not None:
-        assert flops >= 2 * 64 ** 3 * 0.9
 
 
 def test_provenance_stamp(tmp_path):

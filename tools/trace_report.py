@@ -247,6 +247,18 @@ _CATEGORIES = (
 )
 
 
+# spans that lie inside, or round, a span the stage table already counts:
+# search/query encloses search/topk, whose own children are dispatch,
+# device_wait and fetch; the loader's fill and wait are the consumer's
+# train/data_wait seen from inside. They keep their rows under
+# "per-span-name" and stay out of the stage table, so that no second is
+# counted twice there. (xfer/* is a category of its own: bulk sampling
+# fetches under no other span.)
+NESTED_SPANS = frozenset({
+    "search/query", "search/dispatch", "search/device_wait", "search/fetch",
+    "data/fill", "data/wait"})
+
+
 def category_of(name: str) -> str:
     for prefix, cat in _CATEGORIES:
         if name.startswith(prefix):
@@ -872,6 +884,8 @@ def summarize(records: list[dict], meta: dict | None = None) -> dict:
             "p99_ms": round(_percentile(durs_sorted, 99), 3),
         }
         names[name] = row
+        if name in NESTED_SPANS:
+            continue
         cat = categories.setdefault(category_of(name), {"count": 0, "total_ms": 0.0})
         cat["count"] += row["count"]
         cat["total_ms"] = round(cat["total_ms"] + row["total_ms"], 3)
