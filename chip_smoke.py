@@ -62,9 +62,6 @@ SIZE = {
     "model_argv": (),                     # extra --model.* overrides
     "samples": ((256, 4), (512, 1)),      # (resolution, images in the batch)
     "sample_steps": 50,
-    # self-attention sites of the UNet with >= FLASH_MIN_SEQ tokens at 512 px:
-    # the top level's 2 down + 3 up transformer blocks (4096 tokens each)
-    "flash_sites": 5,
     "embed_px": 224,
     "embed_batch": 32,
 }
@@ -389,12 +386,35 @@ def phase_sample() -> dict:
     texts = [p.read_text() for p in ir_dir.glob("*sample_fn*")]
     check(len(texts) > 0, f"no sampler program was dumped under {ir_dir}")
     calls = max(text.count("tpu_custom_call") for text in texts)
-    check(calls == SIZE["flash_sites"],
+    expected = flash_sites(*SIZE["samples"][-1])
+    check(calls == expected,
           f"{calls} tpu_custom_call in the {SIZE['samples'][-1][0]} px "
-          f"sampler, expected {SIZE['flash_sites']}")
+          f"sampler, expected {expected}")
     result.update(tpu_custom_calls_in_lowered_sampler=calls,
                   kernels_in_interpret_mode=0)
     return result
+
+
+def flash_sites(px: int, images: int) -> int:
+    """Self-attention sites of the exported UNet that the dispatcher hands the
+    Pallas kernel in the sampler at `px`: float32, two rows an image (CFG).
+    At SD-2.1 widths on the chip that is the top level's 2 down + 3 up
+    transformer blocks (4,096 tokens at 512 px; 1,024 at 256 px from 3 images
+    a batch on); none off the TPU."""
+    import jax
+    import jax.numpy as jnp
+
+    from dcr_tpu.core.config import ModelConfig, from_dict
+    from dcr_tpu.models.unet2d import self_attention_shapes
+    from dcr_tpu.ops import attention
+
+    index = json.loads(
+        (WORK / "run" / "checkpoint" / "model_index.json").read_text())
+    cfg = from_dict(ModelConfig, index["model_config"])
+    sites = [jax.ShapeDtypeStruct(shape, jnp.float32)
+             for shape in self_attention_shapes(cfg, 2 * images, px // 8)]
+    return sum(attention.path_for(x, x, x, use_flash=cfg.flash_attention)
+               == "flash" for x in sites)
 
 
 def stand_in_samples() -> None:

@@ -109,7 +109,10 @@ class CrossAttention(nn.Module):
     - "ulysses": one all_to_all re-shards seq->heads, full-sequence
       attention per head group (riding the Pallas flash kernel on TPU),
       all_to_all back (ops/ulysses_attention.py). Needs heads % seq == 0;
-      falls back to ring when they don't divide."""
+      falls back to ring when they don't divide.
+
+    Every other site hands the mesh to the dispatcher, which over more than
+    one device runs the Pallas kernel under shard_map (ops/attention.py)."""
 
     num_heads: int
     head_dim: int
@@ -161,7 +164,8 @@ class CrossAttention(nn.Module):
 
                 out = ring_self_attention(q, k, v, self.mesh)
         else:
-            out = dot_product_attention(q, k, v, use_flash=self.use_flash)
+            out = dot_product_attention(q, k, v, use_flash=self.use_flash,
+                                        mesh=self.mesh)
         out = out.reshape(b, sq, inner)
         return nn.Dense(self.out_dim, dtype=self.dtype, name="to_out")(out)
 

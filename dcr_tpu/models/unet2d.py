@@ -30,11 +30,31 @@ def attn_dims(cfg: ModelConfig, ch: int) -> tuple[int, int]:
     return ch // cfg.attention_head_dim, cfg.attention_head_dim
 
 
+def self_attention_shapes(cfg: ModelConfig, rows: int,
+                          latent: int) -> list[tuple[int, int, int, int]]:
+    """[B, S, H, D] of q, k and v at every spatial self-attention site of one
+    UNet call over `rows` latents of latent x latent, in call order: what the
+    attention dispatcher is asked, site by site."""
+    n = len(cfg.block_out_channels)
+
+    def level(i: int, sites: int) -> list[tuple[int, int, int, int]]:
+        heads, head_dim = attn_dims(cfg, cfg.block_out_channels[i])
+        tokens = (latent // 2 ** i) ** 2
+        return [(rows, tokens, heads, head_dim)] * (sites * cfg.transformer_layers)
+
+    down = [s for i in range(n - 1) for s in level(i, cfg.layers_per_block)]
+    up = [s for i in reversed(range(n - 1))
+          for s in level(i, cfg.layers_per_block + 1)]
+    return down + level(n - 1, 1) + up
+
+
 class UNet2DCondition(nn.Module):
     config: ModelConfig
     dtype: jnp.dtype = jnp.float32
-    # attach a mesh with a seq axis >1 to enable ring-attention sequence
-    # parallelism in the spatial self-attentions (config.seq_parallel_min_seq)
+    # the mesh the enclosing jit spans, when it has more than one device: the
+    # flash kernel then runs per device under shard_map (ops/attention.py),
+    # and a seq axis >1 turns on ring-attention sequence parallelism in the
+    # spatial self-attentions (config.seq_parallel_min_seq)
     mesh: Optional[jax.sharding.Mesh] = None
 
     @nn.compact

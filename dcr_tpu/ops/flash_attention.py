@@ -2,8 +2,8 @@
 
 Replaces the role xformers' CUDA memory-efficient attention plays in the
 reference (diff_train.py:578): O(S) memory attention for the UNet's spatial
-self-attention at 512px+ (S=4096 latent tokens). Classic FlashAttention
-(Dao et al. 2022):
+self-attention from 1,024 latent tokens up (256 px in bulk, 512 px+). Classic
+FlashAttention (Dao et al. 2022):
 
 - forward: online softmax over key blocks, f32 logits/statistics/accumulator on
   the MXU while operands stay bf16; emits the per-row logsumexp lane-broadcast
@@ -20,9 +20,9 @@ self-attention at 512px+ (S=4096 latent tokens). Classic FlashAttention
   what does not fit (RESIDENT_KV_MAX_BYTES), and those shapes take XLA
   attention.
 
-Block sizes are tunable per call; the defaults (_resolve_blocks) came from a
-sweep with tools/sweep_flash.py, measured 2026-07-29 on a backend since
-retired; to be re-measured by the benchmark.
+Block sizes are tunable per call; the defaults (_resolve_blocks) and the
+dispatch policy (should_use) are set from device-trace readings on a TPU v5e
+(tools/sweep_flash.py, PR 27; the table is in PERF.md section 5).
 
 Layout contract: [B, S, H, D] at the dispatcher, reshaped to [B*H, S, D] here.
 interpret=True runs the same kernels through the Pallas interpreter (CPU tests).
@@ -37,18 +37,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# legacy defaults (round-1); _resolve_blocks picks per-shape tuned values
-BLOCK_Q = 256
-BLOCK_K = 128
 NEG_INF = -1e30
 LANES = 128      # TPU lane count: min last-dim tile for f32 outputs
 
-# Dispatch threshold: below this key length XLA's fused attention wins on a
-# v5e (the S×S weight tensor still fits HBM comfortably and XLA's single
-# fused kernel beats the Pallas pipeline's overheads); at/above it the flash
-# kernel wins on memory and is competitive on time (measured 2026-07-29 on a
-# backend since retired; to be re-measured by the benchmark).
-FLASH_MIN_SEQ = 2048
+# Dispatch policy, from device times on a v5e (PERF.md section 5, PR 27;
+# kernel WITH its relayouts against XLA's fused attention, same operands).
+# XLA is the faster path for as long as it keeps the f32 [B*H, Sq, Sk] logits
+# on the chip (128 MiB of VMEM): at 1,024 keys 25 heads' 100 MiB cost it
+# 0.09 ms against the kernel's 0.15, and 30 heads' 120 MiB 0.56 to 0.58 ms
+# against 0.17 to 0.19; from there the logits cross HBM three times a call
+# and the kernel wins 2.2x (100 heads in float32, forward) to 2.5x (80 heads
+# in bfloat16, forward and backward; the backward's cliff is the same one).
+# Under 1,024 keys the relayouts round the kernel alone cost what XLA's whole
+# call does (256 keys, 200 heads: 0.13 ms against 0.24), whatever the logits'
+# size, so the key length keeps its own floor.
+FLASH_MIN_SEQ = 1024
+FLASH_MIN_LOGITS_BYTES = 112 * 2**20
 
 # The forward and dQ kernels hold K and V of one head whole, each
 # double-buffered by the pipeline: 4 * sk * d * itemsize bytes of the ~16 MB
@@ -61,12 +65,14 @@ RESIDENT_KV_MAX_BYTES = 4 * 9216 * 64 * 2
 
 
 def _resolve_blocks(sq: int, sk: int, block_q: int | None,
-                    block_k: int | None) -> tuple[int, int]:
-    """Pick (block_q, block_k): explicit args win, else the tuned default
-    clamped so blocks divide the sequence lengths. (1024, 1024) won the
-    tools/sweep_flash.py sweep at every large shape (measured 2026-07-29 on
-    a backend since retired; to be re-measured by the benchmark)."""
-    bq = block_q or min(1024, sq)
+                    block_k: int | None, itemsize: int) -> tuple[int, int]:
+    """Pick (block_q, block_k): explicit args win, else the measured default
+    clamped so blocks divide the sequence lengths. On a v5e (PERF.md section 5,
+    PR 27) block_k 1024 won at every shape, and block_q 1024 with it at 1,024
+    keys in both dtypes. At 4,096 keys in float32 block_q 512 is 3% faster and
+    is what fits: with (1024, 1024) the f32 [block_q, block_k] logits beside
+    4 MiB of resident K/V pass the 16 MiB of scoped VMEM from 4 rows on."""
+    bq = block_q or min(512 if itemsize == 4 and sk > 1024 else 1024, sq)
     bk = block_k or min(1024, sk)
     while sq % bq:
         bq //= 2
@@ -95,10 +101,14 @@ def supported(q: jax.Array, k: jax.Array, v: jax.Array) -> bool:
 
 def should_use(q: jax.Array, k: jax.Array, v: jax.Array) -> bool:
     """Dispatch policy: the Pallas kernel handles this attention only where it
-    actually beats XLA's fused attention on the measured ladder (sk >=
-    FLASH_MIN_SEQ) — below that XLA wins on time and the S×S weight tensor is
-    small enough that flash's memory advantage is moot."""
-    return supported(q, k, v) and k.shape[1] >= FLASH_MIN_SEQ
+    beats XLA's fused attention on the chip: at least FLASH_MIN_SEQ keys, and
+    f32 logits larger than XLA keeps on the chip (FLASH_MIN_LOGITS_BYTES).
+    The shapes are one device's (ops.attention divides a sharded batch)."""
+    if not supported(q, k, v):
+        return False
+    b, sq, h, _ = q.shape
+    sk = k.shape[1]
+    return sk >= FLASH_MIN_SEQ and 4 * b * h * sq * sk > FLASH_MIN_LOGITS_BYTES
 
 
 def _mem(interpret: bool) -> dict:
@@ -157,7 +167,7 @@ def _flash_fwd(q3: jax.Array, k3: jax.Array, v3: jax.Array, *,
     """q3/k3/v3: [BH, S, D] -> (out [BH,S,D], lse [BH,S,LANES] lane-broadcast)."""
     bh, sq, d = q3.shape
     sk = k3.shape[1]
-    bq, bk = _resolve_blocks(sq, sk, block_q, block_k)
+    bq, bk = _resolve_blocks(sq, sk, block_q, block_k, q3.dtype.itemsize)
     scale = 1.0 / (d ** 0.5)
     kernel = functools.partial(_fwd_kernel, scale=scale, block_k=bk)
     mem = _mem(interpret)
@@ -260,7 +270,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, *, interpret: bool,
                block_q: int | None = None, block_k: int | None = None):
     bh, sq, d = q3.shape
     sk = k3.shape[1]
-    bq, bk = _resolve_blocks(sq, sk, block_q, block_k)
+    bq, bk = _resolve_blocks(sq, sk, block_q, block_k, q3.dtype.itemsize)
     scale = 1.0 / (d ** 0.5)
     mem = _mem(interpret)
 

@@ -111,14 +111,14 @@ def make_sampler(cfg: SampleConfig, models: DiffusionModels, mesh):
     images: [B, H, W, 3] float32 in [0, 1]. params = {"unet", "vae", "text"}.
 
     The UNet's module mesh is reconciled with the sampling mesh here, for
-    every caller: ring/Ulysses sequence-parallel attention gates on
-    ``module.mesh``, so an absent one would silently sample dense under a
-    seq-axis mesh, and a stale one (e.g. a training mesh captured at
-    build_models time) would shard_map over the wrong device set. Modules
-    are static config — rebuilding is free.
+    every caller: ring/Ulysses sequence-parallel attention and the flash
+    kernel's per-device shard_map gate on ``module.mesh``, so an absent one
+    would silently sample dense under a seq-axis mesh (or hand the compiler a
+    Mosaic kernel it cannot partition), and a stale one (e.g. a training mesh
+    captured at build_models time) would shard_map over the wrong device set.
+    Modules are static config — rebuilding is free.
     """
-    wants_seq = mesh.shape.get(pmesh.SEQ_AXIS, 1) > 1
-    target_mesh = mesh if wants_seq else None
+    target_mesh = mesh if mesh.size > 1 else None
     if models.unet.mesh is not target_mesh:
         from dcr_tpu.models.unet2d import UNet2DCondition
 

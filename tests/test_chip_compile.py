@@ -3,8 +3,8 @@
 The TPU compiler is installed next to the CPU backend, so these tests catch
 what Pallas interpret mode cannot — a kernel that asks for more VMEM than a
 core has, a slice the tiling refuses — at no chip time. Shapes are the ones
-SD-2.1 hands the kernel: (B, 4096, 5, 64) at 512 px, (B, 9216, 5, 64) at
-768 px.
+SD-2.1 hands the kernel: (B, 1024, 5, 64) at 256 px, (B, 4096, 5, 64) and
+(B, 1024, 10, 64) at 512 px, (B, 9216, 5, 64) at 768 px.
 
 The topology is described inside a fixture and never at import: only one
 process may load the TPU library, and under pytest-xdist every worker imports
@@ -63,12 +63,33 @@ def _compile(fn, shape, dtype, sharding):
     ((2, 4096, 5, 64), jnp.bfloat16),     # 512 px, what training feeds it
     ((2, 9216, 5, 64), jnp.bfloat16),     # 768 px
     ((2, 4096, 5, 64), jnp.float32),      # 512 px, what the sampler feeds it
-], ids=["512px_bf16", "768px_bf16", "512px_f32"])
+    ((20, 1024, 5, 64), jnp.float32),     # 256 px, the sampler at 10 images
+    ((4, 1024, 10, 64), jnp.float32),     # 512 px second level, 2 images
+    ((16, 1024, 5, 64), jnp.bfloat16),    # 256 px, the train step at batch 16
+], ids=["512px_bf16", "768px_bf16", "512px_f32", "256px_f32_20rows",
+        "512px_level2_f32", "256px_bf16_16rows"])
 def test_kernel_compiles_for_v5e(one_chip, shape, dtype, fn, calls):
     x = jax.ShapeDtypeStruct(shape, dtype)
     assert fa.should_use(x, x, x)
     text = _compile(fn, shape, dtype, one_chip).as_text()
     assert text.count("tpu_custom_call") >= calls
+
+
+@pytest.mark.parametrize("rows", [2, 4, 8, 20])
+def test_f32_4096_keys_compile_at_every_batch(one_chip, rows):
+    """The default blocks fit scoped VMEM whatever the batch, so bulk sampling
+    at 512 px is not held to one image a batch by the kernel."""
+    assert fa._resolve_blocks(4096, 4096, None, None, 4) == (512, 1024)
+    _compile(_forward, (rows, 4096, 5, 64), jnp.float32, one_chip)
+
+
+def test_f32_4096_keys_refused_with_1024_blocks(one_chip):
+    """Why block_q is 512 there: (1024, 1024) is refused from 4 rows on
+    ("Scoped allocation with size 16.34M and limit 16.00M exceeded scoped
+    vmem limit")."""
+    with pytest.raises(Exception, match="vmem"):
+        _compile(lambda q, k, v: fa.flash_attention(q, k, v, False, 1024, 1024),
+                 (4, 4096, 5, 64), jnp.float32, one_chip)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],

@@ -29,7 +29,6 @@ TINY = {
     "images": 16, "image_px": 40, "train_px": 16, "train_batch": 1,
     "model_argv": TINY_MODEL_ARGV,
     "samples": ((16, 2), (32, 1)), "sample_steps": 3,
-    "flash_sites": 0,           # no Mosaic kernel exists on the CPU
     "embed_px": 32, "embed_batch": 8,
 }
 
@@ -130,6 +129,30 @@ def test_a_cli_that_exits_by_itself_ends_the_run(tiny, capsys, monkeypatch):
     monkeypatch.setattr(chip_smoke, "phase_kernel", exits)
     assert chip_smoke.main(["--only=kernel"]) != 0
     assert _lines(capsys)[-1] == {"ok": False, "failed_phase": "kernel"}
+
+
+@pytest.mark.parametrize("on_tpu,px,images,sites", [
+    (True, 512, 1, 5),      # the top level's 4,096 tokens; 1,024 x 20 heads stay on XLA
+    (True, 256, 4, 5),      # 8 rows x 5 heads at 1,024 tokens: past XLA's on-chip logits
+    (True, 256, 2, 0),      # 4 rows x 5 heads: XLA's
+    (False, 512, 1, 0),     # no Mosaic kernel exists off the TPU
+])
+def test_flash_sites_follow_the_dispatcher(monkeypatch, tmp_path, on_tpu, px,
+                                           images, sites):
+    """The sampler's expected tpu_custom_call count is derived from the
+    exported UNet's sites and the dispatcher's policy, not written down."""
+    import dataclasses
+
+    from dcr_tpu.core.config import ModelConfig
+    from dcr_tpu.ops import attention
+
+    monkeypatch.setattr(chip_smoke, "WORK", tmp_path)
+    ckpt = tmp_path / "run" / "checkpoint"
+    ckpt.mkdir(parents=True)
+    (ckpt / "model_index.json").write_text(json.dumps(
+        {"model_config": dataclasses.asdict(ModelConfig())}))
+    monkeypatch.setattr(attention, "_on_tpu", lambda: on_tpu)
+    assert chip_smoke.flash_sites(px, images) == sites
 
 
 def test_compile_cache_helper(monkeypatch):

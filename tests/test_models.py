@@ -6,7 +6,8 @@ import pytest
 from dcr_tpu.core.config import ModelConfig
 from dcr_tpu.models import layers as L
 from dcr_tpu.models.clip_text import CLIPTextModel, init_clip_text
-from dcr_tpu.models.unet2d import UNet2DCondition, init_unet, unet_param_count
+from dcr_tpu.models.unet2d import (UNet2DCondition, init_unet,
+                                   self_attention_shapes, unet_param_count)
 from dcr_tpu.models.vae import AutoencoderKL, init_vae, vae_scale_factor
 
 
@@ -97,6 +98,31 @@ def test_sd21_unet_param_count():
     )
     n = sum(np.prod(s.shape) for s in jax.tree.leaves(params))
     assert 0.7e9 < n < 1.1e9, f"param count {n/1e9:.2f}B out of SD-2.1 range"
+
+
+@pytest.mark.parametrize("cfg,rows,latent", [
+    (ModelConfig(), 20, 32), (ModelConfig(), 2, 64), (ModelConfig.tiny(), 3, 8)],
+    ids=["sd21_256px", "sd21_512px", "tiny"])
+def test_self_attention_shapes_are_what_the_unet_asks(monkeypatch, cfg, rows,
+                                                      latent):
+    """self_attention_shapes mirrors the UNet's structure by hand; hold it to
+    what a traced UNet call hands the dispatcher, site by site."""
+    asked = []
+
+    def record(q, k, v, **kw):
+        if q.shape[1] == k.shape[1]:          # cross-attention: 77 (16) keys
+            asked.append(q.shape)
+        return q
+
+    monkeypatch.setattr(L, "dot_product_attention", record)
+    model = UNet2DCondition(cfg)
+    x = jnp.zeros((rows, latent, latent, 4))
+    t = jnp.zeros((rows,), jnp.int32)
+    ctx = jnp.zeros((rows, cfg.text_max_length + 1, cfg.cross_attention_dim))
+    jax.eval_shape(lambda k: model.init(k, x, t, ctx), jax.random.key(0))
+    assert asked == self_attention_shapes(cfg, rows, latent)
+    if cfg.attention_head_dim == 64:
+        assert len(asked) == 16 and asked[0] == (rows, latent * latent, 5, 64)
 
 
 def test_vae_roundtrip_shapes(tiny):

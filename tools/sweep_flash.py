@@ -1,116 +1,212 @@
-"""Block-size sweep for the Pallas flash-attention kernels on the local chip.
+"""Device-time sweep of self-attention on the local chip: XLA's fused attention
+against the Pallas flash kernels, per SD-2.1 site shape and per (block_q,
+block_k). `FLASH_MIN_SEQ` and `_resolve_blocks` in dcr_tpu/ops/flash_attention.py
+are set from what this prints (the readings are kept in PERF.md).
 
-Times flash fwd and fwd+bwd against XLA's fused attention across sequence
-lengths and (block_q, block_k) candidates; appends one JSON object per
-measurement to SWEEP_FLASH.jsonl so a killed run still leaves data.
+Times are device times of the ops in ONE profiler capture, reduced by
+benchmark/lib/trace.py, never a host clock: every variant is a jitted function
+of its own name, each of its runs is one `XLA Modules` event, and the `XLA Ops`
+inside that event split into the kernels (`tpu_custom_call`) and the rest (the
+relayouts round the kernel, or all of XLA's attention). Operands are
+[B, S, H*D], as the UNet's to_q/to_k/to_v hand them over, and the result is
+[B, S, H*D], as to_out takes it, so both paths pay their own relayouts.
 
-Usage: python tools/sweep_flash.py  (run on a box where jax sees the TPU)
+    python tools/sweep_flash.py [--out chiprun_out/sweep_flash] [--iters 10]
+    python tools/sweep_flash.py --default-blocks --sites 8x1024x5x64:float32:fwd ...
+    python tools/sweep_flash.py --tiny      # CPU rehearsal: interpret mode, no times
 """
 
 from __future__ import annotations
 
+import argparse
+import itertools
 import json
+import shutil
+import statistics
 import sys
-import time
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+REPO = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO))
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-OUT = Path(__file__).resolve().parent.parent / "SWEEP_FLASH.jsonl"
+from benchmark.lib import trace as tracelib
+from dcr_tpu.ops import attention, flash_attention as fa
 
-# SD-2.1 UNet spatial self-attention shapes: 256px -> S=1024 (H5 at C320),
-# 512px -> S=4096, 1024px-equivalent long-context -> S=16384.
-SHAPES = [  # (B, H, S, D)
-    (4, 20, 256, 64),
-    (4, 10, 512, 64),
-    (4, 5, 1024, 64),
-    (4, 10, 4096, 64),
-    (1, 5, 16384, 64),
+# (B, S, H, D), dtype, differentiated: the self-attention sites of SD-2.1 in
+# the benchmark's cells. Forward-only float32 is the sampler (20 rows at
+# 256 px, 2 at 512 px); bfloat16 forward and backward is the train step.
+SITES = [
+    ((20, 1024, 5, 64), "float32", False),
+    ((2, 1024, 10, 64), "float32", False),
+    ((20, 256, 10, 64), "float32", False),
+    ((2, 256, 20, 64), "float32", False),
+    ((2, 4096, 5, 64), "float32", False),
+    ((8, 4096, 5, 64), "float32", False),     # 512 px at im_batch 4 (ROADMAP M1)
+    ((16, 1024, 5, 64), "bfloat16", True),
+    ((16, 256, 10, 64), "bfloat16", True),
 ]
-BLOCKS = [(512, 256), (512, 512), (1024, 256), (1024, 512), (1024, 1024),
-          (2048, 512), (256, 256)]
+TINY_SITES = [((1, 256, 2, 64), "float32", False),
+              ((1, 256, 2, 64), "bfloat16", True)]
+BLOCKS = (256, 512, 1024)
+SWEPT_SEQ = (1024, 4096)      # other lengths run the default blocks only
 
 
-def emit(rec: dict) -> None:
-    rec["t"] = time.strftime("%H:%M:%S")
-    with OUT.open("a") as f:
-        f.write(json.dumps(rec) + "\n")
-    print(json.dumps(rec), flush=True)
+def block_candidates(seq: int, itemsize: int, sweep: bool = True
+                     ) -> list[tuple[int | None, int | None]]:
+    """(None, None) is whatever _resolve_blocks ships; the explicit pairs are
+    the others, clamped to the sequence, so S = 256 has nothing to sweep."""
+    if not sweep or seq not in SWEPT_SEQ:
+        return [(None, None)]
+    pairs = {(min(bq, seq), min(bk, seq))
+             for bq, bk in itertools.product(BLOCKS, repeat=2)}
+    pairs.discard(fa._resolve_blocks(seq, seq, None, None, itemsize))
+    return [(None, None)] + sorted(pairs)
 
 
-def timeit(fn, *args, iters: int = 20) -> float:
-    """ms/iter: ``iters`` back-to-back calls ended by block_until_ready,
-    best of three, after a compile + warm-up call."""
-    jax.block_until_ready(fn(*args))
-
-    def run() -> float:
-        t0 = time.perf_counter()
-        out = None
-        for _ in range(iters):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return time.perf_counter() - t0
-
-    return min(run() for _ in range(3)) / iters * 1e3
+def parse_site(text: str):
+    """'20x1024x5x64:float32:fwd' or '...:bfloat16:fwdbwd'."""
+    shape, dtype, direction = text.split(":")
+    return (tuple(int(x) for x in shape.split("x")), dtype,
+            {"fwd": False, "fwdbwd": True}[direction])
 
 
-def main() -> None:
-    from dcr_tpu.ops import flash_attention as fa
+def variant(shape, differentiated: bool, blocks, interpret: bool):
+    """The jitted call of one path over [B, S, H*D] operands: `blocks` None is
+    XLA's attention, a pair the kernel with those blocks."""
+    b, s, h, d = shape
 
-    emit({"phase": "devices", "devices": [str(d) for d in jax.devices()]})
-    rng = np.random.default_rng(0)
+    def attend(xq, xk, xv):
+        q, k, v = (x.reshape(b, s, h, d) for x in (xq, xk, xv))
+        if blocks is None:
+            out = attention._xla_attention(q, k, v, None)
+        else:
+            out = fa.flash_attention(q, k, v, interpret, *blocks)
+        return out.reshape(b, s, h * d)
 
-    for (b, h, s, d) in SHAPES:
-        q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.bfloat16)
-        k = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.bfloat16)
-        v = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.bfloat16)
+    if not differentiated:
+        return attend
 
-        def loss_xla(q, k, v):
-            return jnp.sum(jax.nn.dot_product_attention(q, k, v).astype(jnp.float32) ** 2)
+    def attend_and_grads(xq, xk, xv, g):
+        out, vjp = jax.vjp(attend, xq, xk, xv)
+        return out, vjp(g)
 
-        xla_fwd = jax.jit(lambda q, k, v: jax.nn.dot_product_attention(q, k, v))
-        xla_grad = jax.jit(jax.grad(loss_xla, argnums=(0, 1, 2)))
-        try:
-            ms_f = timeit(xla_fwd, q, k, v)
-            ms_g = timeit(xla_grad, q, k, v)
-            emit({"impl": "xla", "shape": [b, h, s, d], "fwd_ms": round(ms_f, 3),
-                  "fwd_bwd_ms": round(ms_g, 3)})
-        except Exception as e:
-            emit({"impl": "xla", "shape": [b, h, s, d], "error": repr(e)[:300]})
+    return attend_and_grads
 
-        for (bq, bk) in BLOCKS:
-            if s % bq or s % bk:
+
+def tag_of(shape, dtype: str, differentiated: bool, blocks) -> str:
+    path = "xla" if blocks is None else "flash_" + "_".join(
+        "d" if x is None else str(x) for x in blocks)
+    return (f"att_{'x'.join(map(str, shape))}_{dtype}_"
+            f"{'fwdbwd' if differentiated else 'fwd'}_{path}")
+
+
+def reduce_runs(trace: tracelib.Trace, tag: str) -> dict | None:
+    """Median device milliseconds of one variant's runs: the whole module, the
+    Pallas kernels by name, and every other op (relayouts, or XLA's attention)."""
+    runs = []
+    for chip, modules in trace.modules.items():
+        for name, start, dur in modules:
+            if not name.startswith(f"jit_{tag}("):
                 continue
+            kernels: dict[str, float] = {}
+            rest = 0.0
+            for op, _, op_dur in tracelib.in_window(trace.ops.get(chip, []),
+                                                    start, start + dur):
+                base, opcode = tracelib.op_kind(op)
+                if opcode in tracelib.CONTAINERS:
+                    continue
+                if 'custom_call_target="tpu_custom_call"' in op:
+                    kernels[base] = kernels.get(base, 0.0) + op_dur / 1e6
+                else:
+                    rest += op_dur / 1e6
+            runs.append((dur / 1e6, kernels, rest))
+    if not runs:
+        return None
+    names = sorted({k for _, kernels, _ in runs for k in kernels})
+    return {"runs": len(runs),
+            "module_ms": statistics.median(r[0] for r in runs),
+            "kernel_ms": {k: statistics.median(r[1].get(k, 0.0) for r in runs)
+                          for k in names},
+            "rest_ms": statistics.median(r[2] for r in runs)}
 
-            def fl_fwd(q, k, v, bq=bq, bk=bk):
-                return fa.flash_attention(q, k, v, False, bq, bk)
 
-            def loss_fl(q, k, v, bq=bq, bk=bk):
-                return jnp.sum(fa.flash_attention(q, k, v, False, bq, bk)
-                               .astype(jnp.float32) ** 2)
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--out", default=str(REPO / "chiprun_out" / "sweep_flash"))
+    ap.add_argument("--iters", type=int, default=10)
+    ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--sites", nargs="+", type=parse_site, default=None,
+                    help="BxSxHxD:dtype:fwd|fwdbwd in place of SD-2.1's sites")
+    ap.add_argument("--default-blocks", action="store_true",
+                    help="the shipped blocks only, no sweep")
+    args = ap.parse_args()
+    out_dir = Path(args.out)
+    out_dir.mkdir(parents=True, exist_ok=True)
+    device = jax.devices()[0]
+    print(json.dumps({"device": device.device_kind, "platform": device.platform}),
+          flush=True)
+    if device.platform != "tpu" and not args.tiny:
+        print("no TPU: device times come from the chip only (--tiny rehearses)")
+        return 2
 
-            jf = jax.jit(fl_fwd)
-            jg = jax.jit(jax.grad(loss_fl, argnums=(0, 1, 2)))
+    records = []          # (what is printed, the jitted call or None, its operands)
+    sites = args.sites or (TINY_SITES if args.tiny else SITES)
+    for shape, dtype, differentiated in sites:
+        b, s, h, d = shape
+        keys = jax.random.split(jax.random.key(s * h + b), 4)
+        operands = [jax.random.normal(k, (b, s, h * d), jnp.dtype(dtype))
+                    for k in keys[:4 if differentiated else 3]]
+        reference = None
+        itemsize = jnp.dtype(dtype).itemsize
+        for blocks in [None] + block_candidates(s, itemsize,
+                                                not args.default_blocks):
+            rec = {"shape": list(shape), "dtype": dtype,
+                   "differentiated": differentiated,
+                   "path": "xla" if blocks is None else "flash",
+                   "blocks": None if blocks is None else list(
+                       fa._resolve_blocks(s, s, *blocks, itemsize)),
+                   "default_blocks": blocks == (None, None),
+                   "tag": tag_of(shape, dtype, differentiated, blocks)}
+            fn = variant(shape, differentiated, blocks, args.tiny)
+            fn.__name__ = rec["tag"]
+            call = jax.jit(fn)
             try:
-                ms_f = timeit(jf, q, k, v)
-                ms_g = timeit(jg, q, k, v)
-                # correctness spot-check vs XLA
-                err = float(jnp.max(jnp.abs(
-                    jf(q, k, v).astype(jnp.float32)
-                    - xla_fwd(q, k, v).astype(jnp.float32))))
-                emit({"impl": "flash", "shape": [b, h, s, d], "blocks": [bq, bk],
-                      "fwd_ms": round(ms_f, 3), "fwd_bwd_ms": round(ms_g, 3),
-                      "max_abs_err_vs_xla": round(err, 5)})
-            except Exception as e:
-                emit({"impl": "flash", "shape": [b, h, s, d], "blocks": [bq, bk],
-                      "error": repr(e)[:300]})
+                result = jax.block_until_ready(call(*operands))
+            except Exception as e:       # a kernel the chip's compiler refuses
+                rec["error"] = repr(e)[:400]
+                call = None
+            else:
+                out = (result[0] if differentiated else result).astype(jnp.float32)
+                if blocks is None:
+                    reference = out
+                else:
+                    rec["max_abs_err_vs_xla"] = float(jnp.max(jnp.abs(out - reference)))
+            records.append((rec, call, operands))
 
-    emit({"phase": "done"})
+    trace_dir = out_dir / "raw"
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    jax.profiler.start_trace(str(trace_dir))
+    for _, call, operands in records:
+        if call is not None:
+            for _ in range(args.iters):
+                result = call(*operands)
+            jax.block_until_ready(result)
+    jax.profiler.stop_trace()
+    path = tracelib.find_xplane(trace_dir)
+    trace = tracelib.read(path) if path else tracelib.Trace()
+    shutil.rmtree(trace_dir, ignore_errors=True)
+
+    with (out_dir / "sweep.jsonl").open("w") as f:
+        for rec, call, _ in records:
+            if call is not None:
+                rec["device_ms"] = reduce_runs(trace, rec["tag"])
+            f.write(json.dumps(rec) + "\n")
+            print(json.dumps(rec), flush=True)
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())
