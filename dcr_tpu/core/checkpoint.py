@@ -621,13 +621,22 @@ def export_hf_layout(out_dir: str | Path, *, unet=None, vae=None, text_encoder=N
     st_name = {"unet": "diffusion_pytorch_model.safetensors",
                "vae": "diffusion_pytorch_model.safetensors",
                "text_encoder": "model.safetensors"}
+    tower = mc.get("text_tower", "clip")
     for name, params in (("unet", unet), ("vae", vae), ("text_encoder", text_encoder)):
         if params is None:
             continue
         sub = out / name
         sub.mkdir(parents=True, exist_ok=True)
         flat = _flatten(params)
-        np.savez(sub / "params.npz", **flat)
+        np.savez(sub / "params.npz", **_npz_encode(flat))
+        if name == "text_encoder" and tower != "clip":
+            # our own layout only: models/convert.py has no torch naming for
+            # this tower until a published checkpoint is here to hold it to
+            (sub / "config.json").write_text(json.dumps({
+                "architectures": [tower], **mc.get("longcat", {}),
+                "vocab_size": mc.get("text_vocab_size"),
+                "max_position_embeddings": mc.get("text_max_length")}, indent=2))
+            continue
         try:
             from safetensors.numpy import save_file
         except ImportError as e:  # pragma: no cover - safetensors is baked in
@@ -661,7 +670,8 @@ def export_hf_layout(out_dir: str | Path, *, unet=None, vae=None, text_encoder=N
             "_diffusers_version": "0.14.0",
             "unet": ["diffusers", "UNet2DConditionModel"],
             "vae": ["diffusers", "AutoencoderKL"],
-            "text_encoder": ["transformers", "CLIPTextModel"],
+            "text_encoder": (["transformers", "CLIPTextModel"]
+                             if tower == "clip" else ["dcr_tpu", tower]),
             "scheduler": ["diffusers", "DPMSolverMultistepScheduler"],
             "model_config": model_config,     # our native config, round-trips
         }
@@ -689,7 +699,7 @@ def import_hf_layout(ckpt_dir: str | Path, component: str) -> dict:
     if npz.exists():
         with np.load(npz) as z:
             flat = {k: z[k] for k in z.files}
-        return _unflatten(flat)
+        return _unflatten(_npz_decode(flat))
 
     weight_file = next((sub_dir / n for n in _TORCH_WEIGHT_NAMES
                         if (sub_dir / n).exists()), None)
@@ -801,6 +811,26 @@ def _flatten(tree: Any, prefix: str = "") -> dict[str, np.ndarray]:
     else:
         out[prefix[:-1]] = np.asarray(jax.device_get(tree))
     return out
+
+
+#: npz has no bfloat16: such a leaf is stored as its 16 bits under this suffix
+_BF16_SUFFIX = ":bf16"
+
+
+def _npz_encode(flat: dict[str, np.ndarray]) -> dict[str, np.ndarray]:
+    import ml_dtypes
+
+    return {(k + _BF16_SUFFIX if v.dtype == ml_dtypes.bfloat16 else k):
+            (v.view(np.uint16) if v.dtype == ml_dtypes.bfloat16 else v)
+            for k, v in flat.items()}
+
+
+def _npz_decode(flat: dict[str, np.ndarray]) -> dict[str, np.ndarray]:
+    import ml_dtypes
+
+    return {(k[:-len(_BF16_SUFFIX)] if k.endswith(_BF16_SUFFIX) else k):
+            (v.view(ml_dtypes.bfloat16) if k.endswith(_BF16_SUFFIX) else v)
+            for k, v in flat.items()}
 
 
 def _unflatten(flat: dict[str, np.ndarray]) -> dict:

@@ -64,6 +64,58 @@ class MeshConfig:
 
 
 @dataclass
+class LongcatFlashConfig:
+    """Sizes of the LongCat-Flash text tower (models/longcat_flash.py), under
+    the keys of the published config.json (meituan-longcat/LongCat-Flash-Chat);
+    the defaults are the published values. The vocabulary (or the slice of it
+    held here) and the sequence length are ModelConfig.text_vocab_size and
+    text_max_length, as for every tower."""
+
+    hidden_size: int = 6144
+    ffn_hidden_size: int = 12288          # the two dense SwiGLU FFNs of a layer
+    expert_ffn_hidden_size: int = 2048    # a routed expert's SwiGLU
+    num_layers: int = 28                  # double layers: 2 MLA + 2 FFN + 1 MoE
+    num_attention_heads: int = 64
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mla_scale_q_lora: bool = True
+    mla_scale_kv_lora: bool = True
+    n_routed_experts: int = 512           # all of them: the router's routed outputs
+    zero_expert_num: int = 256            # identity experts: the router's other outputs
+    moe_topk: int = 12
+    routed_scaling_factor: float = 6.0
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 1e7
+    # The routed experts THIS device holds, a contiguous range of the
+    # n_routed_experts (the chip's share under expert parallelism; the layer
+    # still routes over every output and adds nothing for experts held
+    # elsewhere). -1 holds all from held_experts_first on.
+    held_experts_first: int = 0
+    held_experts_count: int = -1
+
+    @staticmethod
+    def tiny() -> "LongcatFlashConfig":
+        """CPU-test size: every mechanism present (8 routed + 4 zero-compute
+        experts, top 3, 2 double layers), nothing wide."""
+        return LongcatFlashConfig(
+            hidden_size=64, ffn_hidden_size=128, expert_ffn_hidden_size=32,
+            num_layers=2, num_attention_heads=4, q_lora_rank=32,
+            kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, n_routed_experts=8, zero_expert_num=4, moe_topk=3)
+
+    def held_range(self) -> tuple[int, int]:
+        count = (self.n_routed_experts - self.held_experts_first
+                 if self.held_experts_count < 0 else self.held_experts_count)
+        return self.held_experts_first, count
+
+
+TEXT_TOWERS = ("clip", "longcat_flash")
+
+
+@dataclass
 class ModelConfig:
     """Flagship diffusion-stack dimensions (SD-2.1 base by default).
 
@@ -106,6 +158,13 @@ class ModelConfig:
     vae_layers_per_block: int = 2
     vae_latent_channels: int = 4
     vae_scaling_factor: float = 0.18215
+    # The text tower: "clip" (models/clip_text.py; the text_hidden_size /
+    # text_layers / text_heads / text_act fields below are its sizes) or
+    # "longcat_flash" (models/longcat_flash.py; its sizes are the `longcat`
+    # block, its output is projected to cross_attention_dim). text_vocab_size
+    # and text_max_length belong to whichever tower is chosen.
+    text_tower: str = "clip"
+    longcat: LongcatFlashConfig = field(default_factory=LongcatFlashConfig)
     # CLIP text encoder (OpenCLIP ViT-H text tower for SD-2.1)
     text_vocab_size: int = 49408
     text_hidden_size: int = 1024
@@ -987,6 +1046,23 @@ def run_name(cfg: TrainConfig) -> str:
     return "_".join(parts)
 
 
+def validate_text_tower(m: ModelConfig) -> None:
+    if m.text_tower not in TEXT_TOWERS:
+        raise ValueError(f"model.text_tower must be one of {TEXT_TOWERS}")
+    if m.text_tower != "longcat_flash":
+        return
+    lc = m.longcat
+    first, count = lc.held_range()
+    if first < 0 or count < 0 or first + count > lc.n_routed_experts:
+        raise ValueError(
+            f"model.longcat holds routed experts [{first}, {first + count}) "
+            f"of {lc.n_routed_experts}: not a range of them")
+    if lc.moe_topk > lc.n_routed_experts + lc.zero_expert_num:
+        raise ValueError("model.longcat.moe_topk exceeds the router's outputs")
+    if lc.qk_rope_head_dim % 2:
+        raise ValueError("model.longcat.qk_rope_head_dim must be even (rotary pairs)")
+
+
 def validate_train_config(cfg: TrainConfig) -> None:
     """Cross-flag validation (reference diff_train.py:739-743)."""
     d = cfg.data
@@ -1006,6 +1082,15 @@ def validate_train_config(cfg: TrainConfig) -> None:
     validate_pipe_config(cfg)
     if cfg.model.seq_parallel_mode not in ("ring", "ulysses"):
         raise ValueError("seq_parallel_mode must be 'ring' or 'ulysses'")
+    validate_text_tower(cfg.model)
+    if cfg.model.text_tower == "longcat_flash" and cfg.train_text_encoder:
+        raise ValueError(
+            "train_text_encoder=true is refused with model.text_tower="
+            "longcat_flash: the tower is held frozen in bfloat16 (2 bytes a "
+            "parameter); trained it costs 16 bytes a parameter (float32 "
+            "weights, gradients and two Adam moments), which no cut of it "
+            "fits on a chip beside the UNet's own optimizer state. Encode "
+            "once with dcr-precompute-latents and train from the cache")
     ft = cfg.fault
     if ft.decode_retries < 0 or ft.max_rollbacks < 0:
         raise ValueError("fault.decode_retries/max_rollbacks must be >= 0")

@@ -42,6 +42,14 @@ class Policy:
         )
 
 
+def text_param_dtype(text_tower: str) -> jnp.dtype:
+    """What a frozen text tower's leaves are HELD in. CLIP's (like the VAE's
+    and the UNet's) are float32 and cast at the jit boundary; the LongCat-Flash
+    tower alone is held in bfloat16: at 2 bytes a parameter its chip's share
+    is 10 GB, at 4 it is more than the chip."""
+    return jnp.bfloat16 if text_tower == "longcat_flash" else jnp.float32
+
+
 def policy_from_string(mixed_precision: str) -> Policy:
     if mixed_precision in ("no", "fp32", "float32"):
         return Policy(compute_dtype=jnp.float32)

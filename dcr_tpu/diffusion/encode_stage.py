@@ -110,10 +110,13 @@ def merge_state(hot: HotState, frozen: dict,
         ema_params=hot.ema_params)
 
 
-def _text_ctx(models: DiffusionModels, policy, text_params, input_ids):
-    out = models.text_encoder.apply(
+def _text_out(models: DiffusionModels, policy, text_params, input_ids):
+    return models.text_encoder.apply(
         {"params": policy.cast_to_compute(text_params)}, input_ids)
-    return out.last_hidden_state
+
+
+def _text_ctx(models: DiffusionModels, policy, text_params, input_ids):
+    return _text_out(models, policy, text_params, input_ids).last_hidden_state
 
 
 @compile_surface("train/encode")
@@ -159,7 +162,12 @@ def make_encode_stage(cfg: TrainConfig, models: DiffusionModels, mesh, *,
         if cfg.train_text_encoder:
             enc["input_ids"] = input_ids
         else:
-            enc["ctx"] = _text_ctx(models, policy, frozen["text"], input_ids)
+            out = _text_out(models, policy, frozen["text"], input_ids)
+            enc["ctx"] = out.last_hidden_state
+            if emit == "moments" and hasattr(out, "moe_stats"):
+                # an expert tower's routing counts ride beside the outputs;
+                # the caller adds them to the moe/* counters at its fetch
+                enc["moe"] = out.moe_stats
         return enc
 
     return jax.jit(encode_fn)

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, NamedTuple, Optional
 
+import flax.linen as nn
 import flax.struct
 import jax
 import jax.numpy as jnp
@@ -31,7 +32,6 @@ from dcr_tpu.core.config import OptimConfig, TrainConfig
 from dcr_tpu.core.precision import policy_from_string
 from dcr_tpu.core import rng as rngmod
 from dcr_tpu.models import schedulers as S
-from dcr_tpu.models.clip_text import CLIPTextModel
 from dcr_tpu.models.unet2d import UNet2DCondition
 from dcr_tpu.models.vae import AutoencoderKL
 from dcr_tpu.parallel import mesh as pmesh
@@ -42,7 +42,7 @@ class DiffusionModels(NamedTuple):
 
     unet: UNet2DCondition
     vae: AutoencoderKL
-    text_encoder: CLIPTextModel
+    text_encoder: nn.Module              # models/text_tower.build_text_tower
     schedule: S.NoiseSchedule
 
 

@@ -39,7 +39,7 @@ from dcr_tpu.data.loader import DataLoader
 from dcr_tpu.data.tokenizer import TokenizerBase, load_tokenizer
 from dcr_tpu.diffusion import train as T
 from dcr_tpu.models import schedulers as S
-from dcr_tpu.models.clip_text import init_clip_text
+from dcr_tpu.models.text_tower import init_text_tower
 from dcr_tpu.models.unet2d import init_unet
 from dcr_tpu.models.vae import init_vae, vae_scale_factor
 from dcr_tpu.obs import memwatch
@@ -81,7 +81,8 @@ def build_modules(cfg: TrainConfig, mesh=None) -> "T.DiffusionModels":
     Module objects are static pytree-less config holders; the only arrays here
     are the (tiny) noise-schedule tables. Pairs with abstract_train_state for
     zero-memory lowering (FLOPs accounting, compiles for a described chip)."""
-    from dcr_tpu.models.clip_text import CLIPTextModel
+    from dcr_tpu.core.precision import policy_from_string
+    from dcr_tpu.models.text_tower import build_text_tower
     from dcr_tpu.models.unet2d import UNet2DCondition
     from dcr_tpu.models.vae import AutoencoderKL
 
@@ -93,7 +94,8 @@ def build_modules(cfg: TrainConfig, mesh=None) -> "T.DiffusionModels":
     return T.DiffusionModels(
         unet=UNet2DCondition(cfg.model, dtype=jnp.float32, mesh=mesh),
         vae=AutoencoderKL(cfg.model, dtype=jnp.float32),
-        text_encoder=CLIPTextModel(cfg.model, dtype=jnp.float32),
+        text_encoder=build_text_tower(
+            cfg.model, policy_from_string(cfg.mixed_precision).compute_dtype),
         schedule=sched)
 
 
@@ -113,17 +115,28 @@ def abstract_train_state(cfg: TrainConfig, key: Optional[jax.Array] = None) -> "
     return jax.eval_shape(mk, key if key is not None else jax.random.key(0))
 
 
-def build_models(cfg: TrainConfig, key: jax.Array, mesh=None):
+def build_models(cfg: TrainConfig, key: jax.Array, mesh=None, *,
+                 pretrained: Optional[dict] = None,
+                 parts: tuple[str, ...] = ("unet", "vae", "text")):
     """Initialize the module bundle + params (random init; finetuning loads a
     converted checkpoint over these via models/convert.py). Passing the mesh
     enables ring-attention sequence parallelism in the UNet when its seq axis
-    is >1 (cfg.model.seq_parallel_min_seq)."""
+    is >1 (cfg.model.seq_parallel_min_seq).
+
+    A tree handed in under `pretrained` is taken as it is and its
+    initialiser never runs (a language-model tower and its random twin do not
+    fit one chip together); `parts` names the trees the caller needs (the
+    encode leg holds no UNet). The other parts draw what they always drew."""
     models = build_modules(cfg, mesh=mesh)
     ku, kv, kt = jax.random.split(key, 3)
-    _, unet_params = init_unet(cfg.model, ku, model=models.unet)
-    _, vae_params = init_vae(cfg.model, kv, model=models.vae)
-    _, text_params = init_clip_text(cfg.model, kt, model=models.text_encoder)
-    return models, {"unet": unet_params, "vae": vae_params, "text": text_params}
+    init = {
+        "unet": lambda: init_unet(cfg.model, ku, model=models.unet)[1],
+        "vae": lambda: init_vae(cfg.model, kv, model=models.vae)[1],
+        "text": lambda: init_text_tower(cfg.model, kt, models.text_encoder),
+    }
+    pretrained = pretrained or {}
+    return models, {name: pretrained[name] if name in pretrained
+                    else init[name]() for name in parts}
 
 
 class Trainer:
@@ -205,9 +218,8 @@ class Trainer:
                                 and not self.replica_mode))
         root = rngmod.root_key(cfg.seed)
         self.models, params = build_models(cfg, rngmod.stream_key(root, "init"),
-                                           mesh=self.mesh)
-        if pretrained_params:
-            params.update(pretrained_params)
+                                           mesh=self.mesh,
+                                           pretrained=pretrained_params)
         self.state = T.init_train_state(
             cfg, self.models, unet_params=params["unet"],
             text_params=params["text"], vae_params=params["vae"])
@@ -648,7 +660,7 @@ class Trainer:
         else:
             enc["ctx"] = jax.ShapeDtypeStruct(
                 (local_bs, cfg.model.text_max_length,
-                 cfg.model.text_hidden_size), policy.compute_dtype,
+                 cfg.model.cross_attention_dim), policy.compute_dtype,
                 sharding=bs)
         return enc
 
@@ -706,7 +718,7 @@ class Trainer:
             sharding=bs)
         ctx = jax.ShapeDtypeStruct(
             (local_bs, cfg.model.text_max_length,
-             cfg.model.text_hidden_size), jnp.float32, sharding=bs)
+             cfg.model.cross_attention_dim), jnp.float32, sharding=bs)
         return {"mean": moment, "std": moment, "ctx": ctx}
 
     def train(self) -> dict:

@@ -49,6 +49,8 @@ def path_for(q, k, v, *, mask=None, use_flash: bool = True, mesh=None) -> str:
     does not divide cannot be sharded for the kernel, so XLA."""
     if not (use_flash and _on_tpu() and mask is None and q.ndim == 4):
         return "xla"
+    if v.shape[-1] != q.shape[-1]:
+        return "xla"        # latent attention: v narrower than q/k
     from dcr_tpu.ops import flash_attention as fa
 
     rows, heads, _ = _shards(mesh)
@@ -66,7 +68,8 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           mesh: Optional[Mesh] = None) -> jax.Array:
     """Multi-head attention over [B, S, H, D] tensors (BSHD layout).
 
-    q: [B, Sq, H, D]; k, v: [B, Sk, H, D]. Returns [B, Sq, H, D].
+    q: [B, Sq, H, D]; k: [B, Sk, H, D]; v: [B, Sk, H, Dv] with Dv <= D (latent
+    attention's values are narrower than its keys). Returns [B, Sq, H, Dv].
     Dispatches to the Pallas TPU flash kernel where it beats XLA on the chip
     (flash_attention.should_use) and we're on TPU, otherwise XLA (which fuses
     the softmax chain on its own). Every site counts itself once a trace in
@@ -96,4 +99,9 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # lets XLA pick its fused implementation. The scope tells this path from
     # the Pallas kernels (named flash_*) in a device trace.
     with jax.named_scope("attention_xla"):
-        return jax.nn.dot_product_attention(q, k, v, mask=mask)
+        dv = v.shape[-1]
+        if dv < q.shape[-1]:
+            # jax.nn's call wants v as wide as q/k: zero columns of v give
+            # zero columns of the output, which are cut off again (exact)
+            v = jnp.pad(v, ((0, 0),) * 3 + ((0, q.shape[-1] - dv),))
+        return jax.nn.dot_product_attention(q, k, v, mask=mask)[..., :dv]

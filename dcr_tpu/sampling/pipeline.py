@@ -32,7 +32,7 @@ from dcr_tpu.core import rng as rngmod
 from dcr_tpu.data.tokenizer import TokenizerBase, load_tokenizer
 from dcr_tpu.diffusion.train import DiffusionModels
 from dcr_tpu.models import schedulers as S
-from dcr_tpu.models.clip_text import CLIPTextModel
+from dcr_tpu.models.text_tower import build_text_tower
 from dcr_tpu.models.unet2d import UNet2DCondition
 from dcr_tpu.models.vae import AutoencoderKL
 from dcr_tpu.parallel import mesh as pmesh
@@ -75,7 +75,7 @@ def load_checkpoint_models(ckpt_dir: str | Path, mesh=None):
     models = DiffusionModels(
         unet=UNet2DCondition(model_cfg, mesh=mesh),
         vae=AutoencoderKL(model_cfg),
-        text_encoder=CLIPTextModel(model_cfg),
+        text_encoder=build_text_tower(model_cfg),
         # model_cfg carries the schedule fields for every checkpoint flavor:
         # native exports round-trip them; the genuine-diffusers path fills
         # them from scheduler_config.json (model_config_from_diffusers)

@@ -553,7 +553,7 @@ class GenerationService:
         incarnation. Returns a ready-to-call program (with a one-way degrade
         to the plain jit path should the executable ever reject its inputs)."""
         L = self.stack.model_cfg.text_max_length
-        D = self.stack.model_cfg.text_hidden_size
+        D = self.stack.model_cfg.cross_attention_dim
         jit_fn = make_batch_sampler(bucket, self.stack.models,
                                     self.cfg.seed, self.cfg.max_batch)
         emb = jax.ShapeDtypeStruct((self.cfg.max_batch, L, D), jnp.float32)

@@ -650,6 +650,57 @@ def test_pipelined_nan_rollback(train_setup):
     assert t._frozen["vae"] is frozen_before
 
 
+@pytest.mark.parametrize("case", ["named_ahead", "another_batch_asked",
+                                  "write_fails"])
+def test_precompute_job_keeps_the_device_ahead(train_setup, case):
+    """`PrecomputeJob.encode_batch(number, then)`: the batch named as next is
+    on the device before this one is waited for and the rows go to the disk
+    on the writer's thread, and the cache is byte for byte what the calls one
+    after the other write; a caller that asks for another batch than it
+    named gets that batch; a write that fails is raised by the next call."""
+    from dcr_tpu.cli.precompute import PrecomputeJob
+
+    make, tmp_path = train_setup
+
+    def job(name):
+        cfg = make(name)
+        cfg.pipe.latent_cache = str(tmp_path / name)
+        cfg.pipe.cache_shard_size = 4
+        cfg.train_batch_size = 1            # a row a (virtual) device
+        return PrecomputeJob(cfg)
+
+    ahead = job("ahead")
+    batches = len(ahead)
+    assert batches == 2 and ahead.batch_size == 8
+    if case == "named_ahead":
+        for n in range(batches):
+            ahead.encode_batch(n, n + 1 if n + 1 < batches else None)
+            assert (ahead._ahead or [None])[0] == (n + 1 if n + 1 < batches else None)
+        ahead.drain()
+        assert len(list((tmp_path / "ahead").glob("shard_*.npz"))) == 4
+        serial = job("serial")
+        for n in range(batches):
+            serial.encode_batch(n)
+        shards = lambda j: json.loads(j.finalize().read_text())["shards"]  # noqa: E731
+        assert shards(ahead) == shards(serial)
+    elif case == "another_batch_asked":
+        ahead.encode_batch(0, 1)
+        got = ahead.encode_batch(0)
+        want = job("serial").encode_batch(0)
+        assert got["index"].tolist() == want["index"].tolist()
+        for name in ("mean", "std", "ctx"):
+            np.testing.assert_array_equal(got[name], want[name])
+        ahead.close()
+    else:
+        def full(*args):
+            raise OSError("no space left on device")
+
+        ahead.writer.add = full
+        ahead.encode_batch(0, 1)
+        with pytest.raises(OSError, match="no space"):
+            ahead.encode_batch(1)
+
+
 @pytest.mark.slow
 def test_precompute_and_cache_fed_training(train_setup):
     """dcr-precompute-latents -> Trainer(pipe.latent_cache): encoders never
