@@ -15,7 +15,18 @@ ONE program:
   queries replicated), so GSPMD runs the matmul as per-device partial
   products — the pjit-sharded equivalent of the reference's chunk loop;
 - the ``search/topk`` program does matmul + pad-mask + ``lax.top_k`` — the
-  global merge across mesh shards happens ON DEVICE inside the program;
+  global merge across mesh shards happens ON DEVICE inside the program. The
+  host's exchanges with the runtime overlap the scan instead of following
+  it: the answer's two host copies are queued at dispatch
+  (``copy_to_host_async``), so the runtime starts both when the program
+  ends instead of one after the other when the caller asks, and the fetch
+  pays the tail of one transfer, not two round trips; and inside ONE
+  placed segment the loop dispatches chunk n+1 before it collects chunk n
+  (two executions in flight, never more, never across segments) where
+  ``build()`` saw room for a second execution's temporaries — the compiled
+  program's ``memory_analysis()`` against the mesh devices' free memory
+  (``memory_stats()``) — and stays serial where it did not or where either
+  figure cannot be read (gauge ``search/dispatch_ahead``);
 - across segments (a store bigger than resident memory) the [B, K] tables
   merge on host — K rows per segment, not N: host traffic shrinks from the
   brute force's [B, N] similarity slabs to the answer itself.
@@ -97,6 +108,19 @@ def merge_topk(scores: np.ndarray, keys: np.ndarray, new_scores: np.ndarray,
             np.take_along_axis(all_keys, order, axis=1))
 
 
+def room_for_two(execution_bytes: Optional[int],
+                 free_bytes: Optional[int]) -> bool:
+    """Whether two ``search/topk`` executions fit the device at once: what
+    decides if ``_scan_segment`` dispatches a chunk ahead. ``execution_bytes``
+    is what ONE execution allocates (the compiled program's temporaries and
+    outputs: the [query_batch, segment_rows] scores are most of it),
+    ``free_bytes`` what the device had left before any ran. An unknown
+    figure (XLA:CPU reports neither) is no room."""
+    if execution_bytes is None or free_bytes is None:
+        return False
+    return free_bytes >= 2 * execution_bytes
+
+
 class ShardedTopK:
     """Compiled mesh-sharded top-k over an :class:`EmbeddingStoreReader`.
 
@@ -141,6 +165,9 @@ class ShardedTopK:
         self._row_sharding = None
         self._q_sharding = None
         self._fn = None
+        # build()'s decision (room_for_two): whether a chunk is dispatched
+        # before the previous one is collected, or the loop is serial
+        self._ahead = False
         self._normalize_rows = bool(normalize_rows)
         self._built = False
 
@@ -236,16 +263,42 @@ class ShardedTopK:
             # device (keys + row counts ride the device tuples) — dropping
             # them halves the engine's host-RAM footprint
             self._segments = []
+            # placed, not on their way: the free figure below is read after
+            jax.block_until_ready([seg[:2] for seg in self._dev_segments])
+        mem = res.memory or {}
+        execution = (mem["temp_bytes"] + mem.get("output_bytes", 0)
+                     if "temp_bytes" in mem else None)
+        free = self._free_bytes()
+        self._ahead = room_for_two(execution, free)
         self._built = True
         reg = tracing.registry()
         reg.gauge("search/index_rows").set(self.reader.total)
         reg.gauge("search/index_segments").set(self.num_segments)
+        reg.gauge("search/dispatch_ahead").set(int(self._ahead))
         log.info("shardindex: ready — %d rows in %d segment(s) of %d "
-                 "(top_k=%d, batch=%d, %s, program %s)", self.reader.total,
+                 "(top_k=%d, batch=%d, %s, program %s; an execution takes "
+                 "%s bytes of %s free: %s)", self.reader.total,
                  self.num_segments, self.segment_rows, k, self.query_batch,
                  "device-resident" if self.resident else "host-streamed",
-                 res.source)
+                 res.source, execution, free,
+                 "a chunk ahead" if self._ahead else "serial")
         return self
+
+    def _free_bytes(self) -> Optional[int]:
+        """The least free memory over this process's devices of the mesh
+        once the resident segments are placed, less ONE more segment's
+        share: the one a host-streamed scan or a ``query_rows`` tail places
+        beside them. None where a device reports no figures (XLA:CPU). A
+        device of THIS mesh against the program's bytes on a device:
+        ``memwatch.remaining_device_bytes`` sums over the whole host."""
+        free = []
+        for dev in self.mesh.local_devices:
+            stats = dev.memory_stats() or {}
+            if "bytes_limit" not in stats or "bytes_in_use" not in stats:
+                return None
+            free.append(int(stats["bytes_limit"]) - int(stats["bytes_in_use"]))
+        segment = self.segment_rows * (4 * self.reader.embed_dim + 1)
+        return min(free) - segment // len(self.mesh.devices.flat)
 
     def _put_segment(self, seg):
         import jax
@@ -313,28 +366,52 @@ class ShardedTopK:
                                jax.device_put(chunk, self._q_sharding)))
         return chunks
 
+    def _dispatch(self, feats, valid, chunk_dev):
+        """Start one chunk's scan of one placed segment; returns the two
+        results (on the device, their host copies on the way)."""
+        with tracing.span("search/dispatch"):
+            scores, idx = self._fn(feats, valid, chunk_dev)
+            # the answer is wanted on the host: said now, the runtime starts
+            # both copies when the program ends instead of when the caller
+            # asks, one after the other, once it has woken up
+            scores.copy_to_host_async()
+            idx.copy_to_host_async()
+        tracing.registry().counter("search/host_copy_queued_total").inc(2)
+        return scores, idx
+
     def _scan_segment(self, si: int, seg, chunks, out_scores: np.ndarray,
                       out_keys: np.ndarray) -> None:
         """Run every query chunk against one placed segment and fold the
-        [B, K] tables into the running answer in place."""
+        [B, K] tables into the running answer in place, in the chunks'
+        order. Where ``build()`` saw room, chunk n+1 is dispatched before
+        chunk n is collected: two executions in flight at most, both local
+        to this call and collected before it returns (nothing runs ahead
+        across segments)."""
         reg = tracing.registry()
+        ran_ahead = reg.counter("search/dispatch_ahead_total")
+        scanned = reg.counter("search/segments_scanned_total")
         feats, valid, keys, n_rows = seg
-        for start, m, chunk_dev in chunks:
+        ahead = None                    # the next chunk's results, if started
+        for n, (start, m, chunk_dev) in enumerate(chunks):
             with tracing.span("search/topk", segment=si,
                               rows=int(n_rows), batch=m,
                               index_size=self.reader.total):
-                with tracing.span("search/dispatch"):
-                    scores, idx = self._fn(feats, valid, chunk_dev)
-                # one host call that returns when the fetch would have: the
+                scores, idx = ahead or self._dispatch(feats, valid, chunk_dev)
+                ahead = None
+                if self._ahead and n + 1 < len(chunks):
+                    ahead = self._dispatch(feats, valid, chunks[n + 1][2])
+                    ran_ahead.inc()
+                # one host call that returns when the program has: the
                 # device's share of the call, apart from the copies below
                 # (both results are one program's: one is ready, both are;
                 # waiting on the pair costs 0.05 ms a call more on a v5e)
                 with tracing.span("search/device_wait"):
                     scores.block_until_ready()
+                # what the copies queued at dispatch still cost the caller
                 with tracing.span("search/fetch"):
                     scores = np.asarray(scores)[:m]
                     idx = np.asarray(idx)[:m]
-            reg.counter("search/segments_scanned_total").inc()
+            scanned.inc()
             with tracing.span("search/merge"):
                 # pad hits (score -inf) keep key "" — invisible post-merge
                 seg_keys = np.where(np.isneginf(scores), "", keys[idx])

@@ -150,7 +150,13 @@ SEARCH_CHILDREN = ("search/put", "search/topk", "search/merge")
 TOPK_CHILDREN = ("search/dispatch", "search/device_wait", "search/fetch")
 
 
-def test_one_query_is_one_root_whose_children_fit_inside_it(tmp_path, rng_np):
+@pytest.mark.parametrize("ahead", [0, 1], ids=["serial", "ahead"])
+def test_one_query_is_one_root_whose_children_fit_inside_it(tmp_path, rng_np,
+                                                            ahead):
+    """The same tree in both orders of the loop (PR 29): serial, a chunk's
+    `search/topk` holds its own dispatch, wait and fetch; a chunk ahead, it
+    holds the NEXT chunk's dispatch (the first holds two, the last none)
+    before this chunk's wait and fetch."""
     from dcr_tpu.search.shardindex import open_engine
     from dcr_tpu.search.store import EmbeddingStoreWriter
 
@@ -160,6 +166,10 @@ def test_one_query_is_one_root_whose_children_fit_inside_it(tmp_path, rng_np):
                np.arange(40).astype(str))
     writer.finalize()
     engine = open_engine(tmp_path / "store", top_k=2, query_batch=4)
+    # a CPU device reports no memory, so build() decided serial: the other
+    # order is set on the built object
+    assert engine._ahead == 0
+    engine._ahead = ahead
     q = rng_np.standard_normal((6, 16)).astype(np.float32)     # two chunks
     engine.query(q)                     # compiles; the spans of this one go
     tracing.reset_for_tests()
@@ -192,6 +202,17 @@ def test_one_query_is_one_root_whose_children_fit_inside_it(tmp_path, rng_np):
     counters = tracing.registry().counters("search/")
     assert counters["search/query_total"] == 1
     assert counters["search/segments_scanned_total"] == scans
+    # and the two that say how the call was collected
+    assert counters["search/host_copy_queued_total"] == 2 * scans
+    assert counters["search/dispatch_ahead_total"] == (
+        ahead * engine.num_segments)
+    dispatches = sorted(tracing.timeline("search/dispatch"))
+    waits = sorted(tracing.timeline("search/device_wait"))
+    for seg in range(engine.num_segments):
+        # two chunks a segment: ahead, both are dispatched before the first
+        # is waited for; serial, the second only after it
+        second, first_wait = dispatches[2 * seg + 1], waits[2 * seg]
+        assert (second[0] < first_wait[0]) == bool(ahead)
 
 
 # ---------------------------------------------------------------------------
