@@ -660,8 +660,11 @@ class ServeConfig:
 
 
 def validate_serve_config(cfg: ServeConfig) -> None:
-    if cfg.sampler not in ("ddim", "dpm++", "ddpm"):
-        raise ValueError("serve sampler must be 'ddim', 'dpm++' or 'ddpm'")
+    from dcr_tpu.sampling.sampler import SAMPLERS
+
+    if cfg.sampler not in SAMPLERS:
+        raise ValueError(f"serve sampler must be one of {tuple(SAMPLERS)}, "
+                         f"got {cfg.sampler!r}")
     if cfg.max_batch < 1:
         raise ValueError("serve max_batch must be >= 1")
     if cfg.queue_depth < 1:

@@ -172,7 +172,9 @@ def inference_timesteps(sched: NoiseSchedule, num_inference_steps: int,
 # ---------------------------------------------------------------------------
 
 def ddpm_step(sched: NoiseSchedule, model_out: jax.Array, x_t: jax.Array,
-              t: jax.Array, prev_t: jax.Array, key: jax.Array) -> jax.Array:
+              t: jax.Array, prev_t: jax.Array, noise: jax.Array) -> jax.Array:
+    """Ancestral update; ``noise`` is the caller's standard-normal draw,
+    shaped like ``x_t`` (who keys it, and how, is the sampler's business)."""
     x0, eps = pred_to_x0_eps(sched, model_out, x_t, t)
     x0 = jnp.clip(x0, -1000.0, 1000.0)
     acp = _gather(sched.alphas_cumprod, t, x_t.ndim)
@@ -184,7 +186,6 @@ def ddpm_step(sched: NoiseSchedule, model_out: jax.Array, x_t: jax.Array,
     coef_xt = jnp.sqrt(alpha_t) * (1.0 - acp_prev) / (1.0 - acp)
     mean = coef_x0 * x0 + coef_xt * x_t
     var = beta_t * (1.0 - acp_prev) / (1.0 - acp)
-    noise = jax.random.normal(key, x_t.shape, x_t.dtype)
     add_noise_mask = _bcast(jnp.asarray(prev_t) >= 0, x_t.ndim)
     return jnp.where(add_noise_mask,
                      mean + jnp.sqrt(jnp.maximum(var, 1e-20)) * noise, mean)

@@ -42,12 +42,12 @@ from dcr_tpu.core import warmcache
 from dcr_tpu.core.compile_surface import compile_surface
 from dcr_tpu.core.config import ServeConfig
 from dcr_tpu.core.metrics import LatencyTracker, MetricWriter
-from dcr_tpu.models import schedulers as S
 from dcr_tpu.models.vae import vae_scale_factor
 from dcr_tpu.obs import memwatch
 from dcr_tpu.sampling import fastsample
 from dcr_tpu.sampling.pipeline import GenerationStack
-from dcr_tpu.sampling.sampler import fast_plan_grid, scheduler_step
+from dcr_tpu.sampling.sampler import (SAMPLERS, denoise_images,
+                                      embedding_noise)
 from dcr_tpu.serve.batcher import Batcher
 from dcr_tpu.serve.cache import EmbeddingCache, embedding_key, mitigation_tag
 from dcr_tpu.serve.queue import (AdmissionError, BucketLimitError,
@@ -58,7 +58,6 @@ from dcr_tpu.utils import profiling
 
 log = logging.getLogger("dcr_tpu")
 
-SAMPLERS = ("ddim", "dpm++", "ddpm")
 MAX_STEPS = 1000        # more denoising steps than train timesteps is nonsense
 MAX_RESOLUTION = 4096
 
@@ -69,7 +68,7 @@ def validate_bucket(bucket: GenBucket, *, vae_scale: int) -> None:
     failure (500) — and never a compiled-and-cached degenerate program."""
     if bucket.sampler not in SAMPLERS:
         raise InvalidRequestError(
-            f"sampler must be one of {SAMPLERS}, got {bucket.sampler!r}")
+            f"sampler must be one of {tuple(SAMPLERS)}, got {bucket.sampler!r}")
     if not 1 <= bucket.steps <= MAX_STEPS:
         raise InvalidRequestError(
             f"steps must be in [1, {MAX_STEPS}], got {bucket.steps}")
@@ -102,18 +101,12 @@ def make_batch_sampler(bucket: GenBucket, models, root_seed: int,
     seeds: [B] uint32 per-request seeds. Every stochastic draw for row i uses
     only ``fold_in(root_key(root_seed), seeds[i])``-derived keys, generated
     per-row, so row i's image is a pure function of (params, cond[i],
-    seeds[i]) — batch composition cannot perturb it.
+    seeds[i]) — batch composition cannot perturb it. This builder owns the
+    per-request keying; the loop is the bulk sampler's
+    :func:`~dcr_tpu.sampling.sampler.denoise_images`.
     """
-    sched = models.schedule
-    ts, prev_ts, lower_order_final, plan = fast_plan_grid(
-        bucket.sampler, sched, bucket.steps, bucket.fast_ratio)
-    # dense plan => the ORIGINAL scan body, bit-identical to the pre-fast
-    # sampler; a reuse plan is a distinct compiled program for this bucket
-    use_fast = not fastsample.is_dense(plan)
     latent_size = bucket.resolution // vae_scale_factor(models.vae.config)
     latent_ch = models.vae.config.vae_latent_channels
-    scaling = models.vae.config.vae_scaling_factor
-    guidance = bucket.guidance
     lam = bucket.rand_noise_lam
 
     def sample_fn(params, cond, uncond, seeds):
@@ -127,74 +120,26 @@ def make_batch_sampler(bucket: GenBucket, models, root_seed: int,
                 f"{batch_size}; got {cond.shape[0]} rows — pad the batch")
         root = rngmod.root_key(root_seed)
         keys = jax.vmap(lambda s: jax.random.fold_in(root, s))(seeds)
-        if lam > 0.0:
-            # Newpipe mitigation noise, per-request: fresh noise even for a
-            # cache-hit embedding, independent of the rest of the batch
-            def noise_pair(c, u, k):
-                k1, k2 = jax.random.split(rngmod.stream_key(k, "emb_noise"))
-                return (c + lam * jax.random.normal(k1, c.shape, c.dtype),
-                        u + lam * jax.random.normal(k2, u.shape, u.dtype))
-            cond, uncond = jax.vmap(noise_pair)(cond, uncond, keys)
+        # Newpipe mitigation noise, per-request: fresh noise even for a
+        # cache-hit embedding, independent of the rest of the batch
+        cond, uncond = jax.vmap(lambda c, u, k: embedding_noise(
+            c, u, rngmod.stream_key(k, "emb_noise"), lam))(cond, uncond, keys)
         ctx = jnp.concatenate([uncond, cond], axis=0)      # [2B, L, D]
 
         x = jax.vmap(lambda k: jax.random.normal(
             rngmod.stream_key(k, "init"),
             (latent_size, latent_size, latent_ch)))(keys)  # [B, h, w, c]
         step_keys = jax.vmap(lambda k: rngmod.stream_key(k, "steps"))(keys)
-
-        def denoise(carry, step_idx):
-            if use_fast:
-                x, dpm_state, bank = carry
-            else:
-                x, dpm_state = carry
-            t = ts[step_idx]
-            prev_t = prev_ts[step_idx]
-            bsz = x.shape[0]
-
-            def predict():
-                tb = jnp.full((2 * bsz,), t, jnp.int32)
-                pred = models.unet.apply({"params": params["unet"]},
-                                         jnp.concatenate([x, x], axis=0), tb,
-                                         ctx)
-                with jax.named_scope("cfg"):
-                    pred_uncond, pred_cond = jnp.split(pred, 2, axis=0)
-                    return pred_uncond + guidance * (pred_cond - pred_uncond)
-
-            if use_fast:
-                # elementwise over the batch, plan uniform per bucket: row
-                # i's reuse/extrapolation depends only on row i's banked
-                # scores, so batch-composition bit-independence survives
-                pred, bank = fastsample.predict_or_reuse(
-                    plan, step_idx, t, bank, bucket.fast_order, predict)
-            else:
-                pred = predict()
-            if bucket.sampler == "ddpm":
-                # per-row keys via vmap: the ancestral noise of request i
-                # must not depend on batch position or neighbors (the bulk
-                # pipeline draws ONE batch-shaped noise per step instead)
-                x_new = jax.vmap(
-                    lambda p_row, x_row, k_row: scheduler_step(
-                        bucket.sampler, sched, p_row, x_row, t, prev_t, None,
-                        noise_key=jax.random.fold_in(k_row, step_idx))[0])(
-                    pred, x, step_keys)
-                dpm_new = dpm_state
-            else:
-                force1 = jnp.logical_and(lower_order_final,
-                                         step_idx == len(ts) - 1)
-                x_new, dpm_new = scheduler_step(
-                    bucket.sampler, sched, pred, x, t, prev_t, dpm_state,
-                    force_first_order=force1)
-            if use_fast:
-                return (x_new, dpm_new, bank), ()
-            return (x_new, dpm_new), ()
-
-        init = (x, S.dpm_init_state(x.shape))
-        if use_fast:
-            init = init + (fastsample.bank_init(x.shape),)
-        (x, *_), _ = jax.lax.scan(denoise, init, jnp.arange(len(ts)))
-        images = models.vae.apply({"params": params["vae"]}, x / scaling,
-                                  method=models.vae.decode)
-        return jnp.clip(images * 0.5 + 0.5, 0.0, 1.0)
+        return denoise_images(
+            models, params, ctx, x, sampler=bucket.sampler,
+            steps=bucket.steps, guidance=bucket.guidance,
+            fast_ratio=bucket.fast_ratio, fast_order=bucket.fast_order,
+            # per-row keys via vmap: the ancestral noise of request i must
+            # not depend on batch position or neighbors (the bulk pipeline
+            # draws ONE batch-shaped noise per step instead)
+            step_noise=lambda step_idx: jax.vmap(
+                lambda k: jax.random.normal(jax.random.fold_in(k, step_idx),
+                                            x.shape[1:], x.dtype))(step_keys))
 
     return jax.jit(sample_fn)
 

@@ -105,11 +105,35 @@ def test_ddpm_step_terminal_is_mean_only():
     noise = jax.random.normal(jax.random.fold_in(key, 1), x0.shape)
     t = jnp.array([0])
     xt = S.add_noise(s, x0, noise, t)
-    out1 = S.ddpm_step(s, noise, xt, t, jnp.array([-1]), jax.random.key(7))
-    out2 = S.ddpm_step(s, noise, xt, t, jnp.array([-1]), jax.random.key(8))
+    out1 = S.ddpm_step(s, noise, xt, t, jnp.array([-1]),
+                       jax.random.normal(jax.random.key(7), xt.shape))
+    out2 = S.ddpm_step(s, noise, xt, t, jnp.array([-1]),
+                       jax.random.normal(jax.random.key(8), xt.shape))
     # at prev_t=-1 no noise is added -> deterministic, and equals x0_hat
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=1e-6)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(x0), atol=1e-4)
+
+
+def test_ddpm_step_with_handed_noise_gives_the_keyed_forms_bits():
+    """ddpm_step takes the noise, not the key (the samplers own the keying).
+    Handed ``normal(key, x.shape, x.dtype)`` — the one draw the keyed form
+    made inside — it must give the keyed form's bits: the values below were
+    recorded from ``S.ddpm_step(s, eps, x, t, prev_t, jax.random.key(9))``
+    at the commit before the signature changed (eager, CPU)."""
+    s = _sched()
+    key = jax.random.key(6)
+    x = jax.random.normal(key, (2, 2, 2, 2))
+    eps = jax.random.normal(jax.random.fold_in(key, 1), x.shape)
+    t, prev_t = jnp.array([500, 300]), jnp.array([400, -1])
+    out = S.ddpm_step(s, eps, x, t, prev_t,
+                      jax.random.normal(jax.random.key(9), x.shape, x.dtype))
+    keyed_form_bits = np.array(
+        [991793152, 1049050062, 3214117976, 3220716424, 1060319644,
+         3185310544, 3184241416, 3217891960, 3189440952, 3206424983,
+         3169621836, 1054016327, 3165748159, 3222901389, 1062343930,
+         1066054571], np.uint32)
+    np.testing.assert_array_equal(
+        np.asarray(out).view(np.uint32).ravel(), keyed_form_bits)
 
 
 def test_dpmpp_2m_perfect_model_recovers_x0():
@@ -154,7 +178,8 @@ def test_steps_support_batched_prev_t():
         single = S.ddim_step(s, eps[i:i + 1], x[i:i + 1],
                              t[i:i + 1], prev_t[i:i + 1])
         np.testing.assert_allclose(np.asarray(out[i]), np.asarray(single[0]), atol=1e-6)
-    out2 = S.ddpm_step(s, eps, x, t, prev_t, jax.random.key(9))
+    out2 = S.ddpm_step(s, eps, x, t, prev_t,
+                       jax.random.normal(jax.random.key(9), x.shape))
     assert out2.shape == x.shape
     state = S.dpm_init_state(x.shape, batch_shape=t.shape)
     out3, state = S.dpmpp_2m_step(s, eps, x, t, prev_t, state)
