@@ -83,13 +83,28 @@ def test_f32_4096_keys_compile_at_every_batch(one_chip, rows):
     _compile(_forward, (rows, 4096, 5, 64), jnp.float32, one_chip)
 
 
-def test_f32_4096_keys_refused_with_1024_blocks(one_chip):
-    """Why block_q is 512 there: (1024, 1024) is refused from 4 rows on
-    ("Scoped allocation with size 16.34M and limit 16.00M exceeded scoped
-    vmem limit")."""
+@pytest.mark.parametrize("limit_mib,shape,dtype", [
+    (16, (4, 4096, 5, 64), jnp.float32),       # Mosaic's default: 16.08M asked
+    (16, (2, 9216, 5, 64), jnp.bfloat16),      # 16.60M asked
+    (None, (1, 16384, 5, 64), jnp.bfloat16),   # what supported() refuses
+], ids=["default_limit_f32_4096", "default_limit_bf16_9216",
+        "stated_limit_bf16_16384"])
+def test_vmem_limit_is_what_the_resident_slabs_need(one_chip, monkeypatch,
+                                                    limit_mib, shape, dtype):
+    """Why the kernels state VMEM_LIMIT_BYTES: a 128-lane K and V slab and a
+    batch row's lse, double-buffered, pass Mosaic's own 16 MiB at shapes the
+    dispatcher sends ("Scoped allocation with size 16.08M and limit 16.00M
+    exceeded scoped vmem limit"); and why supported() stops where it does:
+    1,024 px (16,384 keys) passes the stated limit too."""
+    x = jax.ShapeDtypeStruct(shape, dtype)
+    assert fa.supported(x, x, x) is (limit_mib is not None)
+    if limit_mib is not None:
+        _compile(_forward, shape, dtype, one_chip)      # fits as shipped
+        monkeypatch.setattr(fa, "VMEM_LIMIT_BYTES", limit_mib * 2**20)
     with pytest.raises(Exception, match="vmem"):
-        _compile(lambda q, k, v: fa.flash_attention(q, k, v, False, 1024, 1024),
-                 (4, 4096, 5, 64), jnp.float32, one_chip)
+        # a function of its own: jit would hand back _forward's first trace
+        _compile(lambda q, k, v: fa.flash_attention(q, k, v), shape, dtype,
+                 one_chip)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -110,3 +125,121 @@ def test_every_supported_shape_compiles(one_chip, head_dim, dtype):
     if head_dim == 64 and dtype == jnp.bfloat16:
         # SD-2.1 at 768 px stays on the kernel; 1024 px does not fit VMEM
         assert 9216 in accepted and 16384 not in accepted
+
+
+def _entry_instructions(text):
+    """(name, opcode, whole line) of the entry computation's instructions."""
+    import re
+
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    found = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (?:\(.*?\)|\S+) "
+                       r"([\w\-]+)\((.*)$", entry, re.M)
+    return [(name, opcode, rest) for name, opcode, rest in found]
+
+
+@pytest.mark.parametrize("shape,dtype,fn", [
+    ((20, 1024, 5, 64), jnp.float32, _forward),
+    ((2, 4096, 5, 64), jnp.float32, _forward),
+    ((16, 1024, 5, 64), jnp.bfloat16, _forward_backward),
+], ids=["256px_f32_20rows", "512px_f32", "256px_bf16_16rows_fwd_bwd"])
+def test_kernel_reads_the_projections_own_layout(one_chip, shape, dtype, fn):
+    """[B, S, H*D] in, as to_q/to_k/to_v write it, through the reshape the
+    model makes, the kernel, and the reshape back: the compiled program is the
+    kernels and nothing else, no transpose and no copy. And the forward call
+    is what benchmark/metrics/flash_fwd_roofline.py looks for: a
+    tpu_custom_call whose result is the pair (out [B, S, H*D], lse f32
+    [B, S, 128]) and whose first operands are q, k, v."""
+    import re
+
+    from jax.experimental.layout import Format, Layout
+
+    from benchmark.metrics import flash_fwd_roofline
+
+    b, s, h, d = shape
+    # row-major, the layout the custom call asks of whatever feeds it (a bare
+    # parameter of [B, S, 320] would otherwise sit S-minor on the TPU)
+    fmt = Format(Layout(major_to_minor=(0, 1, 2)), one_chip)
+    x = jax.ShapeDtypeStruct((b, s, h * d), dtype, sharding=fmt)
+
+    def through_the_models_reshapes(*flat):
+        results = fn(*(a.reshape(b, s, h, d) for a in flat))
+        return jax.tree.map(lambda r: r.reshape(b, s, h * d), results)
+
+    text = jax.jit(through_the_models_reshapes, out_shardings=fmt
+                   ).lower(x, x, x).compile().as_text()
+    instructions = _entry_instructions(text)
+    opcodes = [opcode for _, opcode, _ in instructions]
+    assert "transpose" not in opcodes and "copy" not in opcodes
+    parameters = {name: int(re.match(r"(\d+)\)", rest).group(1))
+                  for name, opcode, rest in instructions
+                  if opcode == "parameter"}
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == (1 if fn is _forward else 3)
+    forward = [line for line in kernels if "flash_fwd" in line]
+    assert len(forward) == 1
+    t = {jnp.float32: "f32", jnp.bfloat16: "bf16"}[dtype]
+    match = re.search(
+        rf"= \({t}\[{b},{s},{h * d}\]\S*, f32\[{b},{s},128\]\S*\) "
+        r"custom-call\(%([\w.\-]+), %([\w.\-]+), %([\w.\-]+)\)", forward[0])
+    assert match, forward[0][:300]
+    assert [parameters[name] for name in match.groups()] == [0, 1, 2]
+    flops, moved = flash_fwd_roofline.kernel_work(forward[0].strip())
+    assert flops == 4.0 * b * s * s * h * d
+    itemsize = jnp.dtype(dtype).itemsize
+    assert moved == 4 * b * s * h * d * itemsize + b * s * 128 * 4
+
+
+@pytest.mark.parametrize("rows,dtype,backward", [(20, jnp.float32, False),
+                                                 (16, jnp.bfloat16, True)],
+                         ids=["sampler_256px", "train_step_256px"])
+def test_no_relayout_between_the_projections_and_the_kernels(
+        one_chip, monkeypatch, rows, dtype, backward):
+    """The model's own self-attention layer at the 256 px UNet's top level
+    (to_q/to_k/to_v, the dispatcher, to_out), forward as the sampler runs it
+    and differentiated as the train step does: the program holds no transpose
+    and no copy at all, and every kernel operand is a parameter, another
+    kernel's result or what a fusion (a projection) wrote."""
+    import re
+
+    from dcr_tpu.models import layers
+    from dcr_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    layer = layers.CrossAttention(num_heads=5, head_dim=64, out_dim=320,
+                                  dtype=dtype)
+    x = jax.ShapeDtypeStruct((rows, 1024, 320), dtype, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: layer.init(jax.random.key(0),
+                                          jnp.zeros(x.shape, dtype))))
+
+    def forward(params, x):
+        return layer.apply(params, x)
+
+    def forward_backward(params, x):
+        return jax.grad(lambda p, x: forward(p, x).astype(jnp.float32).sum(),
+                        argnums=(0, 1))(params, x)
+
+    text = jax.jit(forward_backward if backward else forward
+                   ).lower(params, x).compile().as_text()
+    instructions = _entry_instructions(text)
+    opcode_of = {name: opcode for name, opcode, _ in instructions}
+    assert "transpose" not in opcode_of.values()
+    assert "copy" not in opcode_of.values()
+    kernels = [(name, rest) for name, opcode, rest in instructions
+               if 'custom_call_target="tpu_custom_call"' in rest]
+    assert len(kernels) == (3 if backward else 1)
+    through = {"get-tuple-element", "bitcast", "copy-done", "copy-start"}
+
+    def source(name):       # past tuple reads, bitcasts and VMEM prefetches
+        while opcode_of[name] in through:
+            rest = next(r for n, _, r in instructions if n == name)
+            name = re.match(r"%([\w.\-]+)", rest).group(1)
+        return opcode_of[name]
+
+    for name, rest in kernels:
+        operands = re.findall(r"%([\w.\-]+)", rest[:rest.index(")")])
+        assert {source(o) for o in operands} <= {"fusion", "custom-call",
+                                                 "parameter"}, (name, operands)

@@ -24,6 +24,15 @@ def test_supported_shapes():
     assert not FA.supported(q3, k3, v3)  # CLIP cross-attn length falls back to XLA
     q4, k4, v4 = _rand_qkv(jax.random.key(0), d=48)
     assert not FA.supported(q4, k4, v4)
+    wide = _site((1, 256, 2, 256))       # a head is a slab or half of one:
+    assert not FA.supported(wide, wide, wide)     # no second layout for D = 256
+    many = _site((1, 256, 129, 64))      # a lane of lse a head
+    assert not FA.supported(many, many, many)
+    long_q = _site((1, 32768, 2, 64))    # a batch row's lse is resident
+    short_k = _site((1, 256, 2, 64))
+    assert not FA.supported(long_q, short_k, short_k)
+    at_768px = _site((1, 9216, 5, 64), jnp.bfloat16)
+    assert FA.supported(at_768px, at_768px, at_768px)
 
 
 def _site(shape, dtype=jnp.float32):
@@ -225,6 +234,78 @@ def test_flash_matches_xla_at_1024_keys_with_shipped_blocks(dtype, tol):
         assert a.dtype == dtype
         np.testing.assert_allclose(np.asarray(a, dtype=np.float32),
                                    np.asarray(b), atol=tol, rtol=tol)
+
+
+# heads to a 128-lane slab: H = 5 at D = 64 leaves the last slab half filled
+# (its other half is padding, NaN under the interpreter), H = 1 is that slab
+# alone, H = 2 one full slab, H = 3 at D = 128 a head a slab
+SLAB_CASES = [(5, 64), (1, 64), (2, 64), (4, 64), (3, 128)]
+
+
+def _logsumexp_by_head(q, k):
+    logits = jnp.einsum("bqhd,bkhd->bqhk", q.astype(jnp.float32),
+                        k.astype(jnp.float32)) / q.shape[-1] ** 0.5
+    return jax.nn.logsumexp(logits, axis=-1)              # [B, Sq, H]
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4), (jnp.bfloat16, 6e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("h,d", SLAB_CASES,
+                         ids=[f"h{h}_d{d}" for h, d in SLAB_CASES])
+def test_flash_matches_xla_by_slab_filling(h, d, dtype, tol):
+    """Forward AND gradients against _xla_attention with Sq != Sk, for every
+    way heads fill the slabs of [B, S, H*D]."""
+    q, k, v = _rand_qkv(jax.random.key(20 + h), b=2, sq=256, sk=128, h=h, d=d,
+                        dtype=dtype)
+    g = jax.random.normal(jax.random.key(21), q.shape, dtype)
+    out, vjp = jax.vjp(lambda *x: FA.flash_attention(*x, True), q, k, v)
+    ref, ref_vjp = jax.vjp(lambda *x: A._xla_attention(*x, None),
+                           *(x.astype(jnp.float32) for x in (q, k, v)))
+    assert out.dtype == dtype and out.shape == q.shape
+    np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
+                               np.asarray(ref), atol=tol, rtol=tol)
+    for a, b in zip(vjp(g), ref_vjp(g.astype(jnp.float32))):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a, dtype=np.float32),
+                                   np.asarray(b), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("h,d", SLAB_CASES,
+                         ids=[f"h{h}_d{d}" for h, d in SLAB_CASES])
+def test_lse_lane_h_is_head_hs_logsumexp(h, d):
+    """The forward's second result is ONE [B, Sq, 128] f32 array: lane h holds
+    head h's logsumexp, lanes from H on read 0 (the backward reads it as is)."""
+    q, k, v = _rand_qkv(jax.random.key(30 + h), b=2, sq=256, sk=128, h=h, d=d)
+    flat = lambda x: x.reshape(*x.shape[:2], h * d)
+    out, lse = FA._flash_fwd(flat(q), flat(k), flat(v), d, interpret=True,
+                             block_q=128, block_k=128)
+    assert out.shape == (2, 256, h * d)
+    assert lse.shape == (2, 256, FA.LANES) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse[..., :h]),
+                               np.asarray(_logsumexp_by_head(q, k)),
+                               atol=2e-5, rtol=2e-5)
+    assert not np.asarray(lse[..., h:]).any()
+
+
+def test_nan_in_the_last_slabs_padding_does_not_reach_the_result():
+    """320 lanes are 2.5 slabs: the kernels see lanes 320 to 383 of the last
+    one as padding that may hold anything. The interpreter fills it with NaN
+    (and starts every output as NaN); a product over the slab would carry it
+    into the logits, a select does not. Forward, lse and all three gradients
+    stay finite and equal to a run whose every lane is real (H = 6 with the
+    sixth head cut off afterwards)."""
+    from jax._src.pallas import primitives
+
+    assert np.isnan(primitives.uninitialized_value((), jnp.float32))
+    q6, k6, v6 = _rand_qkv(jax.random.key(40), b=1, sq=256, sk=256, h=6, d=64)
+    g6 = jax.random.normal(jax.random.key(41), q6.shape)
+    q, k, v, g = (x[:, :, :5] for x in (q6, k6, v6, g6))
+    out, vjp = jax.vjp(lambda *x: FA.flash_attention(*x, True, 128, 128), q, k, v)
+    out6, vjp6 = jax.vjp(lambda *x: FA.flash_attention(*x, True, 128, 128),
+                         q6, k6, v6)
+    for a, b in zip((out, *vjp(g)), (out6, *vjp6(g6))):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b[:, :, :5]))
 
 
 def test_softmax_stability_large_logits():

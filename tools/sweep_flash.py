@@ -9,7 +9,12 @@ of its own name, each of its runs is one `XLA Modules` event, and the `XLA Ops`
 inside that event split into the kernels (`tpu_custom_call`) and the rest (the
 relayouts round the kernel, or all of XLA's attention). Operands are
 [B, S, H*D], as the UNet's to_q/to_k/to_v hand them over, and the result is
-[B, S, H*D], as to_out takes it, so both paths pay their own relayouts.
+[B, S, H*D], as to_out takes it, so both paths pay their own relayouts. The
+kernel's variants take and return them row-major, the layout the custom call
+asks of the projections that feed it inside a model (a bare parameter of
+[B, S, 320] would sit S-minor on the TPU and be copied); XLA's variant is
+left the layout the compiler picks. Since PR 31 the kernels read that layout
+themselves, so their "rest" reads about nothing.
 
     python tools/sweep_flash.py [--out chiprun_out/sweep_flash] [--iters 10]
     python tools/sweep_flash.py --default-blocks --sites 8x1024x5x64:float32:fwd ...
@@ -31,6 +36,8 @@ sys.path.insert(0, str(REPO))
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
 
 from benchmark.lib import trace as tracelib
 from dcr_tpu.ops import attention, flash_attention as fa
@@ -152,6 +159,8 @@ def main() -> int:
         print("no TPU: device times come from the chip only (--tiny rehearses)")
         return 2
 
+    row_major = Format(Layout(major_to_minor=(0, 1, 2)),
+                       SingleDeviceSharding(device))
     records = []          # (what is printed, the jitted call or None, its operands)
     sites = args.sites or (TINY_SITES if args.tiny else SITES)
     for shape, dtype, differentiated in sites:
@@ -159,6 +168,7 @@ def main() -> int:
         keys = jax.random.split(jax.random.key(s * h + b), 4)
         operands = [jax.random.normal(k, (b, s, h * d), jnp.dtype(dtype))
                     for k in keys[:4 if differentiated else 3]]
+        kernel_operands = [jax.device_put(x, row_major) for x in operands]
         reference = None
         itemsize = jnp.dtype(dtype).itemsize
         for blocks in [None] + block_candidates(s, itemsize,
@@ -172,9 +182,13 @@ def main() -> int:
                    "tag": tag_of(shape, dtype, differentiated, blocks)}
             fn = variant(shape, differentiated, blocks, args.tiny)
             fn.__name__ = rec["tag"]
-            call = jax.jit(fn)
+            if blocks is None:
+                call, given = jax.jit(fn), operands
+            else:
+                call, given = jax.jit(fn, in_shardings=row_major,
+                                      out_shardings=row_major), kernel_operands
             try:
-                result = jax.block_until_ready(call(*operands))
+                result = jax.block_until_ready(call(*given))
             except Exception as e:       # a kernel the chip's compiler refuses
                 rec["error"] = repr(e)[:400]
                 call = None
@@ -184,7 +198,7 @@ def main() -> int:
                     reference = out
                 else:
                     rec["max_abs_err_vs_xla"] = float(jnp.max(jnp.abs(out - reference)))
-            records.append((rec, call, operands))
+            records.append((rec, call, given))
 
     trace_dir = out_dir / "raw"
     shutil.rmtree(trace_dir, ignore_errors=True)
