@@ -27,7 +27,8 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 
 from dcr_tpu.core import tracing
-from dcr_tpu.core.config import TrainConfig, parse_cli, validate_train_config
+from dcr_tpu.core.config import (TEXT_TOWERS, TrainConfig, parse_cli,
+                                 validate_train_config)
 
 log = logging.getLogger("dcr_tpu")
 
@@ -83,6 +84,7 @@ class PrecomputeJob:
             pretrained=pretrained_params, parts=("vae", "text"))
         self.encode_fn = E.make_encode_stage(cfg, self.models, self.mesh,
                                              emit="moments")
+        gauge_layers(cfg.model)
         fp = LC.cache_fingerprint(cfg, self.dataset, tokenizer,
                                   vae_params=self.frozen["vae"],
                                   text_params=self.frozen["text"])
@@ -195,14 +197,29 @@ class PrecomputeJob:
 
 def count_routing(stats: dict) -> None:
     """An expert tower's routing counts of one call into the moe/* counters
-    (`stats`: the host copy of `TextTowerOutput.moe_stats`)."""
+    (`stats`: the host copy of `TextTowerOutput.moe_stats`; a tower gives the
+    counts it has: `unheld` is not every tower's, and a stack without an
+    expert layer gives none)."""
     reg = tracing.registry()
     for counter, name in (("moe/assignments_total", "assignments"),
                           ("moe/assignments_held_total", "held"),
                           ("moe/assignments_zero_total", "zero"),
-                          ("moe/assignments_dropped_total", "dropped")):
-        reg.counter(counter).inc(int(stats[name]))
-    reg.gauge("moe/held_expert_load_max").set(int(stats["held_load_max"]))
+                          ("moe/assignments_dropped_total", "dropped"),
+                          ("moe/tokens_unheld_total", "unheld")):
+        reg.counter(counter).inc(int(stats.get(name, 0)))
+    reg.gauge("moe/held_expert_load_max").set(int(stats.get("held_load_max", 0)))
+
+
+def gauge_layers(model) -> None:
+    """The depth of a language-model tower (`model`: the ModelConfig) into the
+    gauges `tower/layers` and `moe/layers` (the layers whose counts
+    `count_routing` adds up), so that a mean load an expert a layer can be had
+    from the registry alone."""
+    block = TEXT_TOWERS[model.text_tower].block
+    if block is not None:
+        layers, expert_layers = getattr(model, block).layer_counts()
+        tracing.registry().gauge("tower/layers").set(layers)
+        tracing.registry().gauge("moe/layers").set(expert_layers)
 
 
 def precompute(cfg: TrainConfig) -> dict:

@@ -42,6 +42,7 @@ from dcr_tpu.core import dist
 from dcr_tpu.core import fsio
 from dcr_tpu.core import resilience as R
 from dcr_tpu.core import tracing
+from dcr_tpu.core.config import TEXT_TOWERS
 
 log = logging.getLogger("dcr_tpu")
 
@@ -633,7 +634,7 @@ def export_hf_layout(out_dir: str | Path, *, unet=None, vae=None, text_encoder=N
             # our own layout only: models/convert.py has no torch naming for
             # this tower until a published checkpoint is here to hold it to
             (sub / "config.json").write_text(json.dumps({
-                "architectures": [tower], **mc.get("longcat", {}),
+                "architectures": [tower], **mc.get(TEXT_TOWERS[tower].block, {}),
                 "vocab_size": mc.get("text_vocab_size"),
                 "max_position_embeddings": mc.get("text_max_length")}, indent=2))
             continue

@@ -107,12 +107,112 @@ class LongcatFlashConfig:
             v_head_dim=16, n_routed_experts=8, zero_expert_num=4, moe_topk=3)
 
     def held_range(self) -> tuple[int, int]:
-        count = (self.n_routed_experts - self.held_experts_first
-                 if self.held_experts_count < 0 else self.held_experts_count)
-        return self.held_experts_first, count
+        return _held_range(self)
+
+    def router_outputs(self) -> int:
+        return self.n_routed_experts + self.zero_expert_num
+
+    def experts_per_token(self) -> int:
+        return self.moe_topk
+
+    def layer_counts(self) -> tuple[int, int]:
+        """(layers, those of them with an expert layer): every double layer
+        has one."""
+        return self.num_layers, self.num_layers
 
 
-TEXT_TOWERS = ("clip", "longcat_flash")
+def _held_range(block) -> tuple[int, int]:
+    count = (block.n_routed_experts - block.held_experts_first
+             if block.held_experts_count < 0 else block.held_experts_count)
+    return block.held_experts_first, count
+
+
+@dataclass
+class OpenPanguUltraMoEConfig:
+    """Sizes of the openPangu-Ultra-MoE text tower
+    (models/openpangu_ultra_moe.py), under the keys of the published
+    config.json (FreedomIntelligence/openPangu-Ultra-MoE-718B); the defaults
+    are the published values. The vocabulary (or the slice of it held here)
+    and the sequence length are ModelConfig.text_vocab_size and
+    text_max_length, as for every tower."""
+
+    hidden_size: int = 7680
+    intermediate_size: int = 18432        # the dense SwiGLU of a leading layer
+    moe_intermediate_size: int = 2048     # a routed or shared expert's SwiGLU
+    num_hidden_layers: int = 61
+    first_k_dense_replace: int = 3        # leading layers whose FFN is dense
+    num_attention_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 256           # all of them: the router's outputs
+    n_shared_experts: int = 1             # every token passes them
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True           # the chosen weights sum to one, then scale
+    routed_scaling_factor: float = 2.5
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 2.56e7
+    # The routed experts THIS device holds: as LongcatFlashConfig's.
+    held_experts_first: int = 0
+    held_experts_count: int = -1
+
+    @staticmethod
+    def tiny() -> "OpenPanguUltraMoEConfig":
+        """CPU-test size: every mechanism present (one dense layer, two
+        expert layers of 8 routed experts and a shared one, top 3), nothing
+        wide."""
+        return OpenPanguUltraMoEConfig(
+            hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+            num_hidden_layers=3, first_k_dense_replace=1,
+            num_attention_heads=4, q_lora_rank=32, kv_lora_rank=16,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            n_routed_experts=8, num_experts_per_tok=3)
+
+    def held_range(self) -> tuple[int, int]:
+        return _held_range(self)
+
+    def router_outputs(self) -> int:
+        return self.n_routed_experts
+
+    def experts_per_token(self) -> int:
+        return self.num_experts_per_tok
+
+    def layer_counts(self) -> tuple[int, int]:
+        """(layers, those of them with an expert layer): all but the leading
+        dense ones."""
+        return self.num_hidden_layers, max(
+            0, self.num_hidden_layers - self.first_k_dense_replace)
+
+
+@dataclass(frozen=True)
+class TextTower:
+    """One row of TEXT_TOWERS: what every reader of `ModelConfig.text_tower`
+    needs to know of an architecture."""
+
+    module: str                 # "<module>:<class>", built by models/text_tower.py
+    block: Optional[str]        # the ModelConfig field that holds its sizes
+    held_dtype: str             # what its frozen leaves are HELD in
+    # whether train_text_encoder may be set: a tower held in bfloat16 is 2
+    # bytes a parameter frozen and 16 trained
+    trainable: bool
+
+
+#: name -> row. CLIP's sizes are ModelConfig's own text_* fields; its leaves
+#: (like the VAE's and the UNet's) are float32 and cast at the jit boundary. A
+#: language-model tower is held in bfloat16: at 2 bytes a parameter its
+#: chip's share is 10 GB, at 4 it is more than the chip.
+TEXT_TOWERS = {
+    "clip": TextTower("dcr_tpu.models.clip_text:CLIPTextModel", None,
+                      "float32", True),
+    "longcat_flash": TextTower(
+        "dcr_tpu.models.longcat_flash:LongcatFlashTextTower", "longcat",
+        "bfloat16", False),
+    "openpangu_ultra_moe": TextTower(
+        "dcr_tpu.models.openpangu_ultra_moe:OpenPanguUltraMoETextTower",
+        "openpangu", "bfloat16", False),
+}
 
 
 @dataclass
@@ -158,13 +258,17 @@ class ModelConfig:
     vae_layers_per_block: int = 2
     vae_latent_channels: int = 4
     vae_scaling_factor: float = 0.18215
-    # The text tower: "clip" (models/clip_text.py; the text_hidden_size /
-    # text_layers / text_heads / text_act fields below are its sizes) or
-    # "longcat_flash" (models/longcat_flash.py; its sizes are the `longcat`
-    # block, its output is projected to cross_attention_dim). text_vocab_size
-    # and text_max_length belong to whichever tower is chosen.
+    # The text tower, a name of TEXT_TOWERS: "clip" (models/clip_text.py; the
+    # text_hidden_size / text_layers / text_heads / text_act fields below are
+    # its sizes) or a frozen language model whose sizes are a block of its
+    # own and whose output is projected to cross_attention_dim:
+    # "longcat_flash" (models/longcat_flash.py, `longcat`) or
+    # "openpangu_ultra_moe" (models/openpangu_ultra_moe.py, `openpangu`).
+    # text_vocab_size and text_max_length belong to whichever tower is chosen.
     text_tower: str = "clip"
     longcat: LongcatFlashConfig = field(default_factory=LongcatFlashConfig)
+    openpangu: OpenPanguUltraMoEConfig = field(
+        default_factory=OpenPanguUltraMoEConfig)
     # CLIP text encoder (OpenCLIP ViT-H text tower for SD-2.1)
     text_vocab_size: int = 49408
     text_hidden_size: int = 1024
@@ -1051,19 +1155,23 @@ def run_name(cfg: TrainConfig) -> str:
 
 def validate_text_tower(m: ModelConfig) -> None:
     if m.text_tower not in TEXT_TOWERS:
-        raise ValueError(f"model.text_tower must be one of {TEXT_TOWERS}")
-    if m.text_tower != "longcat_flash":
-        return
-    lc = m.longcat
-    first, count = lc.held_range()
-    if first < 0 or count < 0 or first + count > lc.n_routed_experts:
         raise ValueError(
-            f"model.longcat holds routed experts [{first}, {first + count}) "
-            f"of {lc.n_routed_experts}: not a range of them")
-    if lc.moe_topk > lc.n_routed_experts + lc.zero_expert_num:
-        raise ValueError("model.longcat.moe_topk exceeds the router's outputs")
-    if lc.qk_rope_head_dim % 2:
-        raise ValueError("model.longcat.qk_rope_head_dim must be even (rotary pairs)")
+            f"model.text_tower must be one of {tuple(TEXT_TOWERS)}")
+    name = TEXT_TOWERS[m.text_tower].block
+    if name is None:
+        return
+    block = getattr(m, name)
+    first, count = block.held_range()
+    if first < 0 or count < 0 or first + count > block.n_routed_experts:
+        raise ValueError(
+            f"model.{name} holds routed experts [{first}, {first + count}) "
+            f"of {block.n_routed_experts}: not a range of them")
+    if block.experts_per_token() > block.router_outputs():
+        raise ValueError(f"model.{name} chooses more experts a token than "
+                         "the router has outputs")
+    if block.qk_rope_head_dim % 2:
+        raise ValueError(
+            f"model.{name}.qk_rope_head_dim must be even (rotary pairs)")
 
 
 def validate_train_config(cfg: TrainConfig) -> None:
@@ -1086,10 +1194,12 @@ def validate_train_config(cfg: TrainConfig) -> None:
     if cfg.model.seq_parallel_mode not in ("ring", "ulysses"):
         raise ValueError("seq_parallel_mode must be 'ring' or 'ulysses'")
     validate_text_tower(cfg.model)
-    if cfg.model.text_tower == "longcat_flash" and cfg.train_text_encoder:
+    tower = TEXT_TOWERS[cfg.model.text_tower]
+    if cfg.train_text_encoder and not tower.trainable:
         raise ValueError(
             "train_text_encoder=true is refused with model.text_tower="
-            "longcat_flash: the tower is held frozen in bfloat16 (2 bytes a "
+            f"{cfg.model.text_tower}: the tower is held frozen in "
+            f"{tower.held_dtype} (2 bytes a "
             "parameter); trained it costs 16 bytes a parameter (float32 "
             "weights, gradients and two Adam moments), which no cut of it "
             "fits on a chip beside the UNet's own optimizer state. Encode "

@@ -13,6 +13,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from dcr_tpu.core.config import TEXT_TOWERS
+
 
 @dataclass(frozen=True)
 class Policy:
@@ -43,11 +45,9 @@ class Policy:
 
 
 def text_param_dtype(text_tower: str) -> jnp.dtype:
-    """What a frozen text tower's leaves are HELD in. CLIP's (like the VAE's
-    and the UNet's) are float32 and cast at the jit boundary; the LongCat-Flash
-    tower alone is held in bfloat16: at 2 bytes a parameter its chip's share
-    is 10 GB, at 4 it is more than the chip."""
-    return jnp.bfloat16 if text_tower == "longcat_flash" else jnp.float32
+    """What a frozen text tower's leaves are HELD in: its row of
+    core/config.TEXT_TOWERS says."""
+    return jnp.dtype(TEXT_TOWERS[text_tower].held_dtype)
 
 
 def policy_from_string(mixed_precision: str) -> Policy:
