@@ -95,7 +95,8 @@ def build_modules(cfg: TrainConfig, mesh=None) -> "T.DiffusionModels":
         unet=UNet2DCondition(cfg.model, dtype=jnp.float32, mesh=mesh),
         vae=AutoencoderKL(cfg.model, dtype=jnp.float32),
         text_encoder=build_text_tower(
-            cfg.model, policy_from_string(cfg.mixed_precision).compute_dtype),
+            cfg.model, policy_from_string(cfg.mixed_precision).compute_dtype,
+            mesh=mesh),
         schedule=sched)
 
 

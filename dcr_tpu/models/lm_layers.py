@@ -18,7 +18,7 @@ compute in float32.
 
 from __future__ import annotations
 
-from typing import Callable, NamedTuple
+from typing import Callable, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -89,11 +89,14 @@ class MLA(nn.Module):
     one rotary key is shared by all heads. `cfg` is a tower's config block
     under the published keys; `mla_scale_q_lora` / `mla_scale_kv_lora`, where
     a block has them and they are true, multiply the normed latents by
-    sqrt(hidden / rank)."""
+    sqrt(hidden / rank). `mesh` is the mesh the enclosing jit spans, handed
+    on to the dispatcher, which sizes its XLA path's row groups from ONE
+    device's share of the batch."""
 
     cfg: object
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
+    mesh: Optional[jax.sharding.Mesh] = None
 
     @nn.compact
     def __call__(self, x: jax.Array, mask: jax.Array) -> jax.Array:
@@ -130,7 +133,7 @@ class MLA(nn.Module):
             [k_nope, jnp.broadcast_to(k_rope, (b, s, heads, rope))], axis=-1)
         # softmax(q k^T / sqrt(nope + rope)) v: the dispatcher's own scaling,
         # on its XLA path (a masked site, and v narrower than q/k)
-        out = dot_product_attention(q, k, v, mask=mask)
+        out = dot_product_attention(q, k, v, mask=mask, mesh=self.mesh)
         return dense(hidden, "o_proj")(out.reshape(b, s, heads * vd))
 
 

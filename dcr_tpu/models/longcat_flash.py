@@ -30,6 +30,8 @@ norms and the softmaxes compute in float32.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -69,6 +71,7 @@ class DoubleLayer(nn.Module):
     cfg: LongcatFlashConfig
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
+    mesh: Optional[jax.sharding.Mesh] = None
 
     @nn.compact
     def __call__(self, h: jax.Array, mask: jax.Array) -> tuple[jax.Array, dict]:
@@ -78,8 +81,8 @@ class DoubleLayer(nn.Module):
             norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype,  # noqa: E731
                                         self.param_dtype, name=name)
             with jax.named_scope("mla"):
-                h = h + MLA(c, self.dtype, self.param_dtype, name=f"mla_{i}")(
-                    norm(f"input_norm_{i}")(h), mask)
+                h = h + MLA(c, self.dtype, self.param_dtype, self.mesh,
+                            name=f"mla_{i}")(norm(f"input_norm_{i}")(h), mask)
             n = norm(f"post_attention_norm_{i}")(h)
             if i == 0:
                 shortcut, stats = ScMoE(c, self.dtype, self.param_dtype,
@@ -98,6 +101,7 @@ class LongcatFlashTextTower(nn.Module):
     config: ModelConfig
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.bfloat16
+    mesh: Optional[jax.sharding.Mesh] = None    # handed down to every MLA
 
     @nn.compact
     def __call__(self, input_ids: jax.Array) -> TextTowerOutput:
@@ -108,7 +112,7 @@ class LongcatFlashTextTower(nn.Module):
         mask = causal_mask(input_ids.shape[1])
         total = None
         for i in range(c.num_layers):
-            h, stats = DoubleLayer(c, self.dtype, self.param_dtype,
+            h, stats = DoubleLayer(c, self.dtype, self.param_dtype, self.mesh,
                                    name=f"layers_{i}")(h, mask)
             total = merge_stats(total, stats)
         h = RMSNorm(c.rms_norm_eps, self.dtype, self.param_dtype, name="norm")(h)

@@ -26,6 +26,8 @@ norms and the softmaxes compute in float32.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -69,6 +71,7 @@ class SandwichLayer(nn.Module):
     dense: bool                         # a leading layer: F is a dense SwiGLU
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
+    mesh: Optional[jax.sharding.Mesh] = None
 
     @nn.compact
     def __call__(self, h: jax.Array, mask: jax.Array):
@@ -81,7 +84,7 @@ class SandwichLayer(nn.Module):
 
         with jax.named_scope("mla"):
             h = h + norm("post_attention_layernorm")(MLA(
-                c, self.dtype, self.param_dtype, name="self_attn")(
+                c, self.dtype, self.param_dtype, self.mesh, name="self_attn")(
                     norm("input_layernorm")(h), mask))
         n = norm("pre_mlp_layernorm")(h)
         if self.dense:
@@ -102,6 +105,7 @@ class OpenPanguUltraMoETextTower(nn.Module):
     config: ModelConfig
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.bfloat16
+    mesh: Optional[jax.sharding.Mesh] = None    # handed down to every MLA
 
     @nn.compact
     def __call__(self, input_ids: jax.Array) -> TextTowerOutput:
@@ -114,7 +118,7 @@ class OpenPanguUltraMoETextTower(nn.Module):
         for i in range(c.num_hidden_layers):
             h, stats = SandwichLayer(
                 c, i < c.first_k_dense_replace, self.dtype, self.param_dtype,
-                name=f"layers_{i}")(h, mask)
+                self.mesh, name=f"layers_{i}")(h, mask)
             total = merge_stats(total, stats)
         h = RMSNorm(c.rms_norm_eps, self.dtype, self.param_dtype, name="norm")(h)
         with jax.named_scope("tower/ctx_proj"):

@@ -23,10 +23,13 @@ from dcr_tpu.core.config import TEXT_TOWERS, ModelConfig, validate_text_tower
 from dcr_tpu.core.precision import text_param_dtype
 
 
-def build_text_tower(cfg: ModelConfig, compute_dtype=jnp.float32) -> nn.Module:
+def build_text_tower(cfg: ModelConfig, compute_dtype=jnp.float32,
+                     mesh=None) -> nn.Module:
     """The tower's module (no parameters are made). CLIP computes in float32
     on leaves the caller casts; a tower held in bfloat16 computes in
-    `compute_dtype` on those leaves."""
+    `compute_dtype` on those leaves and hands `mesh`, the mesh the enclosing
+    jit spans, to its attention sites (ops/attention sizes a cut site's row
+    groups from one device's share)."""
     validate_text_tower(cfg)
     tower = TEXT_TOWERS[cfg.text_tower]
     module, _, name = tower.module.partition(":")
@@ -34,7 +37,7 @@ def build_text_tower(cfg: ModelConfig, compute_dtype=jnp.float32) -> nn.Module:
     if tower.held_dtype == "float32":
         return cls(cfg, dtype=jnp.float32)
     return cls(cfg, dtype=compute_dtype,
-               param_dtype=text_param_dtype(cfg.text_tower))
+               param_dtype=text_param_dtype(cfg.text_tower), mesh=mesh)
 
 
 def init_text_tower(cfg: ModelConfig, key: jax.Array, model: nn.Module):

@@ -61,6 +61,11 @@ LANES = 128      # TPU lane count: the width of a slab, and of the lse array
 # floor stays where XLA's cliff is. Under 1,024 keys the kernel is never a
 # tenth ahead (256 keys, 200 heads: 0.120 ms against 0.131 forward; 0.278
 # against 0.138 forward and backward), so the key length keeps its own floor.
+# Since PR 33 the logits floor serves TWO decisions, both about the one fact
+# that XLA's attention is fast for as long as a call's f32 logits stay on the
+# chip: kernel or XLA (should_use, below), and, for a site that stays on XLA
+# (a mask, a v narrower than q/k, under 1,024 keys), whole or in row groups
+# that are each at or under the floor (ops/attention._xla_attention).
 FLASH_MIN_SEQ = 1024
 FLASH_MIN_LOGITS_BYTES = 112 * 2**20
 

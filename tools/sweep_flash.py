@@ -1,7 +1,12 @@
 """Device-time sweep of self-attention on the local chip: XLA's fused attention
 against the Pallas flash kernels, per SD-2.1 site shape and per (block_q,
-block_k). `FLASH_MIN_SEQ` and `_resolve_blocks` in dcr_tpu/ops/flash_attention.py
-are set from what this prints (the readings are kept in PERF.md).
+block_k), and XLA's attention whole against the row groups the dispatcher cuts
+a site into whose float32 logits would not stay on the chip (`xla_grouped`;
+PR 33). `FLASH_MIN_SEQ`, `FLASH_MIN_LOGITS_BYTES` and `_resolve_blocks` in
+dcr_tpu/ops/flash_attention.py are set from what this prints (the readings are
+kept in PERF.md). A site may name a v width and a causal mask (a language-model
+tower's latent attention): the kernel takes neither, so such a site runs XLA's
+two variants only.
 
 Times are device times of the ops in ONE profiler capture, reduced by
 benchmark/lib/trace.py, never a host clock: every variant is a jitted function
@@ -18,6 +23,7 @@ themselves, so their "rest" reads about nothing.
 
     python tools/sweep_flash.py [--out chiprun_out/sweep_flash] [--iters 10]
     python tools/sweep_flash.py --default-blocks --sites 8x1024x5x64:float32:fwd ...
+    python tools/sweep_flash.py --sites 16x256x128x192:128:causal:bfloat16:fwd
     python tools/sweep_flash.py --tiny      # CPU rehearsal: interpret mode, no times
 """
 
@@ -26,10 +32,12 @@ from __future__ import annotations
 import argparse
 import itertools
 import json
+import math
 import shutil
 import statistics
 import sys
 from pathlib import Path
+from typing import NamedTuple
 
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
@@ -42,21 +50,30 @@ from jax.sharding import SingleDeviceSharding
 from benchmark.lib import trace as tracelib
 from dcr_tpu.ops import attention, flash_attention as fa
 
-# (B, S, H, D), dtype, differentiated: the self-attention sites of SD-2.1 in
-# the benchmark's cells. Forward-only float32 is the sampler (20 rows at
-# 256 px, 2 at 512 px); bfloat16 forward and backward is the train step.
+class Site(NamedTuple):
+    shape: tuple[int, int, int, int]    # (B, S, H, D) of q and k
+    dtype: str
+    differentiated: bool
+    v_width: int | None = None          # None: D
+    causal: bool = False
+
+
+# The self-attention sites of SD-2.1 in the benchmark's cells. Forward-only
+# float32 is the sampler (20 rows at 256 px, 2 at 512 px); bfloat16 forward
+# and backward is the train step.
 SITES = [
-    ((20, 1024, 5, 64), "float32", False),
-    ((2, 1024, 10, 64), "float32", False),
-    ((20, 256, 10, 64), "float32", False),
-    ((2, 256, 20, 64), "float32", False),
-    ((2, 4096, 5, 64), "float32", False),
-    ((8, 4096, 5, 64), "float32", False),     # 512 px at im_batch 4 (ROADMAP M1)
-    ((16, 1024, 5, 64), "bfloat16", True),
-    ((16, 256, 10, 64), "bfloat16", True),
+    Site((20, 1024, 5, 64), "float32", False),
+    Site((2, 1024, 10, 64), "float32", False),
+    Site((20, 256, 10, 64), "float32", False),
+    Site((2, 256, 20, 64), "float32", False),
+    Site((2, 4096, 5, 64), "float32", False),
+    Site((8, 4096, 5, 64), "float32", False),     # 512 px at im_batch 4 (ROADMAP M1)
+    Site((16, 1024, 5, 64), "bfloat16", True),
+    Site((16, 256, 10, 64), "bfloat16", True),
 ]
-TINY_SITES = [((1, 256, 2, 64), "float32", False),
-              ((1, 256, 2, 64), "bfloat16", True)]
+TINY_SITES = [Site((1, 256, 2, 64), "float32", False),
+              Site((1, 256, 2, 64), "bfloat16", True),
+              Site((4, 128, 2, 24), "bfloat16", True, 16, True)]
 BLOCKS = (256, 512, 1024)
 SWEPT_SEQ = (1024, 4096)      # other lengths run the default blocks only
 
@@ -73,27 +90,51 @@ def block_candidates(seq: int, itemsize: int, sweep: bool = True
     return [(None, None)] + sorted(pairs)
 
 
-def parse_site(text: str):
-    """'20x1024x5x64:float32:fwd' or '...:bfloat16:fwdbwd'."""
-    shape, dtype, direction = text.split(":")
-    return (tuple(int(x) for x in shape.split("x")), dtype,
-            {"fwd": False, "fwdbwd": True}[direction])
+def parse_site(text: str) -> Site:
+    """'20x1024x5x64:float32:fwd', '...:bfloat16:fwdbwd', or with a v width
+    and a causal mask between: '16x256x128x192:128:causal:bfloat16:fwd'."""
+    shape, *options, dtype, direction = text.split(":")
+    return Site(tuple(int(x) for x in shape.split("x")), dtype,
+                {"fwd": False, "fwdbwd": True}[direction],
+                next((int(o) for o in options if o.isdigit()), None),
+                "causal" in options)
 
 
-def variant(shape, differentiated: bool, blocks, interpret: bool):
-    """The jitted call of one path over [B, S, H*D] operands: `blocks` None is
-    XLA's attention, a pair the kernel with those blocks."""
-    b, s, h, d = shape
+def kernel_takes(site: Site) -> bool:
+    b, s, h, d = site.shape
+    x = jax.ShapeDtypeStruct(site.shape, jnp.dtype(site.dtype))
+    return (not site.causal and site.v_width in (None, d)
+            and fa.supported(x, x, x))
+
+
+def grouped_floor(site: Site, rehearsal: bool) -> int:
+    """The floor `xla_grouped` cuts at: the dispatcher's own, or in a CPU
+    rehearsal one row's logits, so that a tiny site is cut."""
+    _, s, h, _ = site.shape
+    return 4 * h * s * s if rehearsal else fa.FLASH_MIN_LOGITS_BYTES
+
+
+def variant(site: Site, path, interpret: bool):
+    """The jitted call of one path over [B, S, H*D] operands. `path` "xla" is
+    XLA's attention whole, "xla_grouped" what the dispatcher's XLA path does
+    with the site (row groups past `grouped_floor`), a pair the kernel with
+    those blocks."""
+    b, s, h, d = site.shape
+    dv = site.v_width or d
+    mask = jnp.tril(jnp.ones((s, s), bool))[None, None] if site.causal else None
+    floor = {"xla": math.inf,
+             "xla_grouped": grouped_floor(site, interpret)}.get(path)
 
     def attend(xq, xk, xv):
-        q, k, v = (x.reshape(b, s, h, d) for x in (xq, xk, xv))
-        if blocks is None:
-            out = attention._xla_attention(q, k, v, None)
+        q, k = xq.reshape(b, s, h, d), xk.reshape(b, s, h, d)
+        v = xv.reshape(b, s, h, dv)
+        if isinstance(path, str):
+            out = attention._xla_attention(q, k, v, mask, floor=floor)
         else:
-            out = fa.flash_attention(q, k, v, interpret, *blocks)
-        return out.reshape(b, s, h * d)
+            out = fa.flash_attention(q, k, v, interpret, *path)
+        return out.reshape(b, s, h * dv)
 
-    if not differentiated:
+    if not site.differentiated:
         return attend
 
     def attend_and_grads(xq, xk, xv, g):
@@ -103,11 +144,13 @@ def variant(shape, differentiated: bool, blocks, interpret: bool):
     return attend_and_grads
 
 
-def tag_of(shape, dtype: str, differentiated: bool, blocks) -> str:
-    path = "xla" if blocks is None else "flash_" + "_".join(
-        "d" if x is None else str(x) for x in blocks)
-    return (f"att_{'x'.join(map(str, shape))}_{dtype}_"
-            f"{'fwdbwd' if differentiated else 'fwd'}_{path}")
+def tag_of(site: Site, path) -> str:
+    name = path if isinstance(path, str) else "flash_" + "_".join(
+        "d" if x is None else str(x) for x in path)
+    latent = (f"v{site.v_width}" if site.v_width else "") + (
+        "c" if site.causal else "")
+    return (f"att_{'x'.join(map(str, site.shape))}{latent}_{site.dtype}_"
+            f"{'fwdbwd' if site.differentiated else 'fwd'}_{name}")
 
 
 def reduce_runs(trace: tracelib.Trace, tag: str) -> dict | None:
@@ -163,38 +206,47 @@ def main() -> int:
                        SingleDeviceSharding(device))
     records = []          # (what is printed, the jitted call or None, its operands)
     sites = args.sites or (TINY_SITES if args.tiny else SITES)
-    for shape, dtype, differentiated in sites:
-        b, s, h, d = shape
+    for site in sites:
+        b, s, h, d = site.shape
+        dv = site.v_width or d
         keys = jax.random.split(jax.random.key(s * h + b), 4)
-        operands = [jax.random.normal(k, (b, s, h * d), jnp.dtype(dtype))
-                    for k in keys[:4 if differentiated else 3]]
+        operands = [jax.random.normal(k, (b, s, h * w), jnp.dtype(site.dtype))
+                    for k, w in zip(keys, (d, d, dv, dv)[
+                        :4 if site.differentiated else 3])]
         kernel_operands = [jax.device_put(x, row_major) for x in operands]
         reference = None
-        itemsize = jnp.dtype(dtype).itemsize
-        for blocks in [None] + block_candidates(s, itemsize,
-                                                not args.default_blocks):
-            rec = {"shape": list(shape), "dtype": dtype,
-                   "differentiated": differentiated,
-                   "path": "xla" if blocks is None else "flash",
-                   "blocks": None if blocks is None else list(
-                       fa._resolve_blocks(s, s, *blocks, itemsize)),
-                   "default_blocks": blocks == (None, None),
-                   "tag": tag_of(shape, dtype, differentiated, blocks)}
-            fn = variant(shape, differentiated, blocks, args.tiny)
+        itemsize = jnp.dtype(site.dtype).itemsize
+        blocks = (block_candidates(s, itemsize, not args.default_blocks)
+                  if kernel_takes(site) else [])
+        for path in ["xla", "xla_grouped"] + blocks:
+            kernel = not isinstance(path, str)
+            rec = {"shape": list(site.shape), "dtype": site.dtype,
+                   "v_width": dv, "causal": site.causal,
+                   "differentiated": site.differentiated,
+                   "path": "flash" if kernel else path,
+                   "blocks": list(fa._resolve_blocks(s, s, *path, itemsize))
+                   if kernel else None,
+                   "default_blocks": path == (None, None),
+                   "tag": tag_of(site, path)}
+            if path == "xla_grouped":
+                rec["group"] = list(attention._group_of(
+                    b, h, s, s, grouped_floor(site, args.tiny)))
+            fn = variant(site, path, args.tiny)
             fn.__name__ = rec["tag"]
-            if blocks is None:
-                call, given = jax.jit(fn), operands
-            else:
+            if kernel:
                 call, given = jax.jit(fn, in_shardings=row_major,
                                       out_shardings=row_major), kernel_operands
+            else:
+                call, given = jax.jit(fn), operands
             try:
                 result = jax.block_until_ready(call(*given))
             except Exception as e:       # a kernel the chip's compiler refuses
                 rec["error"] = repr(e)[:400]
                 call = None
             else:
-                out = (result[0] if differentiated else result).astype(jnp.float32)
-                if blocks is None:
+                out = (result[0] if site.differentiated else result
+                       ).astype(jnp.float32)
+                if path == "xla":
                     reference = out
                 else:
                     rec["max_abs_err_vs_xla"] = float(jnp.max(jnp.abs(out - reference)))
