@@ -9,6 +9,12 @@ add the rows to the latent cache. The CLI's loop is `for number in range(...):
 job.encode_batch(number, number + 1)`; the window drives the same call round
 and round the dataset, back to back, one caller.
 
+A tower is three modules, named by the class attributes `stack` (the
+configuration's file as `--model.*` arguments, the reference's sizes, the
+seeded leaves, the layers with an expert layer), `flops` (the FLOPs of a
+unit) and `ref` (the plain reference): LongCat's here; another tower's
+driver subclasses this one and names its own.
+
 Traffic parameters (the workload's file): `train_config`, `overrides`,
 `images`, `image_px`, `caption_tokens`, `check_rows`, `reference.tie_eps`,
 `limits`.
@@ -21,8 +27,7 @@ import json
 import numpy as np
 
 from benchmark.lib import harness, lm_flops, lm_stack, sd_stack
-from benchmark.reference import longcat_flash as ref
-from benchmark.reference import sd21
+from benchmark.reference import longcat_flash, sd21
 
 MOE_COUNTERS = ("moe/assignments_total", "moe/assignments_held_total",
                 "moe/assignments_zero_total", "moe/assignments_dropped_total")
@@ -41,7 +46,33 @@ def rel_rms(got, want) -> float:
                  / max(np.sqrt(np.mean(want ** 2)), 1e-30))
 
 
+def window_phases(window) -> dict:
+    """Where a plain window's seconds went, for the reader of a log: of each
+    of the program's four phases the seconds inside the measured window and
+    the longest single span, and the median and the longest unit (a run that
+    sits low shows here whether one stall or every unit did it)."""
+    from benchmark.lib import program_spans as ps
+
+    lo, hi = window.t0, window.t0 + window.seconds
+    out = {}
+    for phase in ("load", "encode", "fetch", "write"):
+        spans = [d for t, d in ps.timeline(f"precompute/{phase}") if lo <= t < hi]
+        out[f"{phase}_s"] = round(sum(spans), 4)
+        out[f"{phase}_max_s"] = round(max(spans, default=0.0), 4)
+    units = sorted(d for _, d in window.unit_times)
+    if units:
+        out.update(unit_median_s=round(units[len(units) // 2], 4),
+                   unit_max_s=round(units[-1], 4),
+                   units_over_twice_median=sum(
+                       d > 2 * units[len(units) // 2] for d in units))
+    return out
+
+
 class Driver:
+    stack = lm_stack
+    flops = lm_flops
+    ref = longcat_flash
+
     def __init__(self, bench):
         self.bench = bench
         self.cfg = bench.cell.config
@@ -65,7 +96,7 @@ class Driver:
                 f"--data.train_data_dir={b.work / 'train'}",
                 f"--data.caption_jsons={b.work / 'captions.json'}",
                 f"--pipe.latent_cache={b.work / 'latent_cache'}",
-                *t.get("overrides", []), *lm_stack.model_argv(self.cfg, px)]
+                *t.get("overrides", []), *self.stack.model_argv(self.cfg, px)]
 
     def setup(self) -> None:
         import jax
@@ -81,7 +112,7 @@ class Driver:
         self.train_cfg = cfg = parse_cli(TrainConfig, self.job_argv())
         self.shapes = lm_stack.weight_shapes(cfg)
         weights = {"vae": lm_stack.vae_weights(self.shapes, b.seed),
-                   "text": lm_stack.tower_leaves(self.shapes, self.weights_seed)}
+                   "text": self.stack.tower_leaves(self.shapes, self.weights_seed)}
         jax.block_until_ready(weights)
         b.log("weights_made", weights_seed=self.weights_seed, tower_parameters=sum(
             int(np.prod(x.shape)) for x in jax.tree.leaves(weights["text"])))
@@ -107,7 +138,12 @@ class Driver:
         self._next = 1
         real = (self.fed["input_ids"] != 0).sum(axis=1)
         b.log("first_unit", real_tokens=real.tolist(), **self.first_counts,
-              **b.meter.snapshot())
+              **self.tower_counts(), **b.meter.snapshot())
+
+    def tower_counts(self) -> dict:
+        """What a tower's program counts beside `MOE_COUNTERS`, for the log's
+        `first_unit` and `routing` lines: nothing for LongCat."""
+        return {}
 
     # -- the window -----------------------------------------------------
     def unit(self) -> None:
@@ -134,25 +170,29 @@ class Driver:
         counts = moe_counters()
         units = max(1, self._next)
         held = counts["moe/assignments_held_total"] / units
-        experts = int(self.cfg["n_routed_experts"]) * int(self.cfg["num_layers"])
-        out = {"flops_per_unit": lm_flops.encode_unit_flops(
+        experts = int(self.cfg["n_routed_experts"]) * len(
+            self.stack.expert_layers(self.cfg))
+        out = {"flops_per_unit": self.flops.encode_unit_flops(
                    self.cfg, px, self.batch, seq, held),
                "held_assignments_per_unit": held,
                "dropped_assignments": counts["moe/assignments_dropped_total"]}
         if self.load_max and held > 0:
             out["held_load_max_over_mean"] = float(
                 np.mean(self.load_max) / (held / experts))
-        self.bench.log("routing", units=units, **counts, **{
+        self.bench.log("routing", units=units, **counts, **self.tower_counts(), **{
             k: v for k, v in out.items() if k != "flops_per_unit"})
+        self.bench.log("phases", **window_phases(window))
         return out
 
     # -- after the window -------------------------------------------------
     def release(self) -> None:
         """Before the tower goes: the program's own router scores and choices
-        on the first unit's captions, layer by layer (the `routing` collection
-        of its module; the timed program keeps none of it). This second pass
-        is held to the timed one by what the timed one did return: the held
-        and zero-compute assignments it counted for that unit."""
+        on the first unit's captions, expert layer by expert layer (the
+        `routing` collection of its module; the timed program keeps none of
+        it). This second pass is held to the timed one by what the timed one
+        did return: the held and zero-compute assignments it counted for that
+        unit (a tower without zero-compute experts counts none on either
+        side)."""
         import jax
 
         if self.job is None:
@@ -163,16 +203,16 @@ class Driver:
         _, kept = jax.jit(lambda p, i: tower.apply(
             {"params": p}, i, mutable=["routing"]))(job.frozen["text"],
                                                     self.first_ids)
-        layers = int(self.cfg["num_layers"])
         routing = [{name: np.asarray(kept["routing"][f"layers_{i}"]["moe"][name][0])
-                    for name in ("scores", "chosen")} for i in range(layers)]
+                    for name in ("scores", "chosen")}
+                   for i in self.stack.expert_layers(self.cfg)]
         del kept
         first = int(self.cfg["share"]["held_experts_first"])
         held = range(first, first + int(self.cfg["n_routed_experts"]))
         again = {"moe/assignments_held_total": sum(
                      int(np.isin(r["chosen"], held).sum()) for r in routing),
                  "moe/assignments_zero_total": sum(
-                     int((r["chosen"] >= lm_stack.routed_total(self.cfg)).sum())
+                     int((r["chosen"] >= self.stack.routed_total(self.cfg)).sum())
                      for r in routing)}
         self.second_pass_gap = sum(
             abs(again[name] - self.first_counts[name]) for name in again
@@ -192,10 +232,11 @@ class Driver:
                                            self.program_routing])
         return compare(program, reference, t["limits"], b.log)
 
-    def reference(self, *, ops: ref.Ops = ref.EXACT, vae_ops=sd21.EXACT,
-                  follow=None) -> dict:
+    def reference(self, *, ops=None, vae_ops=sd21.EXACT, follow=None) -> dict:
         """The plain reference on the checked rows: VAE moments and the
-        tower's states, float32 at HIGHEST, a layer's leaves alive at a time."""
+        tower's states, float32 at HIGHEST, a layer's leaves alive at a time.
+        `ops` is the reference's `Ops` (`self.ref.EXACT` where None; the
+        control hands in `self.ref.Ops(quant="fp8")`)."""
         import jax
         import jax.numpy as jnp
 
@@ -207,11 +248,11 @@ class Driver:
             std = jnp.exp(0.5 * jnp.clip(logvar, -30.0, 20.0))
             out = {"mean": np.asarray(mean), "std": np.asarray(std)}
             del vae, mean, logvar, std
-            tower = ref.forward(
-                lm_stack.reference_sizes(self.cfg), self.fed["input_ids"],
-                lambda part: lm_stack.tower_leaves(
+            tower = self.ref.forward(
+                self.stack.reference_sizes(self.cfg), self.fed["input_ids"],
+                lambda part: self.stack.tower_leaves(
                     self.shapes, self.weights_seed, part, "float32"),
-                ops=ops, follow=follow,
+                ops=self.ref.EXACT if ops is None else ops, follow=follow,
                 tie_eps=float(t["reference"]["tie_eps"]))
         out["ctx"] = np.asarray(tower["ctx"])
         out["routing"] = [{k: np.asarray(v) for k, v in layer.items()}

@@ -332,7 +332,13 @@ def run_window(bench: Bench, driver) -> tuple[Window, Any]:
         from benchmark.lib import trace as tracelib
 
         path = tracelib.find_xplane(trace_dir)
+        t0 = time.perf_counter()
         trace = tracelib.read(path) if path else None
+        if trace is not None:
+            bench.log("trace_read", seconds=round(time.perf_counter() - t0, 3),
+                      bytes=Path(path).stat().st_size, ops=sum(
+                          len(evs) for evs in trace.ops.values()),
+                      unscoped_busy_share=tracelib.unscoped_share(trace))
         shutil.rmtree(trace_dir, ignore_errors=True)
     return window, trace
 

@@ -25,6 +25,11 @@ def routed_total(config: dict) -> int:
     return int(config["share"]["router_outputs"]) - int(config["zero_expert_num"])
 
 
+def expert_layers(config: dict) -> range:
+    """The layers with an expert layer: every one of LongCat's double layers."""
+    return range(int(config["num_layers"]))
+
+
 def model_argv(config: dict, resolution: int) -> list[str]:
     """`--model.<field>=<value>` for parse_cli: sd21's UNet, VAE and schedule
     blocks as `sd_stack` reads them, and the tower under `model.longcat.*`."""

@@ -24,6 +24,12 @@ def expert_layers(config: dict) -> range:
                  int(config["num_hidden_layers"]))
 
 
+def routed_total(config: dict) -> int:
+    """The router's routed outputs: every routed expert of the deployment
+    (this tower has no zero-compute experts)."""
+    return int(config["share"]["router_outputs"])
+
+
 def model_argv(config: dict, resolution: int) -> list[str]:
     """`--model.<field>=<value>` for parse_cli: sd21's UNet, VAE and schedule
     blocks as `sd_stack` reads them, and the tower under `model.openpangu.*`
@@ -47,7 +53,7 @@ def model_argv(config: dict, resolution: int) -> list[str]:
             value = "true" if value else "false"
         out.append(f"--model.openpangu.{key}={value}")
     share = config["share"]
-    out += [f"--model.openpangu.n_routed_experts={share['router_outputs']}",
+    out += [f"--model.openpangu.n_routed_experts={routed_total(config)}",
             f"--model.openpangu.held_experts_first={share['held_experts_first']}",
             f"--model.openpangu.held_experts_count={config['n_routed_experts']}"]
     return out

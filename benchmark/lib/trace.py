@@ -11,6 +11,13 @@ Host and device events share one clock to within a millisecond or two (the
 first device event of a dispatch can read up to ~1 ms before the host span
 that dispatched it), so gaps are attributed, not measured, by host spans.
 
+Each device op also has a SCOPE PATH: the stat `tf_op` of its event's
+metadata, the op's `op_name` as the program's `jax.named_scope`s and Flax
+modules built it (`jit(encode)/.../layers_1/moe/experts/while/body/dot_general`;
+`ProfileData` does not show it, so `benchmark/lib/xplane.py` decodes it from
+the file), kept in `Trace.scopes[chip]` parallel to `Trace.ops[chip]`, "" for
+an op without one.
+
 Everything here is plain Python over `(name, start_ns, duration_ns)` tuples,
 so that the tests can feed it a hand-made timeline as well as a recorded file.
 """
@@ -25,6 +32,8 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 ANNOTATION_PREFIX = "bench/"
+#: the program's own spans (`dcr_tpu.core.tracing`), read for idle gaps
+PROGRAM_PREFIX = "dcr/"
 TRACED = "bench/traced"
 #: instructions that only contain others; they count for "busy", never as an
 #: operation of their own in the breakdown
@@ -37,7 +46,8 @@ Event = tuple[str, float, float]          # name, start_ns, duration_ns
 class Trace:
     ops: dict[int, list[Event]] = field(default_factory=dict)       # chip -> XLA Ops
     modules: dict[int, list[Event]] = field(default_factory=dict)   # chip -> XLA Modules
-    host: list[Event] = field(default_factory=list)                 # bench/* annotations
+    host: list[Event] = field(default_factory=list)     # bench/* and dcr/* annotations
+    scopes: dict[int, list[str]] = field(default_factory=dict)      # chip -> a path an op
 
     def window(self) -> tuple[float, float]:
         """The traced stretch: the `bench/traced` annotation where there is
@@ -59,7 +69,8 @@ def find_xplane(trace_dir: str | Path) -> str | None:
 
 
 def read(path: str | Path) -> Trace:
-    """Read an `.xplane.pb` with nothing but JAX."""
+    """Read an `.xplane.pb`: events by JAX's `ProfileData`, the ops' scope
+    paths by `xplane`."""
     import jax
 
     data = jax.profiler.ProfileData.from_file(str(path))
@@ -78,10 +89,38 @@ def read(path: str | Path) -> Trace:
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(ANNOTATION_PREFIX):
+                    if e.name.startswith((ANNOTATION_PREFIX, PROGRAM_PREFIX)):
                         trace.host.append((e.name, e.start_ns, e.duration_ns))
     trace.host.sort(key=lambda e: e[1])
+    trace.scopes = scope_paths(path, trace.ops)
     return trace
+
+
+def scope_paths(path: str | Path, ops: dict[int, list[Event]]) -> dict[int, list[str]]:
+    """chip -> the scope path of each of `ops[chip]`, in order: the `tf_op`
+    stat of the event's metadata without its `:<type>` tail, "" where the op
+    has none. A line whose events do not pair one for one with `ops` (by
+    name) gives every op "": no path is better than a wrong one."""
+    from benchmark.lib import xplane
+
+    out = {chip: [""] * len(evs) for chip, evs in ops.items()}
+    for plane in xplane.read(path, lambda name: DEVICE_PLANE.match(name)):
+        chip = int(DEVICE_PLANE.match(plane.name).group(1))
+        line = next((x for x in plane.lines if x.name == OPS_LINE), None)
+        if chip not in ops or line is None or len(line.events) != len(ops[chip]):
+            continue
+        metas = [plane.event_metadata.get(mid) for mid, _, _ in line.events]
+        if any(m is None or m.name != name
+               for m, (name, _, _) in zip(metas, ops[chip])):
+            continue
+        paths = {}
+        for m in metas:
+            if id(m) not in paths:
+                tf_op = plane.stat(m, "tf_op")
+                paths[id(m)] = (tf_op.rpartition(":")[0] if ":" in tf_op
+                                else tf_op) if isinstance(tf_op, str) else ""
+        out[chip] = [paths[id(m)] for m in metas]
+    return out
 
 
 # -- intervals ----------------------------------------------------------------
@@ -124,12 +163,14 @@ def idle_share(trace: Trace) -> float | None:
 # -- operations ---------------------------------------------------------------
 
 _INSTR = re.compile(r"^%?([\w.\-]+?)(?:\.\d+)? = (?:\([^=]*?\)|\S+) ([\w\-]+)\(")
+#: `/*index=5*/`, which XLA writes into a long tuple's shape
+_COMMENT = re.compile(r"/\*.*?\*/")
 
 
 def op_kind(name: str) -> tuple[str, str]:
     """('fusion', 'fusion') for '%fusion.12 = f32[..] fusion(...)': the
     instruction's name without its number, and its opcode."""
-    m = _INSTR.match(name)
+    m = _INSTR.match(_COMMENT.sub("", name))
     if not m:
         return name.split(" ")[0].lstrip("%"), ""
     return m.group(1), m.group(2)
@@ -165,16 +206,105 @@ def seconds_by(events: list[Event], pattern: str) -> tuple[float, int, list[Even
 
 def top_ops(trace: Trace, n: int = 10) -> list[list]:
     """[[label, seconds], ...] of the operations that took most device time
-    in the traced window, summed over chips, containers left out."""
+    in the traced window, summed over chips, containers left out. An op with
+    a scope path is labelled by the part of the program it ran for as well
+    (`experts:fusion:kOutput`, `scope_name`)."""
     t0, t1 = trace.window()
     total: dict[str, float] = {}
-    for evs in trace.ops.values():
-        for name, s, d in in_window(evs, t0, t1):
-            if op_kind(name)[1] in CONTAINERS:
+    labels: dict[tuple[str, str], str | None] = {}     # an op's text repeats
+    for chip, evs in trace.ops.items():
+        for (name, s, d), path in zip(evs, paths_of(trace, chip)):
+            if not (s + d > t0 and s < t1):
                 continue
-            label = op_label(name)
-            total[label] = total.get(label, 0.0) + d / 1e9
+            if (name, path) not in labels:
+                base, opcode = op_kind(name)
+                label, part = op_label(name), scope_name(path)
+                labels[name, path] = (None if opcode in CONTAINERS else
+                                      f"{part}:{label}" if part and part != base
+                                      else label)
+            label = labels[name, path]
+            if label is not None:
+                total[label] = total.get(label, 0.0) + d / 1e9
     return [[k, v] for k, v in sorted(total.items(), key=lambda kv: -kv[1])[:n]]
+
+
+# -- scopes -------------------------------------------------------------------
+
+#: components of a path that name no part of the program: what JAX's
+#: control flow puts in (a transform's own, `jit(f)`, `vmap()`,
+#: `transpose(jvp(...))`, is told by its parenthesis)
+UNNAMED = frozenset(("while", "body", "cond", "closed_call", "checkpoint",
+                     "remat"))
+
+
+def paths_of(trace: Trace, chip: int) -> list[str]:
+    """The scope path of each of `trace.ops[chip]`; "" for every op where the
+    trace holds none."""
+    paths = trace.scopes.get(chip, [])
+    return paths if len(paths) == len(trace.ops[chip]) else [""] * len(trace.ops[chip])
+
+
+def in_scope(path: str, scope: str) -> bool:
+    """Whether the components of `scope` stand in the op's path one after
+    the other, each a whole component, before the op's kind: `moe/experts` is
+    in `.../moe/experts/while/body/dot_general` and not in
+    `.../moe/experts_x/dot_general`. A path of several joined by `;` (XLA's,
+    for an op made of several) is in the scope where one of them is."""
+    want = scope.split("/")
+    n = len(want)
+    for one in path.split(";"):
+        parts = one.split("/")[:-1]
+        if any(parts[i:i + n] == want for i in range(len(parts) - n + 1)):
+            return True
+    return False
+
+
+def scope_name(path: str) -> str:
+    """The last named component of an op's path before its kind: `experts`
+    for `.../moe/experts/while/body/dot_general`, `q_a_proj` for
+    `.../mla/self_attn/q_a_proj/dot_general`; "" where none is named."""
+    for part in reversed(path.split(";")[0].split("/")[:-1]):
+        if part and "(" not in part and part not in UNNAMED \
+                and not part.startswith("branch_"):
+            return part
+    return ""
+
+
+def scoped_seconds(trace: Trace, keep) -> tuple[float, float] | None:
+    """(device seconds of the ops whose path `keep` accepts, device seconds
+    of all ops), each the union of their intervals inside the traced stretch,
+    summed over chips: a `while` and the ops of its body count once. None
+    where no op of the stretch carries a path."""
+    t0, t1 = trace.window()
+    kept = total = 0.0
+    pathed = False
+    verdict: dict[str, bool] = {}                       # a path repeats
+    for chip, evs in trace.ops.items():
+        pairs = [(e, p) for e, p in zip(evs, paths_of(trace, chip))
+                 if e[1] + e[2] > t0 and e[1] < t1]
+        pathed = pathed or any(p for _, p in pairs)
+        for _, p in pairs:
+            if p not in verdict:
+                verdict[p] = keep(p)
+        kept += busy_seconds([e for e, p in pairs if verdict[p]], t0, t1)
+        total += busy_seconds([e for e, _ in pairs], t0, t1)
+    return (kept, total) if pathed and total > 0 else None
+
+
+def scope_share(trace: Trace, scope: str) -> float | None:
+    """The device time inside `scope` as a share of all device time of the
+    traced stretch, in percent; None where no op carries a path or none is
+    in the scope."""
+    got = scoped_seconds(trace, lambda p: bool(p) and in_scope(p, scope))
+    return None if got is None or got[0] <= 0 else 100.0 * got[0] / got[1]
+
+
+def unscoped_share(trace: Trace) -> float | None:
+    """The device time that no op with a path covers, as a share of all
+    device time of the traced stretch, in percent: what no scope metric can
+    see (a `while` without a path whose body ops have one is not in it)."""
+    got = scoped_seconds(trace, bool)
+    return None if got is None else 100.0 * (1.0 - got[0] / got[1])
 
 
 # -- gaps ---------------------------------------------------------------------
@@ -194,9 +324,12 @@ def gaps(events: list[Event], t0: float, t1: float,
 
 def attribute_gaps(trace: Trace, n: int = 10) -> list[list]:
     """[[what the host was doing, idle seconds], ...]: each idle gap of the
-    first chip goes to the `bench/*` annotation that overlaps it most,
-    `bench/traced` itself aside; a gap no annotation touches is
-    'unattributed'."""
+    first chip goes to a host span that overlaps it, the benchmark's
+    `bench/*` and the program's `dcr/*` alike (`bench/traced` itself aside),
+    named without its prefix: the one that overlaps it most, and then, while
+    spans that lie inside that one overlap the gap too, the innermost of
+    them (`search/fetch`, not the `search/query` round it); a gap no span
+    touches is 'unattributed'."""
     t0, t1 = trace.window()
     chips = trace.ops or trace.modules
     if not chips:
@@ -205,12 +338,17 @@ def attribute_gaps(trace: Trace, n: int = 10) -> list[list]:
     spans = [e for e in trace.host if e[0] != TRACED]
     total: dict[str, float] = {}
     for a, b in gaps(events, t0, t1):
-        best, best_overlap = "unattributed", 0.0
-        for name, s, d in spans:
-            overlap = min(b, s + d) - max(a, s)
-            if overlap > best_overlap:
-                best, best_overlap = name[len(ANNOTATION_PREFIX):], overlap
-        total[best] = total.get(best, 0.0) + (b - a) / 1e9
+        over = [(min(b, s + d) - max(a, s), s, s + d, name)
+                for name, s, d in spans if min(b, s + d) - max(a, s) > 0]
+        best = max(over, key=lambda o: o[0], default=None)
+        while best is not None:
+            inner = [o for o in over if best[1] <= o[1] and o[2] <= best[2]
+                     and o[2] - o[1] < best[2] - best[1]]
+            if not inner:
+                break
+            best = max(inner, key=lambda o: o[0])
+        label = "unattributed" if best is None else best[3].partition("/")[2]
+        total[label] = total.get(label, 0.0) + (b - a) / 1e9
     return [[k, v] for k, v in sorted(total.items(), key=lambda kv: -kv[1])[:n]]
 
 

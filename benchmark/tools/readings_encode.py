@@ -8,8 +8,8 @@ the driver's set-up and first unit (the timed path's own program), the
 program's router scores, then the plain reference; the LOWER readings are the
 program's gaps. With `--control N` the first N seeds also put the reference
 in the program's place with fp8 operands in every product of the tower and
-of the VAE (the precision below the bfloat16 the configuration states): the
-UPPER readings.
+of the VAE (the precision below the bfloat16 the configuration states; the
+driver's own reference's `Ops`): the UPPER readings.
 
     python3 benchmark/tools/readings_encode.py --seeds 1,2,3 [--control 1] \
         [--workload longcat-flash-chat-ep32-encode-256] [--out <file>]
@@ -48,7 +48,6 @@ def main() -> int:
     out = Path(args.out or REPO / "chiprun_out" / f"readings_{cell.name}.jsonl")
     out.parent.mkdir(parents=True, exist_ok=True)
     module = harness.load_module("drivers", cell.traffic["driver"], cell.root)
-    from benchmark.reference import longcat_flash as ref
     from benchmark.reference import sd21
 
     for n, seed in enumerate(seeds):
@@ -63,7 +62,7 @@ def main() -> int:
             line = {"seed": seed,
                     "program": numbers(driver.verify(bench.window))}
             if n < args.control:
-                control = driver.reference(ops=ref.Ops(quant="fp8"),
+                control = driver.reference(ops=driver.ref.Ops(quant="fp8"),
                                            vae_ops=sd21.Ops(quant="fp8"))
                 control["dropped"] = 0
                 exact = driver.reference(

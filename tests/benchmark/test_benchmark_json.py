@@ -1,6 +1,7 @@
 """BENCHMARK.json against the harness and the contract's letter: every cell,
 configuration, driver and metric it names resolves to a file; names and units
 use only the allowed characters; and a later PR's cell is files and entries."""
+import copy
 import json
 import re
 import shutil
@@ -91,6 +92,40 @@ def test_every_name_resolves_to_a_file():
         assert set(m.get("workloads", [])) <= cells
         if "moves" in m:
             assert m["moves"] in e2e_names
+
+
+def test_the_order_checks_stand_when_entries_are_appended():
+    """The three checks of where earlier PRs' entries stand ask nothing of
+    what follows them: on a copy with one more configuration, cell and
+    per-layer entry appended they pass, and a cell put before its neighbour
+    fails them."""
+    from tests.benchmark.test_driver_encode import check_pr_28_follows_the_ten
+    from tests.benchmark.test_driver_encode_openpangu import check_the_cell
+    from tests.benchmark.test_program_spans import check_the_ten
+
+    checks = (check_the_ten, check_pr_28_follows_the_ten, check_the_cell)
+    bench = copy.deepcopy(BENCH)
+    bench["configs"].append({"name": "y", "source": "test", "reduced": [],
+                             "file": "benchmark/configs/y.json", "why": "test"})
+    bench["workloads"].append({"name": "y.x", "config": "y", "traffic": "x",
+                               "chips": 1, "why": "test"})
+    encode = bench["per_layer"][-1]["workloads"]
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m.get("workloads") == encode or m["name"] == "train_images_per_s":
+            m["workloads"] = [*m["workloads"], "y.x"]
+    bench["per_layer"].append({"name": "z.part", "unit": "%", "better": "lower",
+                               "source": "device_trace", "layer": "expert layer",
+                               "moves": "train_images_per_s",
+                               "workloads": [*encode, "y.x"]})
+    for check in checks:
+        check(bench)
+    swapped = copy.deepcopy(bench)
+    swapped["workloads"][-3:-1] = swapped["workloads"][-2:-4:-1]
+    with pytest.raises(AssertionError):
+        check_the_cell(swapped)
+    with pytest.raises(AssertionError):
+        check_pr_28_follows_the_ten({**bench, "per_layer": [
+            m for m in bench["per_layer"] if m["name"] != "encode_step_mfu"]})
 
 
 def test_peaks_name_their_source_and_refuse_an_unknown_chip():

@@ -2,6 +2,7 @@
 devices, per-device batch 1), `correct` coming out false for a fault planted
 in the timed path, and the control in fp8 failing the cell's limits."""
 import json
+import shutil
 
 import jax
 import numpy as np
@@ -76,6 +77,56 @@ def test_runs_end_to_end_and_follows_the_reference(tiny_encode, capsys):
     assert routing["moe/assignments_dropped_total"] == 0
     assert routing["held_load_max_over_mean"] >= 1.0
     assert not (tiny_encode / "benchmark" / ".work" / CELL).exists()
+
+
+def drive(cell_name: str, seed: int):
+    """A driver's set-up, two more units and its counters, called as
+    `harness.run` calls them. -> (the driver, its counters, its log lines)"""
+    cell = harness.load_cell(cell_name)
+    lines = []
+    bench = harness.Bench(cell, seed, 0.0, False, jax.devices()[:cell.chips],
+                          harness.CompileMeter())
+    bench.log = lambda what, **fields: lines.append({"bench": what, **fields})
+    shutil.rmtree(bench.work, ignore_errors=True)
+    bench.work.mkdir(parents=True)
+    driver = harness.load_module("drivers", cell.traffic["driver"]).Driver(bench)
+    try:
+        driver.setup()
+        for _ in range(2):
+            driver.unit()
+        driver.drain()
+        counters = driver.counters(bench.window)
+    finally:
+        driver.close()
+        shutil.rmtree(bench.work, ignore_errors=True)
+    return driver, counters, {x["bench"]: x for x in lines}
+
+
+def test_the_flops_and_counters_of_a_unit_are_longcats(tiny_encode):
+    """The driver names its tower's modules; the FLOPs of a unit and the
+    counters are what `lm_flops` and the program's counters give, over every
+    double layer's held experts, as before the tower became an attribute."""
+    from benchmark.drivers.encode_leg import MOE_COUNTERS
+    from benchmark.lib import lm_flops, lm_stack
+    from benchmark.reference import longcat_flash
+
+    driver, got, lines = drive(CELL, 29)
+    assert (driver.stack, driver.flops, driver.ref) == (lm_stack, lm_flops, longcat_flash)
+    cfg, routing = driver.cfg, lines["routing"]
+    assert routing["units"] == 3
+    held = routing["moe/assignments_held_total"] / 3
+    assert got == {
+        "flops_per_unit": lm_flops.encode_unit_flops(
+            cfg, 16, driver.batch, cfg["text_max_length"], held),
+        "held_assignments_per_unit": held,
+        "dropped_assignments": 0,
+        "held_load_max_over_mean": float(np.mean(driver.load_max) / (
+            held / (cfg["n_routed_experts"] * cfg["num_layers"])))}
+    assert set(lines["first_unit"]) == {
+        "bench", "real_tokens", *MOE_COUNTERS, "compile_seconds", "compilations",
+        "cache_hits", "cache_misses"}
+    assert set(routing) == {"bench", "units", *MOE_COUNTERS, *got} - {"flops_per_unit"}
+    assert {"load_s", "encode_s", "fetch_s", "write_s"} <= set(lines["phases"])
 
 
 def test_an_expert_layer_that_adds_half_of_its_part_is_not_correct(tiny_encode, capsys, monkeypatch):
@@ -159,15 +210,17 @@ def test_the_control_in_fp8_fails_the_limits(tiny_encode):
     assert not harness.checks_pass(control), control
 
 
-def test_the_span_metrics_of_pr_26_stand_as_they_were():
-    """`test_program_spans.py::test_the_new_entries_are_the_ten_of_the_table`
-    also asks that PR 26's ten END `per_layer`. Entries are appended, so from
-    the first PR that adds a metric that test stops at that line (and no PR
-    but a `benchmark` one may edit it); what it goes on to ask of the ten is
-    asked here, with their order and their standing together."""
+#: what PR 28 appended to `per_layer`, in its order
+PR_28 = ["encode_step_mfu", "device_idle_share.encode", "encode_phase_share.load",
+         "encode_phase_share.encode", "encode_phase_share.fetch",
+         "encode_phase_share.write", "moe_held_load_max_over_mean"]
+
+
+def check_pr_28_follows_the_ten(bench: dict) -> None:
+    """PR 26's ten stand together and in order, and PR 28's seven follow
+    them directly, in order; what later PRs append after those is theirs."""
     from tests.benchmark.test_program_spans import NEW, WANT
 
-    bench = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index(NEW[0])
     assert names[at:at + len(NEW)] == NEW and set(WANT) == set(NEW)
@@ -176,11 +229,14 @@ def test_the_span_metrics_of_pr_26_stand_as_they_were():
         assert m["source"] == "program_span" and m["better"] == "lower"
         assert m["workloads"] and set(m["workloads"]) <= set(
             e2e[m["moves"]]["workloads"])
-    # what this PR adds comes after them, and only there
-    assert names[at + len(NEW):] == [
-        "encode_step_mfu", "device_idle_share.encode", "encode_phase_share.load",
-        "encode_phase_share.encode", "encode_phase_share.fetch",
-        "encode_phase_share.write", "moe_held_load_max_over_mean"]
+    after = at + len(NEW)
+    assert names[after:after + len(PR_28)] == PR_28
+
+
+def test_the_span_metrics_of_pr_26_stand_as_they_were():
+    """`test_program_spans.py::check_the_ten` asks that PR 26's ten stand in
+    order; here they stand together, and PR 28's seven directly after them."""
+    check_pr_28_follows_the_ten(json.loads((harness.ROOT / "BENCHMARK.json").read_text()))
 
 
 def test_the_configuration_keeps_the_published_widths():

@@ -86,6 +86,39 @@ def test_runs_end_to_end_and_follows_the_reference(tiny_encode, capsys):
     assert not (tiny_encode / "benchmark" / ".work" / CELL).exists()
 
 
+def test_the_flops_and_counters_of_a_unit_are_the_towers(tiny_encode):
+    """The driver names this tower's modules and runs `encode_leg`'s set-up
+    and counters: the FLOPs of a unit are `pangu_flops`', the mean load is
+    over the expert layers' held experts only, and the log's lines carry the
+    counters PR 32 added and the window's phases, as before."""
+    from benchmark.drivers.encode_leg import MOE_COUNTERS
+    from benchmark.lib import pangu_flops, pangu_stack
+    from benchmark.reference import openpangu_ultra_moe
+    from tests.benchmark.test_driver_encode import drive
+
+    driver, got, lines = drive(CELL, 31)
+    assert (driver.stack, driver.flops, driver.ref) == (
+        pangu_stack, pangu_flops, openpangu_ultra_moe)
+    assert not {"setup", "counters", "release", "reference"} & set(vars(type(driver)))
+    cfg, routing = driver.cfg, lines["routing"]
+    assert routing["units"] == 3
+    held = routing["moe/assignments_held_total"] / 3
+    assert got == {
+        "flops_per_unit": pangu_flops.encode_unit_flops(
+            cfg, 16, driver.batch, cfg["text_max_length"], held),
+        "held_assignments_per_unit": held,
+        "dropped_assignments": 0,
+        "held_load_max_over_mean": float(np.mean(driver.load_max) / (
+            held / (cfg["n_routed_experts"] * 2)))}      # two expert layers
+    new = {"moe/tokens_unheld_total", "moe/layers", "tower/layers"}
+    assert set(lines["first_unit"]) == {
+        "bench", "real_tokens", *MOE_COUNTERS, *new, "compile_seconds",
+        "compilations", "cache_hits", "cache_misses"}
+    assert set(routing) == {"bench", "units", *MOE_COUNTERS, *new, *got} - {
+        "flops_per_unit"}
+    assert {"load_s", "encode_s", "fetch_s", "write_s"} <= set(lines["phases"])
+
+
 def test_a_post_norm_skipped_is_not_correct(tiny_encode, capsys, monkeypatch):
     """The fault planted in the timed path: the norm on the FFN's output is
     computed and thrown away, so the sublayer joins the residual unnormed."""
@@ -183,27 +216,41 @@ def test_the_control_in_fp8_fails_the_limits(tiny_encode):
     assert not harness.checks_pass(control), control
 
 
+LONGCAT = "longcat-flash-chat-ep32-encode-256"
+#: the metrics the cell reported when PR 32 brought it, in their order
+REPORTED = ["train_images_per_s", "encode_step_mfu", "device_idle_share.encode",
+            "encode_phase_share.load", "encode_phase_share.encode",
+            "encode_phase_share.fetch", "encode_phase_share.write",
+            "moe_held_load_max_over_mean"]
+
+
+def check_the_cell(bench: dict) -> None:
+    """The configuration and the cell, found by name, each directly after
+    LongCat's, and the cell directly after LongCat's in every `workloads`
+    list that names both; the eight metrics PR 32 listed it under come first
+    among those that list it now, in their order."""
+    configs = [c["name"] for c in bench["configs"]]
+    cells = [w["name"] for w in bench["workloads"]]
+    at = configs.index("openpangu-ultra-moe-718b-ep16")
+    assert configs[at - 1] == "longcat-flash-chat-ep32"
+    assert cells[cells.index(CELL) - 1] == LONGCAT
+    assert bench["workloads"][cells.index(CELL)]["chips"] == 1
+    metrics = bench["end_to_end"] + bench["per_layer"]
+    listed = [m["name"] for m in metrics if CELL in m.get("workloads", [])]
+    assert listed[:len(REPORTED)] == REPORTED
+    for m in metrics:
+        names = m.get("workloads", [])
+        if CELL in names and LONGCAT in names:
+            assert names.index(CELL) == names.index(LONGCAT) + 1, m["name"]
+
+
 def test_the_cell_is_entries_appended_and_new_files_only():
     """BENCHMARK.json gained one configuration, one cell and the cell's name
-    at the end of eight `workloads` lists; no `per_layer` entry."""
-    bench = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
-    assert bench["configs"][-1]["name"] == "openpangu-ultra-moe-718b-ep16"
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["workloads"][-1]["chips"] == 1
-    listed = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]
-              if CELL in m.get("workloads", [])]
-    assert listed == [
-        "train_images_per_s", "encode_step_mfu", "device_idle_share.encode",
-        "encode_phase_share.load", "encode_phase_share.encode",
-        "encode_phase_share.fetch", "encode_phase_share.write",
-        "moe_held_load_max_over_mean"]
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == CELL
-            assert m["workloads"][-2] == "longcat-flash-chat-ep32-encode-256"
+    in eight `workloads` lists; its traffic is LongCat's job to the letter."""
+    check_the_cell(json.loads((harness.ROOT / "BENCHMARK.json").read_text()))
     cell = harness.load_cell(CELL)
     assert cell.traffic["driver"] == "encode_leg_openpangu"
-    theirs = harness.load_cell("longcat-flash-chat-ep32-encode-256").traffic
+    theirs = harness.load_cell(LONGCAT).traffic
     for key in ("train_config", "overrides", "images", "image_px",
                 "caption_tokens", "check_rows", "traced_units"):
         assert cell.traffic[key] == theirs[key], key
