@@ -24,6 +24,7 @@ from __future__ import annotations
 import json
 import logging
 import time
+from collections.abc import Sequence
 from concurrent.futures import ThreadPoolExecutor
 
 from dcr_tpu.core import tracing
@@ -40,15 +41,21 @@ class PrecomputeJob:
     (the benchmark) drives the same per-batch body itself.
 
     The body keeps the device ahead of the host: a caller that names the
-    batch it will ask for next (`then`) has it decoded and handed to the
+    batches it will ask for next (`then`) has them decoded and handed to the
     device BEFORE the current batch is waited for, and the current batch's
     rows are put on the disk by one writer thread while the device encodes
     the next. Decoded serially behind the device, the rate followed the
-    host's fsync from run to run (PERF.md, PR 28).
+    host's fsync from run to run (PERF.md, PR 28); with one batch ahead, a
+    load or a write that stalls longer than the host's slack in a unit still
+    stops the device, so the CLI names `AHEAD` batches (PERF.md, PR 35).
 
     `pretrained_params` (``{"vae": ..., "text": ...}``, any subset) replaces
     the seeded initialisers' trees, as `Trainer(pretrained_params=...)` does.
     """
+
+    #: batches the CLI's loop names as next: on the device behind the one
+    #: waited for
+    AHEAD = 2
 
     def __init__(self, cfg: TrainConfig, *,
                  pretrained_params: dict | None = None):
@@ -93,7 +100,7 @@ class PrecomputeJob:
         self.batch_size = cfg.train_batch_size * jax.local_device_count()
         self.key = rngmod.stream_key(root, "train")
         self.done = 0
-        self._ahead = None      # (number, indices, result not waited for) of `then`
+        self._ahead = {}        # number -> (indices, result not waited for), `then`'s order
         self._writer = ThreadPoolExecutor(1, thread_name_prefix="precompute-write")
         self._writing = None    # the one shard's rows the writer may hold
 
@@ -138,22 +145,23 @@ class PrecomputeJob:
         with tracing.span("precompute/write", batch=number):
             self.writer.add(out["index"], out["mean"], out["std"], out["ctx"])
 
-    def encode_batch(self, number: int, then: int | None = None) -> dict:
+    def encode_batch(self, number: int,
+                     then: int | Sequence[int] | None = None) -> dict:
         """The per-batch body: load, encode, fetch, write. `then` is the
-        batch the caller will ask for next (None: none). Returns what was
-        fetched (`mean`, `std`, `ctx` of the valid rows, and `index`); the
-        rows are on the disk after the next call or :meth:`drain`."""
+        batch the caller will ask for next, or the batches it will ask for
+        next in that order (None: none); a batch named before is not handed
+        over again. Returns what was fetched (`mean`, `std`, `ctx` of the
+        valid rows, and `index`); the rows are on the disk after the next
+        call or :meth:`drain`."""
         import jax
         import numpy as np
 
-        ahead, self._ahead = self._ahead, None
-        if ahead is not None and ahead[0] == number:
-            index, enc = ahead[1:]
-        else:
-            index, enc = self._start(number)
-        if then is not None:
+        ahead, self._ahead = self._ahead, {}
+        index, enc = ahead.pop(number) if number in ahead else self._start(number)
+        for later in ((then,) if isinstance(then, int) else then or ()):
             # queued behind this batch: the device goes on without the host
-            self._ahead = (then, *self._start(then))
+            self._ahead[later] = (ahead.pop(later) if later in ahead
+                                  else self._start(later))
         with tracing.span("precompute/encode", batch=number):
             # the wait alone: the fetch below then times the copy alone
             enc = jax.block_until_ready(enc)
@@ -175,18 +183,17 @@ class PrecomputeJob:
             writing.result()        # a failed write is raised here
 
     def drain(self) -> None:
-        """Wait for what is in flight: the batch handed to the device ahead
-        of its call, and the writer."""
+        """Wait for what is in flight: the batches handed to the device
+        ahead of their calls, and the writer."""
         import jax
 
-        if self._ahead is not None:
-            jax.block_until_ready(self._ahead[2])
+        jax.block_until_ready([enc for _, enc in self._ahead.values()])
         self._wait_for_writer()
 
     def close(self) -> None:
         """Wait for what is in flight and stop the writer thread."""
         self.drain()
-        self._ahead = None
+        self._ahead = {}
         self._writer.shutdown()
 
     def finalize(self):
@@ -230,7 +237,8 @@ def precompute(cfg: TrainConfig) -> dict:
     n = len(job.dataset)
     batches = len(job)
     for number in range(batches):
-        job.encode_batch(number, number + 1 if number + 1 < batches else None)
+        job.encode_batch(number, range(number + 1,
+                                       min(number + 1 + job.AHEAD, batches)))
         if number % 20 == 0:
             log.info("precompute: %d/%d indices encoded", job.done, n)
     manifest = job.finalize()

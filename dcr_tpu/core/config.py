@@ -120,11 +120,20 @@ class LongcatFlashConfig:
         has one."""
         return self.num_layers, self.num_layers
 
+    def validate(self, name: str) -> None:
+        _even_rope(self, name)
+
 
 def _held_range(block) -> tuple[int, int]:
     count = (block.n_routed_experts - block.held_experts_first
              if block.held_experts_count < 0 else block.held_experts_count)
     return block.held_experts_first, count
+
+
+def _even_rope(block, name: str) -> None:
+    if block.qk_rope_head_dim % 2:
+        raise ValueError(
+            f"model.{name}.qk_rope_head_dim must be even (rotary pairs)")
 
 
 @dataclass
@@ -185,6 +194,75 @@ class OpenPanguUltraMoEConfig:
         return self.num_hidden_layers, max(
             0, self.num_hidden_layers - self.first_k_dense_replace)
 
+    def validate(self, name: str) -> None:
+        _even_rope(self, name)
+
+
+@dataclass
+class SolarOpen2Config:
+    """Sizes of the Solar Open 2 text tower (models/solar_open2.py), under the
+    keys of the published config.json (upstage/Solar-Open2-250B), the keys of
+    its `linear_attn_config` under a `linear_attn_` prefix; the defaults are
+    the published values. Only keys the tower reads are here: the published
+    file's others (`use_rope` false, `kda_use_full_proj` false,
+    `first_k_dense_replace` 0, `linear_attn_config`'s `num_kv_heads` null,
+    `intermediate_size`, which no layer reads) describe what the tower does
+    anyway. The vocabulary (or the slice of it
+    held here) and the sequence length are ModelConfig.text_vocab_size and
+    text_max_length, as for every tower."""
+
+    hidden_size: int = 4096
+    num_hidden_layers: int = 48
+    # the layers whose mixer is gated GQA softmax attention without position
+    # encoding; every other layer's is the gated delta rule (KDA)
+    gqa_layers: tuple[int, ...] = tuple(range(0, 48, 4))
+    num_attention_heads: int = 64         # GQA: query heads
+    num_key_value_heads: int = 8          # GQA: key/value heads
+    head_dim: int = 128
+    use_gqa_gate: bool = True             # GQA: an elementwise sigmoid gate on its output
+    linear_attn_num_heads: int = 64       # KDA: heads
+    linear_attn_head_dim: int = 128       # KDA: a head's key and value width
+    linear_attn_short_conv_kernel_size: int = 4
+    kda_allow_neg_eigval: bool = True     # KDA: beta in (0, 2), not (0, 1)
+    moe_intermediate_size: int = 1280     # a routed or shared expert's SwiGLU
+    n_routed_experts: int = 320           # all of them: the router's outputs
+    n_shared_experts: int = 1             # every token passes them
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True           # the chosen weights sum to one, then scale
+    routed_scaling_factor: float = 1.0
+    rms_norm_eps: float = 1e-5
+    # The routed experts THIS device holds: as LongcatFlashConfig's.
+    held_experts_first: int = 0
+    held_experts_count: int = -1
+
+    @staticmethod
+    def tiny() -> "SolarOpen2Config":
+        """CPU-test size: one whole period (a GQA layer, then three KDA
+        layers) of 8 routed experts and a shared one, top 3, nothing wide."""
+        return SolarOpen2Config(
+            hidden_size=64, num_hidden_layers=4, gqa_layers=(0,),
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            linear_attn_num_heads=4, linear_attn_head_dim=16,
+            moe_intermediate_size=32, n_routed_experts=8, num_experts_per_tok=3)
+
+    def held_range(self) -> tuple[int, int]:
+        return _held_range(self)
+
+    def router_outputs(self) -> int:
+        return self.n_routed_experts
+
+    def experts_per_token(self) -> int:
+        return self.num_experts_per_tok
+
+    def layer_counts(self) -> tuple[int, int]:
+        """(layers, those of them with an expert layer): every one."""
+        return self.num_hidden_layers, self.num_hidden_layers
+
+    def validate(self, name: str) -> None:
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(f"model.{name}.num_key_value_heads must divide "
+                             "num_attention_heads")
+
 
 @dataclass(frozen=True)
 class TextTower:
@@ -212,6 +290,9 @@ TEXT_TOWERS = {
     "openpangu_ultra_moe": TextTower(
         "dcr_tpu.models.openpangu_ultra_moe:OpenPanguUltraMoETextTower",
         "openpangu", "bfloat16", False),
+    "solar_open2": TextTower(
+        "dcr_tpu.models.solar_open2:SolarOpen2TextTower", "solar",
+        "bfloat16", False),
 }
 
 
@@ -262,13 +343,15 @@ class ModelConfig:
     # text_hidden_size / text_layers / text_heads / text_act fields below are
     # its sizes) or a frozen language model whose sizes are a block of its
     # own and whose output is projected to cross_attention_dim:
-    # "longcat_flash" (models/longcat_flash.py, `longcat`) or
-    # "openpangu_ultra_moe" (models/openpangu_ultra_moe.py, `openpangu`).
+    # "longcat_flash" (models/longcat_flash.py, `longcat`),
+    # "openpangu_ultra_moe" (models/openpangu_ultra_moe.py, `openpangu`) or
+    # "solar_open2" (models/solar_open2.py, `solar`).
     # text_vocab_size and text_max_length belong to whichever tower is chosen.
     text_tower: str = "clip"
     longcat: LongcatFlashConfig = field(default_factory=LongcatFlashConfig)
     openpangu: OpenPanguUltraMoEConfig = field(
         default_factory=OpenPanguUltraMoEConfig)
+    solar: SolarOpen2Config = field(default_factory=SolarOpen2Config)
     # CLIP text encoder (OpenCLIP ViT-H text tower for SD-2.1)
     text_vocab_size: int = 49408
     text_hidden_size: int = 1024
@@ -1169,9 +1252,7 @@ def validate_text_tower(m: ModelConfig) -> None:
     if block.experts_per_token() > block.router_outputs():
         raise ValueError(f"model.{name} chooses more experts a token than "
                          "the router has outputs")
-    if block.qk_rope_head_dim % 2:
-        raise ValueError(
-            f"model.{name}.qk_rope_head_dim must be even (rotary pairs)")
+    block.validate(name)
 
 
 def validate_train_config(cfg: TrainConfig) -> None:

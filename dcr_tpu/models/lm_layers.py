@@ -1,7 +1,8 @@
 """What the language-model text towers share (models/longcat_flash.py,
-models/openpangu_ultra_moe.py): RMSNorm, rotary, multi-head latent attention,
-SwiGLU, and the expert layer of a chip that holds a share of a layer's routed
-experts.
+models/openpangu_ultra_moe.py, models/solar_open2.py): RMSNorm, rotary,
+multi-head latent attention, SwiGLU, and the expert layer of a chip that holds
+a share of a layer's routed experts, with the one router rule and module of
+the towers that route by sigmoid and add a shared expert.
 
 The expert layer is ONE function, :func:`expert_layer`: the tower hands in how
 its router turns logits into chosen experts and weights; the layer is told
@@ -25,6 +26,9 @@ import jax
 import jax.numpy as jnp
 
 from dcr_tpu.ops.attention import dot_product_attention
+
+#: keeps the renormalisation of the chosen weights off a division by nought
+NORM_TOPK_EPS = 1e-20
 
 #: rows of one expert computed at a time. An expert's tokens are walked in
 #: blocks of this many with a trip count read from the routing, so no
@@ -275,3 +279,40 @@ def expert_layer(module: nn.Module, n: jax.Array, *, router_outputs: int,
     if count_unheld:
         stats["unheld"] = jnp.sum(~jnp.any(here, axis=1), dtype=jnp.int32)
     return out, stats
+
+
+def sigmoid_route(logits: jax.Array, k: int, renormalise: bool,
+                  scale: float) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The router rule of the towers with a shared expert: scores by a
+    sigmoid over every output, the top k chosen, their weights renormalised
+    to sum to one where `renormalise` and multiplied by `scale`. -> (scores,
+    chosen, weights), as `expert_layer`'s `route` returns them."""
+    s = jax.nn.sigmoid(logits)
+    top, idx = jax.lax.top_k(s, k)
+    if renormalise:
+        top = top / (jnp.sum(top, axis=1, keepdims=True) + NORM_TOPK_EPS)
+    return s, idx, top * scale
+
+
+class SharedExpertMoE(nn.Module):
+    """The expert layer of one layer routed by `sigmoid_route`, for the
+    routed experts held here, and the shared expert. `cfg` is a tower's block
+    with `n_routed_experts`, `num_experts_per_tok`, `norm_topk_prob`,
+    `routed_scaling_factor`, `moe_intermediate_size`, `n_shared_experts` and
+    `held_range()` (openPangu's and Solar Open 2's)."""
+
+    cfg: object
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, n: jax.Array) -> tuple[jax.Array, dict]:
+        c = self.cfg
+        return expert_layer(
+            self, n, router_outputs=c.n_routed_experts,
+            route=lambda logits: sigmoid_route(
+                logits, c.num_experts_per_tok, c.norm_topk_prob,
+                c.routed_scaling_factor),
+            held=c.held_range(), expert_width=c.moe_intermediate_size,
+            shared_width=c.moe_intermediate_size * c.n_shared_experts,
+            count_unheld=True)

@@ -17,8 +17,10 @@ token passes is added to the routed part. The norm, the rotary, MLA, SwiGLU
 and the expert layer itself (told which contiguous range of the routed experts
 THIS device holds, `OpenPanguUltraMoEConfig.held_range`; it drops no
 assignment) are models/lm_layers.py's, shared with the other language-model
-tower. The language-model head and the multi-token-prediction layer predict
-tokens FROM the final states and have no consumer here: they are left out.
+towers; the router rule and the shared expert's module
+(`lm_layers.SharedExpertMoE`) with Solar Open 2's. The language-model head
+and the multi-token-prediction layer predict tokens FROM the final states and
+have no consumer here: they are left out.
 
 Parameters are held in bfloat16 (core/config.TEXT_TOWERS); the router, the
 norms and the softmaxes compute in float32.
@@ -33,37 +35,8 @@ import jax
 import jax.numpy as jnp
 
 from dcr_tpu.core.config import ModelConfig, OpenPanguUltraMoEConfig
-from dcr_tpu.models.lm_layers import (MLA, RMSNorm, SwiGLU, TextTowerOutput,
-                                      causal_mask, expert_layer, merge_stats)
-
-#: keeps the renormalisation of the chosen weights off a division by nought
-NORM_TOPK_EPS = 1e-20
-
-
-class SharedExpertMoE(nn.Module):
-    """The expert layer of one layer, for the routed experts held here, and
-    the shared expert."""
-
-    cfg: OpenPanguUltraMoEConfig
-    dtype: jnp.dtype = jnp.float32
-    param_dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, n: jax.Array) -> tuple[jax.Array, dict]:
-        c = self.cfg
-
-        def route(logits):
-            s = jax.nn.sigmoid(logits)
-            top, idx = jax.lax.top_k(s, c.num_experts_per_tok)
-            if c.norm_topk_prob:
-                top = top / (jnp.sum(top, axis=1, keepdims=True) + NORM_TOPK_EPS)
-            return s, idx, top * c.routed_scaling_factor
-
-        return expert_layer(
-            self, n, router_outputs=c.n_routed_experts, route=route,
-            held=c.held_range(), expert_width=c.moe_intermediate_size,
-            shared_width=c.moe_intermediate_size * c.n_shared_experts,
-            count_unheld=True)
+from dcr_tpu.models.lm_layers import (MLA, RMSNorm, SharedExpertMoE, SwiGLU,
+                                      TextTowerOutput, causal_mask, merge_stats)
 
 
 class SandwichLayer(nn.Module):

@@ -51,6 +51,8 @@ def path_for(q, k, v, *, mask=None, use_flash: bool = True, mesh=None) -> str:
         return "xla"
     if v.shape[-1] != q.shape[-1]:
         return "xla"        # latent attention: v narrower than q/k
+    if k.shape[2] != q.shape[2]:
+        return "xla"        # grouped key/value heads
     from dcr_tpu.ops import flash_attention as fa
 
     rows, heads, _ = _shards(mesh)
@@ -68,8 +70,10 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           mesh: Optional[Mesh] = None) -> jax.Array:
     """Multi-head attention over [B, S, H, D] tensors (BSHD layout).
 
-    q: [B, Sq, H, D]; k: [B, Sk, H, D]; v: [B, Sk, H, Dv] with Dv <= D (latent
-    attention's values are narrower than its keys). Returns [B, Sq, H, Dv].
+    q: [B, Sq, H, D]; k: [B, Sk, Hkv, D]; v: [B, Sk, Hkv, Dv] with Dv <= D
+    (latent attention's values are narrower than its keys) and Hkv dividing H
+    (grouped-query attention: query head h reads key/value head
+    h // (H / Hkv); on the XLA path only). Returns [B, Sq, H, Dv].
     Dispatches to the Pallas TPU flash kernel where it beats XLA on the chip
     (flash_attention.should_use) and we're on TPU, otherwise XLA (which fuses
     the softmax chain on its own). The floor that decides it,
@@ -103,19 +107,24 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
-def _group_of(b: int, h: int, sq: int, sk: int, floor: int) -> tuple[int, int]:
-    """(rows, heads) of one group of a site of b rows and h heads: the whole
-    site where its float32 logits are at or under `floor` bytes, else the
-    largest divisor of the rows whose group is, else (one row is over the
-    floor) one row and the largest such divisor of its heads."""
-    def largest_divisor(n, bytes_each):
-        return max(g for g in range(1, n + 1)
-                   if n % g == 0 and (g == 1 or g * bytes_each <= floor))
+def _group_of(b: int, h: int, sq: int, sk: int, floor: int,
+              kv_heads: int | None = None) -> tuple[int, int]:
+    """(rows, heads) of one group of a site of b rows and h query heads: the
+    whole site where its float32 logits are at or under `floor` bytes, else
+    the largest divisor of the rows whose group is, else (one row is over the
+    floor) one row and the largest such divisor of its heads. With
+    `kv_heads` key/value heads a group of heads is a whole number of the
+    query heads that share one (the groups cut k and v in step)."""
+    kv_heads = h if kv_heads is None else kv_heads
+
+    def largest_divisor(n, bytes_each, least=1):
+        return max(g for g in range(least, n + 1, least)
+                   if n % g == 0 and (g == least or g * bytes_each <= floor))
 
     head = 4 * sq * sk
     if h * head <= floor:
         return largest_divisor(b, h * head), h
-    return 1, largest_divisor(h, head)
+    return 1, largest_divisor(h, head, h // kv_heads)
 
 
 def _one_after_the_other(fn, n: int, ways: int, operands, axes):
@@ -173,16 +182,19 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if floor is None:
         from dcr_tpu.ops.flash_attention import FLASH_MIN_LOGITS_BYTES as floor
     b, sq, h, _ = q.shape
-    dv = v.shape[-1]
-    ways_b, ways_h = shards if b % shards[0] == 0 and h % shards[1] == 0 else (1, 1)
-    g_b, g_h = _group_of(b // ways_b, h // ways_h, sq, k.shape[1], floor)
+    h_kv, dv = k.shape[2], v.shape[-1]
+    ways_b, ways_h = shards if (b % shards[0] == 0 and h % shards[1] == 0
+                                and h_kv % shards[1] == 0) else (1, 1)
+    g_b, g_h = _group_of(b // ways_b, h // ways_h, sq, k.shape[1], floor,
+                         h_kv // ways_h)
     n_b, n_h = b // (ways_b * g_b), h // (ways_h * g_h)
     if n_b * n_h > 1:
         tracing.registry().counter("attention/xla_row_groups_total").inc(n_b * n_h)
 
     def attend(q, k, v, mask):
         # jax.nn.dot_product_attention takes the same BSHD layout and scaling
-        # and lets XLA pick its fused implementation
+        # (and k, v with fewer heads than q) and lets XLA pick its fused
+        # implementation
         if dv < q.shape[-1]:
             # jax.nn's call wants v as wide as q/k: zero columns of v give
             # zero columns of the output, which are cut off again (exact)
@@ -190,6 +202,7 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return jax.nn.dot_product_attention(q, k, v, mask=mask)[..., :dv]
 
     def by_heads(q, k, v, mask):
+        # k and v are cut by their own heads, in step with q's groups
         cut_mask = mask is not None and mask.shape[1] == h
         return _one_after_the_other(attend, n_h, ways_h, (q, k, v, mask),
                                     (2, 2, 2, 1 if cut_mask else None))

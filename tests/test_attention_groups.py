@@ -238,3 +238,31 @@ def test_the_sweeps_site_form_takes_a_v_width_and_a_causal_mask(text, site, kern
     tags = {sweep_flash.tag_of(s, p) for s in (got, plain)
             for p in ("xla", "xla_grouped", (None, None))}
     assert len(tags) == (3 if got == plain else 6)
+
+
+@pytest.mark.parametrize("rows_under_floor, group", [
+    (math.inf, (4, 8)), (2, (2, 8)), (0.5, (1, 4)),
+    # never fewer heads than share one key/value head
+    (0.25, (1, 4))])
+def test_grouped_key_value_heads_whole_by_rows_and_by_heads(rows_under_floor, group):
+    """8 query heads on 2 key/value heads, query head h reading k/v head
+    h // 4: the site whole, in row groups and in head groups equals
+    attention over k and v repeated to every query head."""
+    b, s, h, kv, d = 4, 16, 8, 2, 8
+    floor = rows_under_floor * logits_bytes(1, h, s)
+    assert A._group_of(b, h, s, s, floor, kv) == group
+    keys = jax.random.split(jax.random.key(3), 3)
+    q = jax.random.normal(keys[0], (b, s, h, d))
+    k, v = (jax.random.normal(key, (b, s, kv, d)) for key in keys[1:])
+    got = jax.jit(lambda q, k, v: A._xla_attention(q, k, v, causal(s), floor=floor))(q, k, v)
+    want = A._xla_attention(q, jnp.repeat(k, h // kv, axis=2),
+                            jnp.repeat(v, h // kv, axis=2), causal(s), floor=math.inf)
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+
+
+def test_grouped_key_value_heads_take_the_xla_path(monkeypatch):
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    q = jax.ShapeDtypeStruct((8, 1024, 8, 64), jnp.bfloat16)
+    assert A.path_for(q, q, q) == "flash"               # 256 MiB of logits
+    kv = jax.ShapeDtypeStruct((8, 1024, 2, 64), jnp.bfloat16)
+    assert A.path_for(q, kv, kv) == "xla"

@@ -15,8 +15,8 @@ import pytest
 
 from benchmark.reference import openpangu_ultra_moe as ref
 from dcr_tpu.core.config import (TEXT_TOWERS, LongcatFlashConfig, ModelConfig,
-                                 OpenPanguUltraMoEConfig, TrainConfig,
-                                 parse_cli, validate_train_config)
+                                 OpenPanguUltraMoEConfig, SolarOpen2Config,
+                                 TrainConfig, parse_cli, validate_train_config)
 from dcr_tpu.core.precision import text_param_dtype
 from dcr_tpu.models import openpangu_ultra_moe as pg
 from dcr_tpu.models.text_tower import build_text_tower, init_text_tower
@@ -249,11 +249,13 @@ def test_validation_names_the_tower_and_refuses_to_train_it():
 
 
 def test_one_table_of_towers_is_what_every_reader_reads():
-    assert list(TEXT_TOWERS) == ["clip", "longcat_flash", "openpangu_ultra_moe"]
+    assert list(TEXT_TOWERS) == ["clip", "longcat_flash", "openpangu_ultra_moe",
+                                 "solar_open2"]
     for name, row in TEXT_TOWERS.items():
         m = ModelConfig.tiny()
         m.text_tower = name
         m.longcat, m.openpangu = LongcatFlashConfig.tiny(), OpenPanguUltraMoEConfig.tiny()
+        m.solar = SolarOpen2Config.tiny()
         tower = build_text_tower(m, jnp.bfloat16)
         assert f"{type(tower).__module__}:{type(tower).__name__}" == row.module
         assert text_param_dtype(name) == jnp.dtype(row.held_dtype)
@@ -266,7 +268,8 @@ def test_one_table_of_towers_is_what_every_reader_reads():
 
 def test_the_move_left_clips_and_longcats_parameter_trees_as_they_were():
     """Paths, shapes, dtypes and ORDER of the leaves (the benchmark seeds leaf
-    i from salt i), pinned by digests taken on the commit before the move."""
+    i from salt i), pinned by digests taken on the commit before the move;
+    LongCat's and openPangu's lowered tiny programs too."""
     m = ModelConfig.tiny()
     tower = build_text_tower(m)
     assert path_digest(jax.eval_shape(
@@ -288,6 +291,17 @@ def test_the_move_left_clips_and_longcats_parameter_trees_as_they_were():
             jax.ShapeDtypeStruct((3, 16), jnp.int32)).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "d4600aacfef5de16d61309e5ab419e51ccd13fbf69b4097506e05fcf5ee915c6")
+    # openPangu's tree and lowered program, taken before its router rule and
+    # shared-expert module moved to models/lm_layers.py for Solar Open 2
+    m = tiny_model(2, 4)
+    tower = build_text_tower(m)
+    shapes = jax.eval_shape(lambda k: init_text_tower(m, k, tower), jax.random.key(0))
+    assert path_digest(shapes) == (
+        71, "76fd583a013dca7c82971963e58fede97d43650e818601439dc0bba33d8d371a")
+    text = jax.jit(lambda p, i: build_text_tower(m, jnp.bfloat16).apply(
+        {"params": p}, i)).lower(shapes, jax.ShapeDtypeStruct((3, 16), jnp.int32)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "4c5c87d2bc61003e7d69d882a7b7ab5a78bde9badfd5894cbc63f36420354c36")
     # LongCat at the benchmark's own sizes: four double layers, 16 held
     m = ModelConfig()
     m.text_tower = "longcat_flash"

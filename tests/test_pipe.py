@@ -650,13 +650,14 @@ def test_pipelined_nan_rollback(train_setup):
     assert t._frozen["vae"] is frozen_before
 
 
-@pytest.mark.parametrize("case", ["named_ahead", "another_batch_asked",
-                                  "write_fails"])
+@pytest.mark.parametrize("case", ["named_ahead", "two_ahead",
+                                  "another_batch_asked", "write_fails"])
 def test_precompute_job_keeps_the_device_ahead(train_setup, case):
     """`PrecomputeJob.encode_batch(number, then)`: the batch named as next is
     on the device before this one is waited for and the rows go to the disk
     on the writer's thread, and the cache is byte for byte what the calls one
-    after the other write; a caller that asks for another batch than it
+    after the other write; batches named as next in order are handed over in
+    that order, once each; a caller that asks for another batch than it
     named gets that batch; a write that fails is raised by the next call."""
     from dcr_tpu.cli.precompute import PrecomputeJob
 
@@ -675,7 +676,7 @@ def test_precompute_job_keeps_the_device_ahead(train_setup, case):
     if case == "named_ahead":
         for n in range(batches):
             ahead.encode_batch(n, n + 1 if n + 1 < batches else None)
-            assert (ahead._ahead or [None])[0] == (n + 1 if n + 1 < batches else None)
+            assert list(ahead._ahead) == ([n + 1] if n + 1 < batches else [])
         ahead.drain()
         assert len(list((tmp_path / "ahead").glob("shard_*.npz"))) == 4
         serial = job("serial")
@@ -683,6 +684,18 @@ def test_precompute_job_keeps_the_device_ahead(train_setup, case):
             serial.encode_batch(n)
         shards = lambda j: json.loads(j.finalize().read_text())["shards"]  # noqa: E731
         assert shards(ahead) == shards(serial)
+    elif case == "two_ahead":
+        ahead.encode_batch(0, [1, 0])       # batch 0 again, after batch 1
+        assert list(ahead._ahead) == [1, 0]
+        queued = ahead._ahead[0]
+        ahead.encode_batch(1, (0,))
+        assert list(ahead._ahead) == [0] and ahead._ahead[0] is queued
+        got = ahead.encode_batch(0)
+        assert ahead._ahead == {}
+        want = job("serial").encode_batch(0)
+        for name in ("index", "mean", "std", "ctx"):
+            np.testing.assert_array_equal(got[name], want[name])
+        ahead.close()
     elif case == "another_batch_asked":
         ahead.encode_batch(0, 1)
         got = ahead.encode_batch(0)
