@@ -94,11 +94,13 @@ class ShortConv(nn.Module):
 
 class KDA(nn.Module):
     """The gated delta-rule mixer, scopes `conv`, `gates`, `scan` and `out`
-    inside it."""
+    inside it. `mesh` as `lm_layers.MLA`'s: the scan's kernel runs under
+    shard_map over it."""
 
     cfg: SolarOpen2Config
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
+    mesh: Optional[jax.sharding.Mesh] = None
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -128,7 +130,7 @@ class KDA(nn.Module):
             if c.kda_allow_neg_eigval:
                 beta = 2.0 * beta
         with jax.named_scope("scan"):
-            o = chunked_delta_rule(q, k, v, log_alpha, beta)
+            o = chunked_delta_rule(q, k, v, log_alpha, beta, mesh=self.mesh)
         with jax.named_scope("out"):
             gate = dense(width, "g_b_proj")(dense(d, "g_a_proj")(x))
             o = RMSNorm(c.rms_norm_eps, f32, self.param_dtype, name="o_norm")(o)
@@ -191,7 +193,7 @@ class HybridLayer(nn.Module):
                 y = GatedGQA(c, self.dtype, self.param_dtype, self.mesh,
                              name="gqa")(n, mask)
             else:
-                y = KDA(c, self.dtype, self.param_dtype, name="kda")(n)
+                y = KDA(c, self.dtype, self.param_dtype, self.mesh, name="kda")(n)
             h = h + y
         y, stats = SharedExpertMoE(c, self.dtype, self.param_dtype, name="moe")(
             norm("post_attention_layernorm")(h))
@@ -206,7 +208,7 @@ class SolarOpen2TextTower(nn.Module):
     config: ModelConfig
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.bfloat16
-    mesh: Optional[jax.sharding.Mesh] = None    # handed down to every GQA site
+    mesh: Optional[jax.sharding.Mesh] = None    # handed down to every mixer
 
     @nn.compact
     def __call__(self, input_ids: jax.Array) -> TextTowerOutput:

@@ -39,14 +39,26 @@ decay would overflow float32.
 
 The state, the cumulative log decays and the UT transform are float32; the
 products take operands in q's dtype with float32 accumulation.
+
+`chunked_delta_rule` is the one entry point and a dispatcher, as
+`ops/attention.dot_product_attention` is: on the TPU, where Dk and Dv are
+multiples of 128 and the operands are ones the kernel takes, the Pallas
+kernel of `ops/delta_rule_kernel.py` runs the same mathematics with each
+head's state in VMEM across its chunks ("pallas"); everywhere else the XLA
+form below runs as it is ("xla"). The choice reads shapes, dtypes and the
+platform only (`path_for`).
 """
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
 
 from dcr_tpu.core import tracing
+from dcr_tpu.ops import attention, delta_rule_kernel as dk
 
 #: positions a chunk: the state is read and written once a chunk
 CHUNK = 64
@@ -75,23 +87,12 @@ def _unit_lower_inverse(l: jax.Array) -> jax.Array:
     return t
 
 
-def chunked_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
-                       log_alpha: jax.Array, beta: jax.Array) -> jax.Array:
-    """q, k [B, T, H, Dk]; v [B, T, H, Dv]; log_alpha [B, T, H, Dk] (<= 0,
-    the log of each key channel's decay); beta [B, T, H]. -> o [B, T, H, Dv]
-    float32. T need not be a multiple of `CHUNK`: the last chunk is padded
-    with positions that neither decay nor write the state. `CHUNK` and `SUB`
-    are read when called (`SUB` divides `CHUNK`). Counts itself once a trace:
-    `delta_rule/sites_total`, its chunks in `delta_rule/chunks_total`, the
-    chunk length in the gauge `delta_rule/chunk`."""
+def _xla_form(q: jax.Array, k: jax.Array, v: jax.Array, log_alpha: jax.Array,
+              beta: jax.Array, chunk: int, sub: int) -> jax.Array:
+    """The chunked scan in jnp and `lax.scan`, `chunk` and `sub` as given."""
     b, t, h, dk = q.shape
     dv = v.shape[-1]
-    chunk, sub = CHUNK, SUB
     n = -(-t // chunk)
-    reg = tracing.registry()
-    reg.counter("delta_rule/sites_total").inc()
-    reg.counter("delta_rule/chunks_total").inc(n)
-    reg.gauge("delta_rule/chunk").set(chunk)
     f32, mm = jnp.float32, q.dtype
     pad = n * chunk - t
 
@@ -181,3 +182,76 @@ def chunked_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
     o = jnp.moveaxis(o, (0, 3), (1, 2)).reshape(b, n * chunk, h, dv)
     return o[:, :t]
 
+
+def path_for(q, k, v, log_alpha, beta, *, mesh: Optional[Mesh] = None) -> str:
+    """"pallas" or "xla": which implementation a site of these operands takes.
+    Reads shapes, dtypes and the platform only, so `jax.ShapeDtypeStruct`s
+    will do. Over a mesh the kernel is asked about ONE device's rows and
+    heads; rows or heads the mesh does not divide cannot be sharded for it,
+    so XLA."""
+    if not attention._on_tpu() or q.ndim != 4:
+        return "xla"
+    rows, heads, _ = attention._shards(mesh)
+    b, t, h, _ = q.shape
+    if b % rows or h % heads:
+        return "xla"
+
+    def local(x):
+        return jax.ShapeDtypeStruct((x.shape[0] // rows, x.shape[1], x.shape[2] // heads)
+                                    + tuple(x.shape[3:]), x.dtype)
+
+    return "pallas" if dk.supported(*map(local, (q, k, v, log_alpha, beta))) else "xla"
+
+
+@jax.custom_vjp
+def _pallas_form(q, k, v, log_alpha, beta):
+    """The kernel forward; the XLA form's VJP backward (no cell differentiates
+    the scan, and a frozen tower never does)."""
+    return dk.delta_rule_fwd(q, k, v, log_alpha, beta)
+
+
+def _pallas_fwd(q, k, v, log_alpha, beta):
+    return _pallas_form(q, k, v, log_alpha, beta), (q, k, v, log_alpha, beta)
+
+
+def _pallas_bwd(residuals, g):
+    _, vjp = jax.vjp(lambda *x: _xla_form(*x, CHUNK, SUB), *residuals)
+    return vjp(g)
+
+
+_pallas_form.defvjp(_pallas_fwd, _pallas_bwd)
+
+
+def chunked_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
+                       log_alpha: jax.Array, beta: jax.Array, *,
+                       mesh: Optional[Mesh] = None) -> jax.Array:
+    """q, k [B, T, H, Dk]; v [B, T, H, Dv]; log_alpha [B, T, H, Dk] (<= 0,
+    the log of each key channel's decay); beta [B, T, H]. -> o [B, T, H, Dv]
+    float32. T need not be a multiple of the chunk: the last chunk is padded
+    with positions that neither decay nor write the state. On the XLA path
+    `CHUNK` and `SUB` are read when called (`SUB` divides `CHUNK`); the
+    kernel has its own (`delta_rule_kernel.CHUNK`, `.SUB`).
+
+    Counts itself once a trace: `delta_rule/sites_total` and
+    `delta_rule/sites_total/<path>`, the chunks of the path taken in
+    `delta_rule/chunks_total`, its chunk length in the gauge
+    `delta_rule/chunk`.
+
+    `mesh` is the mesh the enclosing jit spans (pmesh axes), or None on one
+    device or inside a shard_map. A Mosaic kernel is never partitioned
+    automatically, so over more than one device the kernel runs under
+    shard_map, each device on its own rows and heads."""
+    path = path_for(q, k, v, log_alpha, beta, mesh=mesh)
+    chunk = dk.CHUNK if path == "pallas" else CHUNK
+    reg = tracing.registry()
+    reg.counter("delta_rule/sites_total").inc()
+    reg.counter(f"delta_rule/sites_total/{path}").inc()
+    reg.counter("delta_rule/chunks_total").inc(-(-q.shape[1] // chunk))
+    reg.gauge("delta_rule/chunk").set(chunk)
+    if path == "xla":
+        return _xla_form(q, k, v, log_alpha, beta, CHUNK, SUB)
+    _, _, spec = attention._shards(mesh)
+    if spec is None:
+        return _pallas_form(q, k, v, log_alpha, beta)
+    return jax.shard_map(_pallas_form, mesh=mesh, in_specs=(spec,) * 4 + (P(*spec[:3]),),
+                         out_specs=spec, check_vma=False)(q, k, v, log_alpha, beta)

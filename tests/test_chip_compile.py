@@ -243,3 +243,23 @@ def test_no_relayout_between_the_projections_and_the_kernels(
         operands = re.findall(r"%([\w.\-]+)", rest[:rest.index(")")])
         assert {source(o) for o in operands} <= {"fusion", "custom-call",
                                                  "parameter"}, (name, operands)
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((16, 256, 64, 128), jnp.bfloat16),   # the Solar Open 2 encode cell's site
+    ((2, 200, 4, 128), jnp.float32),      # padded to whole chunks, float32
+    ((1, 2048, 2, 256), jnp.bfloat16),    # two slabs a head, a long sequence
+], ids=["solar_site_bf16", "padded_f32", "long_two_slabs"])
+def test_the_delta_rule_kernel_compiles_for_v5e(one_chip, shape, dtype):
+    """The gated delta rule's kernel at the shapes the dispatcher hands it:
+    `HEADS` heads of one sequence a program, their states in VMEM."""
+    from dcr_tpu.ops import delta_rule_kernel as dk
+
+    b, t, h, d = shape
+    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    decay = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((b, t, h), jnp.float32, sharding=one_chip)
+    assert dk.supported(qkv, qkv, qkv, decay, beta)
+    text = jax.jit(dk.delta_rule_fwd).lower(qkv, qkv, qkv, decay, beta
+                                            ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1

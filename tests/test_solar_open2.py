@@ -117,18 +117,18 @@ def test_tower_follows_the_reference_in_float32(small_chunks, first, count):
 
 def _per_chunk(real):
     """The state thrown away at every chunk's start."""
-    def run(q, k, v, log_alpha, beta):
+    def run(q, k, v, log_alpha, beta, **kw):
         c = dr.CHUNK
-        return jnp.concatenate([real(*(x[:, i:i + c] for x in (q, k, v, log_alpha, beta)))
-                                for i in range(0, q.shape[1], c)], axis=1)
+        return jnp.concatenate([real(*(x[:, i:i + c] for x in (q, k, v, log_alpha, beta)),
+                                     **kw) for i in range(0, q.shape[1], c)], axis=1)
     return run
 
 
 def _per_head(real):
     """One decay a head: the mean of its channels'."""
-    def run(q, k, v, log_alpha, beta):
+    def run(q, k, v, log_alpha, beta, **kw):
         return real(q, k, v, jnp.broadcast_to(
-            jnp.mean(log_alpha, axis=-1, keepdims=True), log_alpha.shape), beta)
+            jnp.mean(log_alpha, axis=-1, keepdims=True), log_alpha.shape), beta, **kw)
     return run
 
 
